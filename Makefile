@@ -1,6 +1,12 @@
 # Developer entry points. The tier-1 gate is exactly what CI runs.
 PYTHONPATH := src
 
+# Kernel backend for the bench targets. These targets are the CPU proxy, so
+# they name the XLA references explicitly; on a TPU run e.g.
+# `make bench KERNEL_BACKEND=auto` to time the Mosaic kernels.
+KERNEL_BACKEND ?= xla
+BENCH := REPRO_KERNEL_BACKEND=$(KERNEL_BACKEND) PYTHONPATH=src python -m
+
 .PHONY: test test-dist smoke lint lint-mdrq budget-cert budget-check \
         bench-throughput bench-count bench-specs \
         bench-specs-smoke bench-smoke bench-ingest bench-ingest-smoke \
@@ -23,7 +29,7 @@ smoke:
 
 # Batched-execution throughput sweep (CPU: XLA proxy; TPU: Mosaic kernels).
 bench-throughput:
-	PYTHONPATH=src python -m benchmarks.run --only throughput
+	$(BENCH) benchmarks.run --only throughput
 
 # Lint gate: ruff (config in pyproject.toml) + mdrqlint. CI runs exactly this.
 lint: lint-mdrq budget-check
@@ -49,52 +55,53 @@ budget-check:
 
 # Count-only result mode sweep (device-side reduction, no host nonzero).
 bench-count:
-	PYTHONPATH=src python -m benchmarks.run --only throughput-count
+	$(BENCH) benchmarks.run --only throughput-count
 
 # Reduced result shapes (top-k / aggregates) vs ids at the largest batch.
 bench-specs:
-	PYTHONPATH=src python -m benchmarks.run --only throughput-specs
+	$(BENCH) benchmarks.run --only throughput-specs
 
 # CI-sized reducer smoke: one TopK row + one Agg row at tiny sizes so a
 # reducer perf regression surfaces in CI logs.
 bench-specs-smoke:
-	PYTHONPATH=src python -m benchmarks.bench_throughput --spec topk --smoke
-	PYTHONPATH=src python -m benchmarks.bench_throughput --spec agg --smoke
+	$(BENCH) benchmarks.bench_throughput --spec topk --smoke
+	$(BENCH) benchmarks.bench_throughput --spec agg --smoke
 
 # CI smoke artifact: per-batch-size qps + latency percentiles as JSON.
 # CI runs this into /tmp and diffs against the checked-in BENCH_smoke.json
 # (benchmarks.check_bench, +-30% qps guard band, warn-only).
 BENCH_SMOKE_OUT ?= BENCH_smoke.json
 bench-smoke:
-	PYTHONPATH=src python -m benchmarks.bench_throughput --smoke \
+	$(BENCH) benchmarks.bench_throughput --smoke \
 	--json $(BENCH_SMOKE_OUT)
 
 # Pipelined serving: sync-vs-pipelined head-to-head + offered-load sweep
 # (saturation knee, p99 under load, shed fraction) -> BENCH_pipeline.json.
 bench-pipeline:
-	PYTHONPATH=src python -m benchmarks.bench_throughput --offered-load
+	$(BENCH) benchmarks.bench_throughput --offered-load
 
 # CI-sized pipeline smoke: same sweep at tiny n. CI runs this into /tmp and
 # diffs against the checked-in BENCH_pipeline.json (benchmarks.check_bench,
 # +-30% guard band, warn-only).
 BENCH_PIPELINE_OUT ?= BENCH_pipeline.json
 bench-pipeline-smoke:
-	PYTHONPATH=src python -m benchmarks.bench_throughput --offered-load \
+	$(BENCH) benchmarks.bench_throughput --offered-load \
 	--smoke --json $(BENCH_PIPELINE_OUT)
 
 # Serve-while-ingest sweep: qps vs delta fraction + post-compaction recovery.
 bench-ingest:
-	PYTHONPATH=src python -m benchmarks.run --only throughput-ingest
+	$(BENCH) benchmarks.run --only throughput-ingest
 
 # CI-sized ingest smoke: same sweep at tiny n so a write-path serving
 # regression (delta scan tax, compaction stall) surfaces in CI logs.
 bench-ingest-smoke:
-	PYTHONPATH=src python -m benchmarks.bench_throughput --ingest --smoke
+	$(BENCH) benchmarks.bench_throughput --ingest --smoke
 
 # Cross-device batched scan sweep on the 8-device CPU proxy.
 bench-dist:
-	PYTHONPATH=src python -m benchmarks.bench_throughput --devices
+	$(BENCH) benchmarks.bench_throughput --devices
 
-# Full benchmark matrix (quick sizes).
+# Full benchmark matrix (quick sizes). The multi-device sections (fig4's
+# sharded row, fig11) run over the 8-device CPU proxy.
 bench:
-	PYTHONPATH=src python -m benchmarks.run
+	XLA_FLAGS=--xla_force_host_platform_device_count=8 $(BENCH) benchmarks.run

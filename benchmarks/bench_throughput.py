@@ -4,8 +4,8 @@ Sweeps the serving batch size over {1, 8, 32, 128} with the fused multi-query
 kernels underneath (``MDRQEngine.query_batch`` via ``MDRQServer``) — the
 inter-query analogue of the paper's intra-query scaling figures. Batch 1 is
 the seed engine's per-query regime, so the B{128}/B{1} speedup row is the
-amortization headline. Like every benchmark here, CPU numbers use the XLA
-backend as the honest proxy (see common.py); real kernel numbers are TPU.
+amortization headline. On a CPU run it with ``REPRO_KERNEL_BACKEND=xla``
+(the Makefile targets do; see common.py); real kernel numbers are TPU.
 
 Result shapes ride the ResultSpec layer: every row carries a ``result_spec``
 column, ``--spec {ids,count,mask,topk,agg}`` selects the shape for the mixed
@@ -18,8 +18,7 @@ import os
 import sys
 import time
 
-if __name__ == "__main__":  # direct module run: set the backend before any
-    os.environ.setdefault("REPRO_KERNEL_BACKEND", "xla")  # repro import
+if __name__ == "__main__":
     if "--devices" in sys.argv:
         # the device count locks at first XLA init, so the CPU proxy for the
         # cross-device sweep must be forced before anything imports jax
@@ -426,6 +425,8 @@ if __name__ == "__main__":
                          "qps/latency artifact here (BENCH_smoke.json)")
     args = ap.parse_args()
     from benchmarks.common import CSV_HEADER
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     print(CSV_HEADER, flush=True)
     if args.offered_load:
         run_pipeline(quick=not args.full, smoke=args.smoke,

@@ -1,9 +1,11 @@
 """Shared benchmark utilities.
 
-All benchmarks execute with REPRO_KERNEL_BACKEND=xla (set by run.py before
-any repro import): interpret-mode Pallas runs the grid as a Python loop, so
-the XLA path — semantically identical to the kernels, validated in tests —
-is the honest CPU throughput proxy. On a TPU the same harness times Mosaic.
+The kernel backend is whatever REPRO_KERNEL_BACKEND names; nothing here sets
+it. On a TPU the default (``auto``) times the Mosaic kernels. On a CPU,
+interpret-mode Pallas runs the grid as a Python loop, so the CPU bench
+targets set ``REPRO_KERNEL_BACKEND=xla``: the XLA path — semantically
+identical to the kernels, validated in tests — is the CPU proxy. A CPU
+number is never a device number.
 """
 from __future__ import annotations
 

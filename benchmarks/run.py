@@ -1,11 +1,14 @@
-import os
-os.environ.setdefault("REPRO_KERNEL_BACKEND", "xla")  # see common.py
-
 """Benchmark runner — one section per paper table/figure.
 
-  PYTHONPATH=src python -m benchmarks.run            # quick sizes (CPU box)
+  PYTHONPATH=src python -m benchmarks.run            # quick sizes
   PYTHONPATH=src python -m benchmarks.run --full     # paper-scale sizes
   PYTHONPATH=src python -m benchmarks.run --only fig6,fig10
+
+The kernels run on whatever ``REPRO_KERNEL_BACKEND`` names (see common.py):
+Mosaic on a TPU by default; on a CPU set ``REPRO_KERNEL_BACKEND=xla`` (the
+Makefile bench targets do). The multi-device sections (fig4's sharded row,
+fig11) use the devices this process sees; on a CPU, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to give it eight.
 
 Prints ``name,us_per_call,derived`` CSV rows. The roofline section reads the
 dry-run artifacts under results/dryrun (run repro.launch.dryrun first).
@@ -17,6 +20,7 @@ import traceback
 
 from benchmarks import common
 from benchmarks.common import CSV_HEADER
+from repro.compile_cache import use_compile_cache
 
 # (section name, module[, entry point — defaults to ``run``])
 SECTIONS = [
@@ -55,6 +59,7 @@ def main() -> int:
                          "(its CSV rows as structured records) into this "
                          "directory")
     args = ap.parse_args()
+    use_compile_cache()
     only = set(filter(None, args.only.split(",")))
     if args.json_dir:
         import os as _os

@@ -6,21 +6,25 @@ window — verifies all three agree, and prints the planner's batched
 break-even shift (the cost-model result single-query analysis cannot see).
 
   PYTHONPATH=src python examples/batched_queries.py [n_objects]
+
+On a TPU this runs the Mosaic kernels. On a CPU, prefix
+``REPRO_KERNEL_BACKEND=xla`` to run the XLA references instead of the
+(slow) interpret-mode kernels.
 """
-import os
-os.environ.setdefault("REPRO_KERNEL_BACKEND", "xla")
 
 import sys
 import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import Agg, Count, MDRQEngine, TopK
 from repro.data import gmrqb
 from repro.serve.mdrq_server import MDRQServer
 
 
 def main() -> None:
+    use_compile_cache()
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 200_000
     print(f"building GMRQB ({n} records, 19 attributes) ...")
     ds = gmrqb.build(n, seed=0)

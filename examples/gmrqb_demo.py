@@ -5,20 +5,24 @@ selectivities, and runs each template through scan / vertical scan / kd-tree /
 VA-file with the planner's choice last.
 
   PYTHONPATH=src python examples/gmrqb_demo.py [n_objects]
+
+On a TPU this runs the Mosaic kernels. On a CPU, prefix
+``REPRO_KERNEL_BACKEND=xla`` to run the XLA references instead of the
+(slow) interpret-mode kernels.
 """
-import os
-os.environ.setdefault("REPRO_KERNEL_BACKEND", "xla")
 
 import sys
 import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import MDRQEngine
 from repro.data import gmrqb
 
 
 def main() -> None:
+    use_compile_cache()
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 300_000
     print(f"building GMRQB ({n} variation records, 19 attributes) ...")
     ds = gmrqb.build(n, seed=0)

@@ -5,19 +5,23 @@ same range query through every access path, shows they agree, and asks the
 planner where the scan/index break-even sits — the paper's headline ~1%.
 
   PYTHONPATH=src python examples/quickstart.py
+
+On a TPU this runs the Mosaic kernels. On a CPU, prefix
+``REPRO_KERNEL_BACKEND=xla`` to run the XLA references instead of the
+(slow) interpret-mode kernels.
 """
-import os
-os.environ.setdefault("REPRO_KERNEL_BACKEND", "xla")  # fast CPU proxy path
 
 import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import MDRQEngine, RangeQuery
 from repro.data import synthetic
 
 
 def main() -> None:
+    use_compile_cache()
     n, m = 300_000, 5
     print(f"building SYNT-UNI {n} x {m} and all access paths ...")
     ds = synthetic.synt_uni(n, m, seed=0)

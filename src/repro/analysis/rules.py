@@ -545,10 +545,11 @@ class UncountedLaunchRule(Rule):
 class RawShardMapRule(Rule):
     rule_id = "raw-shard-map"
     doc = ("shard_map only via core.distributed.shard_map_compat (ROADMAP "
-           "standing rule: it papers over jax.shard_map API drift).")
+           "standing rule: one place sets the flags every pallas_call "
+           "inside a shard_map needs).")
 
     _MSG = ("raw shard_map — use core.distributed.shard_map_compat "
-            "(handles the jax.shard_map / jax.experimental.shard_map drift)")
+            "(it turns off the vma check pallas_call outputs cannot pass)")
 
     def check(self, ctx: FileContext) -> list[Finding]:
         if not _in_repro(ctx.posix) \

@@ -41,39 +41,15 @@ from repro.kernels import range_scan as _rs
 
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map`` (with ``check_vma``); this tree's
-    pinned version only has ``jax.experimental.shard_map.shard_map`` (with
-    ``check_rep``). Both flags are disabled for the same reason: pallas_call
-    outputs carry no replication/vma metadata.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            pass
-        try:
-            # intermediate versions export jax.shard_map but still spell the
-            # flag check_rep — it must be disabled just the same
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with the vma check off: pallas_call outputs carry no
+    varying-manual-axes metadata. The one place the repo builds a shard_map
+    (mdrqlint's ``raw-shard-map`` rule points here)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def make_data_mesh(n_devices: int | None = None) -> Mesh:
-    """1-D mesh over all (or the first k) local devices: axis 'data'.
-
-    Builds ``jax.sharding.Mesh`` directly from a device ndarray — the
-    ``jax.make_mesh(..., devices=list)`` path is not portable across the JAX
-    versions this tree supports.
-    """
+    """1-D mesh over all (or the first k) local devices: axis 'data'."""
     devs = jax.devices()
     k = n_devices or len(devs)
     return Mesh(np.asarray(devs[:k]), ("data",))
@@ -88,8 +64,9 @@ def shard_columnar(mesh: Mesh, padded_cols: np.ndarray, tile_n: int = 1024) -> j
     n_dev = mesh.shape["data"]
     m_pad, n_pad = padded_cols.shape
     assert n_pad % (n_dev * tile_n) == 0, (n_pad, n_dev, tile_n)
-    sharding = NamedSharding(mesh, P(None, "data"))
-    return jax.device_put(jnp.asarray(padded_cols), sharding)
+    # Straight from host numpy into the sharding: each device receives only
+    # its own slice (a jnp.asarray first would land the whole array on one).
+    return jax.device_put(padded_cols, NamedSharding(mesh, P(None, "data")))
 
 
 def _local_scan(data_local, lo, up, *, tile_n: int, interpret: bool):
@@ -416,7 +393,7 @@ class DistributedScan:
             tomb = delta.base_tomb_dev(
                 self.data.shape[1], key=("dist", int(self.data.shape[1])),
                 put=lambda h: jax.device_put(
-                    jnp.asarray(h), NamedSharding(self.mesh, P("data"))))
+                    h, NamedSharding(self.mesh, P("data"))))
         payload = distributed_multi_reduce(self.mesh, self.data, lo, up,
                                            dcm, tomb,
                                            spec=spec, tile_n=self.tile_n)

@@ -18,7 +18,7 @@ Unlike the tree MDIS, data stays in storage order (no permutation): the
 VA-file is a *scan accelerator*, not a clustering structure.
 
 Batched execution runs *both* phases fused: phase 1 is one
-``multi_va_filter`` launch per batch (grid ``(n_tiles, Q)``, packed words
+``multi_va_filter`` launch per batch (grid ``(n_tiles,)``, packed words
 fetched from HBM once per batch) whose candidate masks reduce to per-
 (query, block) survivor bits on device — a single small (Q, n_blocks) bool
 readback replaces Q per-query mask transfers — and phase 2 flattens the
@@ -156,7 +156,7 @@ class VAFile:
         """Batched phase 1: one fused filter launch, one small host sync.
 
         ``multi_va_filter`` evaluates every query's approximation in a single
-        (n_tiles, Q) launch and reduces the candidate masks to per-
+        launch and reduces the candidate masks to per-
         (query, block) survivor bits on device, so the only device->host
         transfer of the phase is one (Q, n_blocks) bool array — the batch
         counterpart of the Q mask readbacks the per-query path paid.

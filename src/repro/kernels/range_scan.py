@@ -11,25 +11,29 @@ against data objects). Differences forced by the hardware (see DESIGN.md §2):
   * blocks are (m_pad, TN) VMEM tiles: m is padded to a multiple of 8
     (sublanes), TN is a multiple of 128 (lanes).
 
-Two entry points:
+Entry points:
 
   * ``range_scan_tiles``     — full scan: grid over all n/TN tiles.
+  * ``range_scan_rows``      — the row-major layout, for the layout ablation.
   * ``range_scan_visit``     — two-phase scan: a scalar-prefetched list of
     block ids selects which tiles are visited (kd-tree / R-tree / VA-file
     refinement). Grid size = number of visited blocks, so pruned blocks cost
     *nothing* — the TPU analogue of "skip subtrees".
+  * ``range_scan_vertical``  — partial-match scan over the queried dims only.
+
+The last two are the batched kernels of ``multi_scan`` at Q=1.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 SUBLANES = 8
+# int8's native tile is (32, 128): batched kernels write their (Q, tile_n)
+# int8 masks in chunks of this many query rows.
+INT8_SUBLANES = 32
 DEFAULT_TILE_N = 1024
 
 
@@ -83,29 +87,6 @@ def range_scan_tiles(
     return out[0]
 
 
-def _vertical_kernel(dim_ids_ref, lower_ref, upper_ref, data_ref, out_ref):
-    """One grid step = one (queried dimension, tile) pair — vertical partitioning.
-
-    Grid is (n_tiles, n_qdims); the out tile is revisited across j and the
-    per-dimension masks are AND-merged in place (the paper's bitmask
-    intersection, §3.2, without materializing per-dimension bitmasks in HBM).
-    """
-    j = pl.program_id(1)
-    d = dim_ids_ref[j]
-    x = data_ref[...]  # (1, TN) — only the queried dimension's row is fetched
-    lo = lower_ref[d, 0]
-    up = upper_ref[d, 0]
-    ok = jnp.logical_and(x >= lo, x <= up).astype(jnp.int8)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = ok
-
-    @pl.when(j > 0)
-    def _merge():
-        out_ref[...] = jnp.logical_and(out_ref[...] > 0, ok > 0).astype(jnp.int8)
-
-
 def range_scan_vertical(
     data_cm: jax.Array,
     dim_ids: jax.Array,
@@ -117,39 +98,20 @@ def range_scan_vertical(
 ) -> jax.Array:
     """Partial-match vertical scan: touch only the queried dimensions' columns.
 
+    The batched vertical kernel at Q=1: only the 8-dim sublane groups that
+    hold a queried dimension are read.
+
     Args:
       data_cm: (m_pad, n_pad) columnar data.
       dim_ids: (n_qdims,) int32 ids of the queried dimensions.
-      lower, upper: (m_pad, 1) finite bounds (indexed by dim_ids in-kernel).
+      lower, upper: (m_pad, 1) finite bounds, match-all on the other dims.
 
     Returns:
       (n_pad,) int8 match mask over the queried dimensions only.
     """
-    m_pad, n_pad = data_cm.shape
-    n_qdims = dim_ids.shape[0]
-    assert n_pad % tile_n == 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_pad // tile_n, n_qdims),
-        in_specs=[
-            pl.BlockSpec((m_pad, 1), lambda i, j, ids: (0, 0)),
-            pl.BlockSpec((m_pad, 1), lambda i, j, ids: (0, 0)),
-            pl.BlockSpec((1, tile_n), lambda i, j, ids: (ids[j], i)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda i, j, ids: (0, i)),
-    )
-    out = pl.pallas_call(
-        _vertical_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int8),
-        interpret=interpret,
-    )(
-        dim_ids.astype(jnp.int32),
-        lower.astype(data_cm.dtype),
-        upper.astype(data_cm.dtype),
-        data_cm,
-    )
+    from repro.kernels import multi_scan as _ms  # deferred: _ms imports us
+    out = _ms.multi_scan_vertical(data_cm, dim_ids.reshape(1, -1), lower,
+                                  upper, tile_n=tile_n, interpret=interpret)
     return out[0]
 
 
@@ -202,15 +164,6 @@ def range_scan_rows(
     return out[:, 0]
 
 
-def _visit_kernel(ids_ref, lower_ref, upper_ref, data_ref, out_ref):
-    """Scan the tile selected by the prefetched block-id list."""
-    x = data_ref[...]
-    lo = lower_ref[...]
-    up = upper_ref[...]
-    ok = jnp.logical_and(x >= lo, x <= up)
-    out_ref[...] = jnp.all(ok, axis=0, keepdims=True).astype(jnp.int8)
-
-
 def range_scan_visit(
     data_cm: jax.Array,
     block_ids: jax.Array,
@@ -222,6 +175,8 @@ def range_scan_visit(
 ) -> jax.Array:
     """Two-phase scan: visit only the listed (m_pad, tile_n) blocks.
 
+    The batched visit kernel with every visit on query 0.
+
     Args:
       data_cm: (m_pad, n_pad) columnar data, n_pad % tile_n == 0.
       block_ids: (n_visit,) int32 tile indices into [0, n_pad / tile_n); padding
@@ -231,29 +186,7 @@ def range_scan_visit(
     Returns:
       (n_visit, tile_n) int8 per-visit masks.
     """
-    m_pad, n_pad = data_cm.shape
-    n_visit = block_ids.shape[0]
-    assert m_pad % SUBLANES == 0 and n_pad % tile_n == 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_visit,),
-        in_specs=[
-            pl.BlockSpec((m_pad, 1), lambda i, ids: (0, 0)),
-            pl.BlockSpec((m_pad, 1), lambda i, ids: (0, 0)),
-            pl.BlockSpec((m_pad, tile_n), lambda i, ids: (0, jnp.maximum(ids[i], 0))),
-        ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda i, ids: (i, 0)),
-    )
-    out = pl.pallas_call(
-        _visit_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_visit, tile_n), jnp.int8),
-        interpret=interpret,
-    )(
-        block_ids.astype(jnp.int32),
-        lower.astype(data_cm.dtype),
-        upper.astype(data_cm.dtype),
-        data_cm,
-    )
-    return out
+    from repro.kernels import multi_scan as _ms  # deferred: _ms imports us
+    qids = jnp.zeros(block_ids.shape, jnp.int32)
+    return _ms.multi_scan_visit(data_cm, qids, block_ids, lower, upper,
+                                tile_n=tile_n, interpret=interpret)

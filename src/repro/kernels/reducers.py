@@ -7,20 +7,20 @@ top-k values/positions, an aggregate, a count — *inside the same jit* as the
 kernel that produced the mask, so only O(Q·k) / O(Q) bytes ever cross the
 device->host boundary.
 
-Two Pallas kernels, both on the fused-batch grid ``(n_tiles, Q)`` family the
-multi-query scans use (query axis innermost, so the streamed values tile is
-fetched from HBM once per batch):
+Two Pallas kernels, both on the fused-batch grid ``(n_tiles,)`` the
+multi-query scans use, with (Q, tile_n) mask blocks (so the streamed values
+tile is fetched from HBM once per batch):
 
   * ``masked_fill_tiles`` — elementwise select: matching lanes keep the
     attribute value, non-matching lanes take the reduction identity. The
     filled (Q, n_pad) array feeds ``jax.lax.top_k`` in the same jit — the
     TPU-native way to run a batched masked top-k (sorting networks inside a
     Mosaic kernel are not a win over XLA's top_k).
-  * ``masked_agg_tiles`` — lane-parallel accumulation: grid ``(Q, n_tiles)``
-    with the tile axis innermost revisits one (1, tile_n) accumulator block
-    per query (init at tile 0, combine after — the ``multi_scan_vertical``
-    in-place-merge idiom), leaving a (Q, tile_n) lane partial whose final
-    cross-lane reduce rides in the wrapping jit.
+  * ``masked_agg_tiles`` — lane-parallel accumulation: every tile step
+    revisits one (Q, tile_n) accumulator block (init at tile 0, combine
+    after — the ``multi_scan_vertical`` in-place-merge idiom), leaving a
+    (Q, tile_n) lane partial whose final cross-lane reduce rides in the
+    wrapping jit.
 
 The jnp ``visit_*`` reducers cover the two-phase paths' (V, tile_n) visit
 masks (segment reductions by query id). XLA oracles live in ``ref.py``;
@@ -43,10 +43,15 @@ _AGG_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 _AGG_FINAL = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
 
 
+def _hits(mask_ref):
+    """(Q, TN) bool from an int8 mask block, compared in int32: Mosaic cannot
+    broadcast a value against a bool vector laid out from int8."""
+    return mask_ref[...].astype(jnp.int32) != 0
+
+
 def _masked_fill_kernel(mask_ref, val_ref, out_ref, *, fill):
     """Matching lanes keep the value; the rest take the identity ``fill``."""
-    out_ref[...] = jnp.where(mask_ref[...] != 0, val_ref[...],
-                             jnp.float32(fill))
+    out_ref[...] = jnp.where(_hits(mask_ref), val_ref[...], jnp.float32(fill))
 
 
 def masked_fill_tiles(
@@ -71,26 +76,25 @@ def masked_fill_tiles(
     assert n_pad % tile_n == 0 and tile_n % LANES == 0, (n_pad, tile_n)
     assert values.shape == (n_pad,), values.shape
 
-    # Query axis innermost: the values tile's index map is constant across q,
-    # so each (1, tile_n) HBM tile is fetched once per batch.
-    grid = (n_pad // tile_n, q_n)
+    grid = (n_pad // tile_n,)
     return pl.pallas_call(
         functools.partial(_masked_fill_kernel, fill=float(fill)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_n), lambda i, q: (q, i)),
-            pl.BlockSpec((1, tile_n), lambda i, q: (0, i)),
+            pl.BlockSpec((q_n, tile_n), lambda i: (0, i)),
+            pl.BlockSpec((1, tile_n), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda i, q: (q, i)),
+        out_specs=pl.BlockSpec((q_n, tile_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((q_n, n_pad), jnp.float32),
         interpret=interpret,
     )(masks, values.astype(jnp.float32).reshape(1, n_pad))
 
 
 def _masked_agg_kernel(mask_ref, val_ref, out_ref, *, op, fill):
-    """Accumulate one masked tile into the query's (1, tile_n) lane partial."""
-    i = pl.program_id(1)
-    part = jnp.where(mask_ref[...] != 0, val_ref[...], jnp.float32(fill))
+    """Accumulate one masked tile into every query's (1, tile_n) lane
+    partial (rows of the resident (Q, tile_n) output block)."""
+    i = pl.program_id(0)
+    part = jnp.where(_hits(mask_ref), val_ref[...], jnp.float32(fill))
 
     @pl.when(i == 0)
     def _init():
@@ -125,18 +129,17 @@ def masked_agg_tiles(
     assert values.shape == (n_pad,), values.shape
     fill = AGG_FILL[op]
 
-    # Tile axis innermost: each query's (1, tile_n) accumulator block is
-    # revisited on consecutive grid steps (the in-place merge idiom of
-    # ``multi_scan_vertical``), so the output flushes once per query.
-    grid = (q_n, n_pad // tile_n)
+    # One (Q, tile_n) accumulator block, revisited by every tile step (the
+    # ``multi_scan_vertical`` in-place-merge idiom); it flushes once.
+    grid = (n_pad // tile_n,)
     return pl.pallas_call(
         functools.partial(_masked_agg_kernel, op=op, fill=fill),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_n), lambda q, i: (q, i)),
-            pl.BlockSpec((1, tile_n), lambda q, i: (0, i)),
+            pl.BlockSpec((q_n, tile_n), lambda i: (0, i)),
+            pl.BlockSpec((1, tile_n), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda q, i: (q, 0)),
+        out_specs=pl.BlockSpec((q_n, tile_n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((q_n, tile_n), jnp.float32),
         interpret=interpret,
     )(masks, values.astype(jnp.float32).reshape(1, n_pad))

@@ -15,10 +15,10 @@ Two entry points:
 
   * ``va_filter_packed``       — single query: grid ``(n_tiles,)``.
   * ``multi_va_filter_packed`` — a whole query batch in one launch: grid
-    ``(n_tiles, Q)`` with the query axis innermost, so the packed-word tile's
-    block index map is constant across q and each (w, tile_n) tile streams
-    from HBM once per *batch* — the same fusion ``multi_scan`` applies to the
-    exact scans, here applied to the approximation phase.
+    ``(n_tiles,)`` with a (Q, tile_n) mask block per step, so each (w, tile_n)
+    packed-word tile streams from HBM once per *batch* — the same fusion
+    ``multi_scan`` applies to the exact scans, here applied to the
+    approximation phase.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-LANES = 128
+from repro.kernels.range_scan import INT8_SUBLANES, LANES
 DEFAULT_TILE_N = 2048
 # The paper's static cell resolution (b_j = 2, §2.2.3). Everything downstream
 # — word packing density, the planner's candidate-fraction slack and
@@ -109,6 +109,27 @@ def va_filter_packed(
     return out[0]
 
 
+def _multi_va_kernel(qlo_ref, qhi_ref, packed_ref, out_ref, *, m: int):
+    """Unpack-compare one (w, TN) word tile against every query's cell
+    bounds; (Q, TN) int8 out, written in int8-tile row chunks."""
+    words = packed_ref[...]  # (w, tn) int32
+    q_n = out_ref.shape[0]
+    for q0 in range(0, q_n, INT8_SUBLANES):
+        q1 = min(q0 + INT8_SUBLANES, q_n)
+        lo = qlo_ref[q0:q1, :]  # (qc, m_s), query-major
+        hi = qhi_ref[q0:q1, :]
+        acc = None
+        for d in range(m):
+            wi, k = divmod(d, DIMS_PER_WORD)
+            field = jnp.bitwise_and(
+                jnp.right_shift(words[wi:wi + 1, :], BITS_PER_DIM * k),
+                CODE_MASK)  # (1, tn)
+            ok = jnp.logical_and(field >= lo[:, d:d + 1],
+                                 field <= hi[:, d:d + 1])
+            acc = ok if acc is None else jnp.logical_and(acc, ok)
+        out_ref[q0:q1, :] = acc.astype(jnp.int8)
+
+
 def multi_va_filter_packed(
     packed: jax.Array,
     cell_lo: jax.Array,
@@ -120,10 +141,10 @@ def multi_va_filter_packed(
 ) -> jax.Array:
     """Candidate masks for a whole query batch from one launch.
 
-    The kernel body is the single-query unpack-compare (``_va_kernel``); only
-    the grid changes: ``(n_tiles, Q)`` with the query axis innermost, so the
-    (w, tile_n) packed-word tile is fetched from HBM once per batch and
-    compared against every query's cell bounds while resident in VMEM.
+    Grid ``(n_tiles,)``: each (w, tile_n) packed-word tile is fetched from
+    HBM once per batch and compared against every query's cell bounds while
+    resident in VMEM. Bounds enter as one query-major (Q, m_s) block and the
+    masks leave as (Q, tile_n) blocks (the ``multi_scan`` layout).
 
     Args:
       packed: (w, n_pad) int32 packed codes, n_pad % tile_n == 0.
@@ -140,17 +161,16 @@ def multi_va_filter_packed(
     m_s, q_n = cell_lo.shape
     assert m_s >= m and cell_lo.shape == cell_hi.shape == (m_s, q_n)
 
-    grid = (n_pad // tile_n, q_n)
-    out = pl.pallas_call(
-        functools.partial(_va_kernel, m=m),
+    grid = (n_pad // tile_n,)
+    return pl.pallas_call(
+        functools.partial(_multi_va_kernel, m=m),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m_s, 1), lambda i, q: (0, q)),
-            pl.BlockSpec((m_s, 1), lambda i, q: (0, q)),
-            pl.BlockSpec((w, tile_n), lambda i, q: (0, i)),
+            pl.BlockSpec((q_n, m_s), lambda i: (0, 0)),
+            pl.BlockSpec((q_n, m_s), lambda i: (0, 0)),
+            pl.BlockSpec((w, tile_n), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda i, q: (q, i)),
+        out_specs=pl.BlockSpec((q_n, tile_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((q_n, n_pad), jnp.int8),
         interpret=interpret,
-    )(cell_lo.astype(jnp.int32), cell_hi.astype(jnp.int32), packed)
-    return out
+    )(cell_lo.T.astype(jnp.int32), cell_hi.T.astype(jnp.int32), packed)

@@ -96,6 +96,32 @@ def test_multi_scan_visit_vs_oracle():
                                       full[b * tile_n:(b + 1) * tile_n])
 
 
+def test_multi_scan_visit_chunked_vs_oracle(monkeypatch):
+    """A visit list longer than one kernel's SMEM share runs as a loop over
+    chunks inside the same launch; rows past the list are cut off."""
+    from repro.kernels import multi_scan as ms
+    monkeypatch.setattr(ms, "VISIT_CHUNK", 64)
+    rng = np.random.default_rng(8)
+    m, tile_n = 5, 1024
+    cols = rng.random((m, 8192)).astype(np.float32)
+    batch = QueryBatch.from_queries(_mixed_queries(m, cols, rng, 3))
+    padded, _, _ = ops.prepare_columnar(cols, tile_n=tile_n)
+    data = jnp.asarray(padded)
+    n_blocks = padded.shape[1] // tile_n
+    qids = np.tile(np.repeat(np.arange(3), n_blocks), 4)
+    bids = np.tile(np.arange(n_blocks), 12)
+    qids = np.concatenate([qids, [0, 0]]).astype(np.int32)  # 98 visits
+    bids = np.concatenate([bids, [-1, -1]]).astype(np.int32)
+    lo, up = batch.bounds_columnar(padded.shape[0])
+    lo, up = jnp.asarray(lo), jnp.asarray(up)
+    out = np.asarray(ms.multi_scan_visit(
+        data, jnp.asarray(qids), jnp.asarray(bids), lo, up, tile_n=tile_n,
+        interpret=True))
+    blocks = data.reshape(data.shape[0], n_blocks, tile_n).transpose(1, 0, 2)
+    np.testing.assert_array_equal(out, np.asarray(ref.multi_scan_blocks_ref(
+        blocks, jnp.asarray(qids), jnp.asarray(bids), lo, up)))
+
+
 @pytest.mark.parametrize("m,n_q", [(5, 3), (19, 6), (33, 4)])
 def test_multi_va_filter_vs_single_and_oracle(m, n_q):
     """Batched phase 1: one-launch masks == per-query va_filter == ref,
