@@ -414,10 +414,11 @@ class BlockedIndex:
         q_n = len(batch)
         q_pad = _next_pow2(q_n)  # pow2 query bucket bounds jit retraces
         qlo, qhi = batch.bounds_columnar(self.m, q_pad)
+        # (Q, n_leaves); padding queries are match-all -> dropped
         leaf_mask = ops.device_get(prune_hierarchy_batch(
             self.levels_lo, self.levels_hi,
             jnp.asarray(qlo), jnp.asarray(qhi), fanout=self.fanout,
-        ))[:q_n]  # (Q, n_leaves); padding queries are match-all -> dropped
+        ), stage="launch", path=self.name)[:q_n]
         qids, bids = np.nonzero(leaf_mask)
         self.last_visited_blocks = int(qids.size)
         return launch_visits_batch(

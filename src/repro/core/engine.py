@@ -122,7 +122,8 @@ class PendingBatch:
     plan_seconds: float
     launch_seconds: float
     version: int
-    # per-bucket (input positions, in-flight device payload | None, finalize)
+    # per-bucket (path, input positions, in-flight device payload | None,
+    # finalize)
     _parts: list = dataclasses.field(default_factory=list)
     stats: Optional[BatchStats] = None
 
@@ -132,8 +133,9 @@ class PendingBatch:
         the sense that ``stats`` records the *last* call; call once."""
         t0 = time.perf_counter()
         results: list = [None] * self.n_queries
-        for idxs, payload, fin in self._parts:
-            host = ops.device_get(payload) if payload is not None else None
+        for meth, idxs, payload, fin in self._parts:
+            host = (ops.device_get(payload, stage="finalize", path=meth)
+                    if payload is not None else None)
             out = fin(host)
             for k, res in zip(idxs, out):
                 results[k] = res
@@ -509,7 +511,7 @@ class MDRQEngine:
                     out = self._path_query_batch(path, sub, spec,
                                                  delta=delta_arg)
                     payload, fin = None, (lambda _h, _out=out: _out)
-            pending._parts.append((idxs, payload, fin))
+            pending._parts.append((meth, idxs, payload, fin))
         pending.launch_seconds = time.perf_counter() - t1
 
         reg = obs_metrics.registry()

@@ -37,26 +37,7 @@ from typing import Any, Callable, Protocol, Union, runtime_checkable
 
 import numpy as np
 
-from repro.obs import tracing as obs_tracing
 from repro.core import types as T
-
-
-def _path_span(path, batch, spec, stage: str | None = None):
-    """Span around one adapter batch execution.
-
-    Returns the shared ``NULL_SPAN`` singleton unless a tracer is active —
-    the ``enabled()`` guard also skips building the attrs dict, so the
-    disabled hot path allocates nothing. ``stage="launch"`` marks the
-    device-stage half of a split execution (the span deliberately does NOT
-    block on the output — it measures dispatch, not compute).
-    """
-    if not obs_tracing.enabled():
-        return obs_tracing.NULL_SPAN
-    if stage is None:
-        return obs_tracing.span("path", path=path.name, n_queries=len(batch),
-                                spec=getattr(spec, "kind", str(spec)))
-    return obs_tracing.span("path", path=path.name, n_queries=len(batch),
-                            spec=getattr(spec, "kind", str(spec)), stage=stage)
 
 
 def supports_launch(path) -> bool:
@@ -266,15 +247,11 @@ class ColumnarScanPath(ScanCost):
 
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
-        with _path_span(self, batch, spec) as sp:
-            out = self._scan.query_batch(batch, spec=spec, delta=delta)
-            sp.block_on(out)
-        return out
+        return self._scan.query_batch(batch, spec=spec, delta=delta)
 
     def launch_batch(self, batch: T.QueryBatch,
                      spec: T.ResultSpec = T.IDS, delta=None) -> tuple:
-        with _path_span(self, batch, spec, stage="launch"):
-            return self._scan.launch_batch(batch, spec=spec, delta=delta)
+        return self._scan.launch_batch(batch, spec=spec, delta=delta)
 
 
 class DistributedScanPath(ScanCost):
@@ -300,15 +277,11 @@ class DistributedScanPath(ScanCost):
 
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
-        with _path_span(self, batch, spec) as sp:
-            out = self._dist.query_batch(batch, spec=spec, delta=delta)
-            sp.block_on(out)
-        return out
+        return self._dist.query_batch(batch, spec=spec, delta=delta)
 
     def launch_batch(self, batch: T.QueryBatch,
                      spec: T.ResultSpec = T.IDS, delta=None) -> tuple:
-        with _path_span(self, batch, spec, stage="launch"):
-            return self._dist.launch_batch(batch, spec=spec, delta=delta)
+        return self._dist.launch_batch(batch, spec=spec, delta=delta)
 
 
 class VerticalScanPath(VerticalScanCost):
@@ -339,17 +312,13 @@ class VerticalScanPath(VerticalScanCost):
 
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
-        with _path_span(self, batch, spec) as sp:
-            out = self._scan_ref().query_batch(batch, partial=True, spec=spec,
-                                               delta=delta)
-            sp.block_on(out)
-        return out
+        return self._scan_ref().query_batch(batch, partial=True, spec=spec,
+                                            delta=delta)
 
     def launch_batch(self, batch: T.QueryBatch,
                      spec: T.ResultSpec = T.IDS, delta=None) -> tuple:
-        with _path_span(self, batch, spec, stage="launch"):
-            return self._scan_ref().launch_batch(batch, partial=True,
-                                                 spec=spec, delta=delta)
+        return self._scan_ref().launch_batch(batch, partial=True,
+                                             spec=spec, delta=delta)
 
 
 class BlockedIndexPath(TreeCost):
@@ -374,15 +343,11 @@ class BlockedIndexPath(TreeCost):
 
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
-        with _path_span(self, batch, spec) as sp:
-            out = self._index.query_batch(batch, spec=spec, delta=delta)
-            sp.block_on(out)
-        return out
+        return self._index.query_batch(batch, spec=spec, delta=delta)
 
     def launch_batch(self, batch: T.QueryBatch,
                      spec: T.ResultSpec = T.IDS, delta=None) -> tuple:
-        with _path_span(self, batch, spec, stage="launch"):
-            return self._index.launch_batch(batch, spec=spec, delta=delta)
+        return self._index.launch_batch(batch, spec=spec, delta=delta)
 
 
 class VAFilePath(VAFileCost):
@@ -408,15 +373,11 @@ class VAFilePath(VAFileCost):
 
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
-        with _path_span(self, batch, spec) as sp:
-            out = self._vafile.query_batch(batch, spec=spec, delta=delta)
-            sp.block_on(out)
-        return out
+        return self._vafile.query_batch(batch, spec=spec, delta=delta)
 
     def launch_batch(self, batch: T.QueryBatch,
                      spec: T.ResultSpec = T.IDS, delta=None) -> tuple:
-        with _path_span(self, batch, spec, stage="launch"):
-            return self._vafile.launch_batch(batch, spec=spec, delta=delta)
+        return self._vafile.launch_batch(batch, spec=spec, delta=delta)
 
 
 class PerQueryPath:
@@ -456,20 +417,19 @@ class PerQueryPath:
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
         spec = T.validate_mode(spec)
-        with _path_span(self, batch, spec):
-            if delta is not None and not delta.is_empty:
-                return self._query_batch_delta(batch, spec, delta)
-            if spec.kind == "ids":
-                return [self.query(batch[k]) for k in range(len(batch))]
-            if spec.kind == "count":
-                # the impl's own count (device-reduced where it has one)
-                return [self.count(batch[k]) for k in range(len(batch))]
-            if self._cols is None:
-                raise ValueError(
-                    f"path {self.name!r} has no host columns for result spec "
-                    f"{spec.kind!r}; construct PerQueryPath(..., cols=...)")
-            return [spec.from_ids(self.query(batch[k]), self._cols)
-                    for k in range(len(batch))]
+        if delta is not None and not delta.is_empty:
+            return self._query_batch_delta(batch, spec, delta)
+        if spec.kind == "ids":
+            return [self.query(batch[k]) for k in range(len(batch))]
+        if spec.kind == "count":
+            # the impl's own count (device-reduced where it has one)
+            return [self.count(batch[k]) for k in range(len(batch))]
+        if self._cols is None:
+            raise ValueError(
+                f"path {self.name!r} has no host columns for result spec "
+                f"{spec.kind!r}; construct PerQueryPath(..., cols=...)")
+        return [spec.from_ids(self.query(batch[k]), self._cols)
+                for k in range(len(batch))]
 
     def _query_batch_delta(self, batch: T.QueryBatch, spec: T.ResultSpec,
                            delta) -> Results:

@@ -168,7 +168,8 @@ class VAFile:
             self.packed_dev, jnp.asarray(cell_lo), jnp.asarray(cell_hi),
             m=self.m, tile_n=self.tile_n, block_n=self.tile_n,
         )
-        surv = ops.device_get(block_any)[:q_n]  # padding queries drop
+        surv = ops.device_get(block_any, stage="launch",
+                              path="vafile")[:q_n]  # padding queries drop
         qids, bids = np.nonzero(surv)
         return qids.astype(np.int32), bids.astype(np.int32)
 

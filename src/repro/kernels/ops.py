@@ -95,6 +95,7 @@ def default_interpret() -> bool:
 # launch-budget tests run unchanged against the new backend.
 
 from repro.obs import metrics as _obs_metrics
+from repro.obs import tracing as _tracing
 
 _LAUNCH_FAMILY = "mdrq_launches_total"
 _LAUNCH_HELP = ("Kernel launches (and device->host transfers, op=host_sync) "
@@ -136,17 +137,37 @@ def reset_counters() -> None:
         c.reset()
 
 
-def device_get(x):
+def device_get(x, *, stage=None, path=None):
     """Counted device->host transfer — the host-sync tax the cost model prices.
 
     Accepts a single array or a payload pytree (tuple/list — the ResultSpec
     reducers return e.g. ``(values, indices, counts)``); either way it is one
     logical synchronization, counted once.
+
+    Where a span would record (``obs.tracing.active()``), the transfer runs
+    under a ``sync`` span carrying the payload's device ``bytes`` and the
+    caller's ``stage`` (``"launch"`` for a mid-launch survivor sync,
+    ``"finalize"`` for a payload sync) and ``path``; otherwise no span opens
+    and no byte count is taken.
     """
     _bump("host_sync")
+    if not _tracing.active():
+        return _fetch(x)
+    with _tracing.span("sync", bytes=_payload_nbytes(x), stage=stage,
+                       path=path):
+        return _fetch(x)
+
+
+def _fetch(x):
     if isinstance(x, (tuple, list)):
         return jax.device_get(x)
     return np.asarray(x)
+
+
+def _payload_nbytes(x) -> int:
+    """Device bytes of a payload: the sum of its leaves' ``nbytes``."""
+    return int(sum(getattr(leaf, "nbytes", 0)
+                   for leaf in jax.tree_util.tree_leaves(x)))
 
 
 # -- retrace observability ----------------------------------------------------

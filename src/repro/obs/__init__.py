@@ -6,8 +6,9 @@ Four pieces, layered bottom-up:
     registry with JSONL + Prometheus exporters. The kernel layer's
     launch/host-sync counters are one backend of this registry.
   * ``obs.tracing``  — the span API (``obs.span("kernel", path=...)``) with
-    device-sync-aware close, plus the ``QueryTrace``/``BatchTrace`` records
-    ``MDRQEngine.query_batch(..., trace=True)`` emits.
+    device-sync-aware close and a second sink on the JAX profiler's clock
+    (``obs.to_profiler(True)``), plus the ``QueryTrace``/``BatchTrace``
+    records ``MDRQEngine.query_batch(..., trace=True)`` emits.
   * ``obs.querylog`` — the bounded reservoir-sampled query log
     ``MDRQServer`` keeps (the learned-path training input).
   * ``obs.audit``    — estimated-vs-observed drift report per (path x
@@ -25,12 +26,12 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                registry)
 from repro.obs.querylog import QueryLog, QueryLogEntry
 from repro.obs.tracing import (NULL_SPAN, BatchTrace, QueryTrace, Span,
-                               Tracer, enabled, span)
+                               Tracer, enabled, span, to_profiler)
 
 __all__ = [
     "AuditCell", "DriftReport", "audit", "calibration_samples",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "registry",
     "QueryLog", "QueryLogEntry",
     "NULL_SPAN", "BatchTrace", "QueryTrace", "Span", "Tracer", "enabled",
-    "span",
+    "span", "to_profiler",
 ]

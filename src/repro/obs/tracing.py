@@ -17,16 +17,26 @@ jit-traced Python: that code runs once at trace time and never again, so the
 span would time tracing, not execution ("no trace-time capture"). Wrap the
 jitted call, never the jitted body.
 
-Cost when disabled is one module-global load and an ``is None`` check:
-``span(...)`` returns the shared ``NULL_SPAN`` singleton — no object is
-allocated on the hot path, which is what keeps ``trace=False`` execution at
-zero overhead.
+Two sinks, one API. A span records into the calling thread's ``Tracer``
+(the span tree ``query_batch(trace=True)`` builds) and, while the
+process-wide switch ``to_profiler(True)`` is on, into the JAX profiler: every
+``span(name, **attrs)`` on any thread then also opens a
+``jax.profiler.TraceAnnotation(f"mdrq.{name}", **attrs)``, so the span lands
+on the profiler's host planes on the device trace's clock, with its
+attributes as event stats (DESIGN.md §10). Attributes are ints or short
+strings; ``None`` values are left out of the annotation.
 
-Launch/host-sync attribution: every span snapshots the metrics registry's
-``mdrq_launches_total`` family at open and close (the same counters
-``kernels.ops`` bumps and tests assert budgets on), so a span knows exactly
-how many kernel launches and host syncs happened under it — wall-clock
-measurements on CPU cannot see either.
+Cost when both sinks are off is one thread-local lookup and one
+module-global load: ``span(...)`` returns the shared ``NULL_SPAN``
+singleton — no object is allocated on the hot path, which is what keeps
+``trace=False`` execution at zero overhead.
+
+Launch/host-sync attribution: every ``Tracer`` span snapshots the metrics
+registry's ``mdrq_launches_total`` family at open and close (the same
+counters ``kernels.ops`` bumps and tests assert budgets on), so a span knows
+exactly how many kernel launches and host syncs happened under it —
+wall-clock measurements on CPU cannot see either. Profiler-only spans skip
+the snapshot: nothing reads it there.
 
 ``QueryTrace``/``BatchTrace`` are the records ``MDRQEngine.query_batch(...,
 trace=True)`` produces: per query, the planner's chosen path, realized
@@ -42,12 +52,16 @@ import threading as _threading
 import time
 from typing import Any, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import metrics as _metrics
 
 # The one counter family the kernel layer bumps (see kernels/ops.py); the
 # device->host sync pseudo-op lives in the same family under this op label.
 LAUNCH_FAMILY = "mdrq_launches_total"
 HOST_SYNC_OP = "host_sync"
+# What the profiler sink names a span: ``mdrq.<span name>``.
+PROFILER_PREFIX = "mdrq."
 
 
 def _launch_snapshot() -> tuple[float, float]:
@@ -66,7 +80,7 @@ class Span:
     """One timed region. Context manager; closes device-sync-aware."""
 
     __slots__ = ("name", "attrs", "seconds", "children", "launches",
-                 "host_syncs", "_tracer", "_t0", "_c0", "_pending")
+                 "host_syncs", "_tracer", "_t0", "_c0", "_pending", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict[str, Any]):
         self.name = name
@@ -79,9 +93,11 @@ class Span:
         self._t0 = 0.0
         self._c0 = (0.0, 0.0)
         self._pending: list = []
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
-        """Attach attributes after open (result counts, bucket sizes, ...)."""
+        """Attach attributes after open (result counts, bucket sizes, ...).
+        The profiler sink only sees the attributes given at open."""
         self.attrs.update(attrs)
         return self
 
@@ -92,6 +108,9 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        if _ANNOTATION is not None:
+            self._ann = _annotation(self.name, self.attrs)
+            self._ann.__enter__()
         self._c0 = _launch_snapshot()
         self._t0 = time.perf_counter()
         return self
@@ -105,6 +124,9 @@ class Span:
         c1 = _launch_snapshot()
         self.launches = int(c1[0] - self._c0[0])
         self.host_syncs = int(c1[1] - self._c0[1])
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         self._tracer._pop(self)
 
     def find(self, name: str) -> list["Span"]:
@@ -142,30 +164,83 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+
+class _ProfilerSpan(TraceAnnotation):
+    """A span that only the profiler records (no ``Tracer`` on the thread).
+    It does not block on ``block_on`` values: turning the sink on must not
+    add syncs."""
+
+    def set(self, **attrs) -> "_ProfilerSpan":
+        return self
+
+    def block_on(self, x) -> None:
+        return None
+
+
+# The profiler sink: None while ``to_profiler`` is off, else the annotation
+# class ``span()`` opens. One process-wide switch, read by every thread.
+_ANNOTATION = None
+
+
+def _annotation(name: str, attrs: dict):
+    return _ANNOTATION(PROFILER_PREFIX + name,
+                       **{k: v for k, v in attrs.items() if v is not None})
+
+
+def to_profiler(on: bool) -> bool:
+    """Turn the profiler sink on or off for every thread; returns the
+    previous setting. While on, each ``span()`` also writes a
+    ``TraceAnnotation`` named ``mdrq.<name>``, which a running
+    ``jax.profiler`` trace records (and which costs about a microsecond
+    when none runs)."""
+    global _ANNOTATION
+    prev = _ANNOTATION is not None
+    _ANNOTATION = _ProfilerSpan if on else None
+    return prev
+
+
 # The active tracer, *per thread*. The pipelined server (DESIGN.md §13) runs
 # a dedicated finalizer thread; a process-global tracer would let that
 # thread's spans interleave into the admission thread's span stack and
 # corrupt the tree. Thread-local means: a Tracer installed on one thread
 # sees exactly that thread's spans; other threads' span() calls return
 # NULL_SPAN. (An async server would swap this for a contextvar.)
-_TLS = _threading.local()
+class _Local(_threading.local):
+    # A class-level default: reading ``tracer`` on a thread that never set
+    # it returns None without raising, where a ``getattr(..., None)`` miss
+    # costs an AttributeError, about a microsecond, on every span.
+    tracer: Optional["Tracer"] = None
+
+
+_TLS = _Local()
 
 
 def enabled() -> bool:
-    return getattr(_TLS, "tracer", None) is not None
+    """Whether a ``Tracer`` is installed on the calling thread."""
+    return _TLS.tracer is not None
+
+
+def active() -> bool:
+    """Whether a span opened on the calling thread records anywhere (a
+    ``Tracer`` here, or the profiler sink): the guard for attributes that
+    cost something to compute."""
+    return _ANNOTATION is not None or _TLS.tracer is not None
 
 
 def span(name: str, **attrs):
-    """Open a span under the calling thread's active tracer, or the no-op
-    singleton when tracing is disabled on this thread."""
-    t = getattr(_TLS, "tracer", None)
+    """Open a span under the calling thread's active tracer and, while
+    ``to_profiler`` is on, in the profiler; the no-op singleton when neither
+    records."""
+    t = _TLS.tracer
     if t is None:
-        return NULL_SPAN
+        if _ANNOTATION is None:
+            return NULL_SPAN
+        return _annotation(name, attrs)
     return Span(t, name, attrs)
 
 
 def current() -> Optional["Tracer"]:
-    return getattr(_TLS, "tracer", None)
+    return _TLS.tracer
 
 
 class Tracer:
@@ -178,7 +253,7 @@ class Tracer:
         self._prev: Optional[Tracer] = None
 
     def __enter__(self) -> "Tracer":
-        self._prev = getattr(_TLS, "tracer", None)
+        self._prev = _TLS.tracer
         _TLS.tracer = self
         return self
 

@@ -29,6 +29,14 @@ once ``(backlog depth + 1) x EWMA batch seconds`` exceeds
 in time instead of growing an unbounded queue. Sheds are visible in
 ``ServerStats.shed_counts`` and ``mdrq_server_shed_total``.
 
+**Spans** (``obs.tracing``; on the profiler's clock while
+``obs.to_profiler(True)`` is on): the admission thread opens ``flush``
+(``window``, ``reason``, ``n_queries``) around the launch and
+``backlog_put`` around the blocking hand-off; the finalizer thread opens
+``finalize`` (``window``, ``n_queries``) around the payload syncs, the host
+finalizers and the window's stats. ``window`` is a per-server sequence
+number that links one window's two stages across the threads.
+
 Threading contract (enforced by mdrqlint's ``thread-boundary`` rule):
 device values cross threads only *inside* a ``PendingBatch`` riding the
 backlog queue; ``ops.device_get`` runs only on the finalizer thread; stage
@@ -122,6 +130,8 @@ class _Window:
     batch: PendingBatch
     t_flush: float         # device-stage start (queue latency anchor)
     launch_seconds: float  # device-stage wall (plan + dispatch)
+    window: int            # per-server sequence number: links its two
+    #                        stages' spans (``flush`` and ``finalize``)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -195,6 +205,7 @@ class PipelinedMDRQServer(MDRQServer):
         self._backlog: "queue.Queue[Optional[_Window]]" = \
             queue.Queue(maxsize=backlog)
         self._ewma_batch_s = 0.0   # finalizer-thread-only writer
+        self._window_seq = 0       # admission-thread-only writer
         self._wall_t0: Optional[float] = None
         self._closed = False
         self._warmup_enabled = bool(warmup)
@@ -341,10 +352,12 @@ class PipelinedMDRQServer(MDRQServer):
             return 0
         pending, self._pending = self._pending, []
         queries = [q for q, _, _ in pending]
+        self._window_seq += 1
+        window = self._window_seq
         t0 = time.perf_counter()
         try:
-            with obs_tracing.span("flush", reason=reason,
-                                  n_queries=len(pending), stage="device"):
+            with obs_tracing.span("flush", window=window, reason=reason,
+                                  n_queries=len(pending)):
                 pb = self.engine.launch_batch(queries, method=self.method,
                                               spec=self.spec)
         except Exception:
@@ -355,8 +368,9 @@ class PipelinedMDRQServer(MDRQServer):
         for _, ticket, _ in pending:
             ticket._inflight = True
         win = _Window(pending=pending, reason=reason, batch=pb,
-                      t_flush=t0, launch_seconds=launch_s)
-        self._backlog.put(win)   # blocks when full: backpressure
+                      t_flush=t0, launch_seconds=launch_s, window=window)
+        with obs_tracing.span("backlog_put", window=window):
+            self._backlog.put(win)   # blocks when full: backpressure
         self.stats.flush_reasons[reason] = \
             self.stats.flush_reasons.get(reason, 0) + 1
         obs.registry().counter(
@@ -380,15 +394,14 @@ class PipelinedMDRQServer(MDRQServer):
                 return
             t0 = time.perf_counter()
             try:
-                with obs_tracing.span("pipeline_finalize",
-                                      n_queries=len(win.pending),
-                                      stage="finalize"):
+                with obs_tracing.span("finalize", window=win.window,
+                                      n_queries=len(win.pending)):
                     results = win.batch.finalize()
-                for (_, ticket, _), res in zip(win.pending, results):
-                    ticket._result = res
-                    ticket._done = True
-                self._record_window(win, results,
-                                    time.perf_counter() - t0)
+                    for (_, ticket, _), res in zip(win.pending, results):
+                        ticket._result = res
+                        ticket._done = True
+                    self._record_window(win, results,
+                                        time.perf_counter() - t0)
             except Exception as e:
                 for _, ticket, _ in win.pending:
                     ticket._error = e
