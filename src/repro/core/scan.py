@@ -5,7 +5,7 @@ Three scan flavors, mirroring the paper's contestants:
   * ``ColumnarScan.query``          — complete-match scan over the columnar
     layout via the ``range_scan`` Pallas kernel (vectorized, all dims fused).
   * ``ColumnarScan.query_partial``  — partial-match scan via the
-    ``range_scan_vertical`` kernel: touches only queried dimensions' columns
+    ``range_scan_vertical`` kernel: compares only the queried dimensions
     (the paper's vertical-partitioning advantage, §5.5).
   * ``RowScan.query``               — row-major layout scan (the paper's
     horizontal partitioning, §5.4) — kept for the layout ablation.
@@ -73,13 +73,11 @@ class ColumnarScan:
 
     def mask_partial(self, q: T.RangeQuery) -> np.ndarray:
         """(n,) bool mask touching only the queried dimensions."""
-        dims = np.nonzero(q.dims_mask)[0].astype(np.int32)
-        if dims.size == 0:
+        if not q.dims_mask.any():
             return np.ones((self.n,), bool)
         qlo, qhi = ops.query_bounds_device(q, self.data_dev.shape[0], self.data_dev.dtype)
-        out = ops.range_scan_vertical(
-            self.data_dev, jnp.asarray(dims), qlo, qhi, tile_n=self.tile_n
-        )
+        out = ops.range_scan_vertical(self.data_dev, qlo, qhi,
+                                      tile_n=self.tile_n)
         return ops.device_get(out)[: self.n] > 0
 
     def query(self, q: T.RangeQuery) -> np.ndarray:
@@ -97,13 +95,11 @@ class ColumnarScan:
 
     def count_partial(self, q: T.RangeQuery) -> int:
         """Match count touching only the queried dimensions' columns."""
-        dims = np.nonzero(q.dims_mask)[0].astype(np.int32)
-        if dims.size == 0:
+        if not q.dims_mask.any():
             return self.n
         qlo, qhi = ops.query_bounds_device(q, self.data_dev.shape[0], self.data_dev.dtype)
-        out = ops.range_scan_vertical(
-            self.data_dev, jnp.asarray(dims), qlo, qhi, tile_n=self.tile_n
-        )
+        out = ops.range_scan_vertical(self.data_dev, qlo, qhi,
+                                      tile_n=self.tile_n)
         return int(ops.device_get(ops.mask_counts(out)))
 
     # -- batched execution (fused multi-query kernels) ---------------------
@@ -122,15 +118,22 @@ class ColumnarScan:
     def _mask_batch_device(self, batch: T.QueryBatch, partial: bool) -> jax.Array:
         """(q_pad, n_pad) device masks from one fused launch (rows >= Q and
         columns >= n are padding; object padding never matches)."""
-        q_pad, lo, up = bucketed_batch_bounds(batch, self.data_dev.shape[0],
-                                              self.data_dev.dtype)
+        q_pad, lo, up = self._launch_bounds(batch, partial)
         if partial:
-            dim_ids = batch.padded_dim_ids(q_pad)
-            return ops.multi_range_scan_vertical(
-                self.data_dev, jnp.asarray(dim_ids), lo, up,
-                tile_n=self.tile_n,
-            )
+            return ops.multi_range_scan_vertical(self.data_dev, lo, up,
+                                                 tile_n=self.tile_n)
         return ops.multi_range_scan(self.data_dev, lo, up, tile_n=self.tile_n)
+
+    def _launch_bounds(self, batch: T.QueryBatch, partial: bool
+                       ) -> tuple[int, jax.Array, jax.Array]:
+        """``bucketed_batch_bounds`` of one scan launch, whose compared and
+        skipped (chunk, row) pairs are counted here on the host."""
+        m_pad = self.data_dev.shape[0]
+        q_pad, lo, up = bucketed_batch_bounds(batch, m_pad,
+                                              self.data_dev.dtype)
+        ops.count_scan_rows("vertical" if partial else "full",
+                            batch.dims_mask, q_pad, m_pad)
+        return q_pad, lo, up
 
     def count_batch(self, batch: T.QueryBatch, partial: bool = False
                     ) -> list[int]:
@@ -170,17 +173,15 @@ class ColumnarScan:
         exactly the synchronous path with an unchanged launch/sync budget.
         """
         spec = T.validate_mode(spec).validate(self.m)
-        q_pad, lo, up = bucketed_batch_bounds(batch, self.data_dev.shape[0],
-                                              self.data_dev.dtype)
+        q_pad, lo, up = self._launch_bounds(batch, partial)
         dcm = tomb = None
         if delta is not None and not delta.is_empty:
             dcm = delta.device_cm(self.tile_n)
             tomb = delta.base_tomb_dev(self.data_dev.shape[1])
         if partial:
-            dim_ids = batch.padded_dim_ids(q_pad)
             payload = ops.multi_scan_vertical_reduce(
-                self.data_dev, jnp.asarray(dim_ids), lo, up, dcm, tomb,
-                spec=spec, tile_n=self.tile_n)
+                self.data_dev, lo, up, dcm, tomb, spec=spec,
+                tile_n=self.tile_n)
         else:
             payload = ops.multi_scan_reduce(self.data_dev, lo, up, dcm, tomb,
                                             spec=spec, tile_n=self.tile_n)

@@ -710,25 +710,6 @@ class QueryBatch:
         up[: self.m, : len(self)] = self.upper.T
         return finite_query_bounds(lo, up, dtype=dtype)
 
-    def padded_dim_ids(self, q_pad: int | None = None) -> np.ndarray:
-        """(q_pad or Q, D_max) int32 constrained-dim ids for the batched
-        vertical scan.
-
-        Shorter rows pad by repeating the query's own last constrained dim
-        (AND is idempotent); a fully unconstrained query — and any padding
-        query row — uses dim 0, whose bounds column is match-all. D_max
-        rounds to a pow2 to bound jit retraces.
-        """
-        mask = self.dims_mask
-        d_max = next_pow2(max(1, int(mask.sum(axis=1).max(initial=0))))
-        ids = np.zeros((q_pad or len(self), d_max), np.int32)
-        for k in range(len(self)):
-            d = np.nonzero(mask[k])[0].astype(np.int32)
-            if d.size == 0:
-                d = np.zeros((1,), np.int32)
-            ids[k] = np.pad(d, (0, d_max - d.size), mode="edge")
-        return ids
-
 
 @dataclasses.dataclass
 class Dataset:

@@ -8,15 +8,18 @@ evaluate a (Q, m) batch of query boxes against the (m, n) columnar dataset in
 one launch, so the fixed overheads amortize over Q and each VMEM data tile is
 fetched from HBM *once* and compared against all Q queries while resident.
 
-Three variants, mirroring the single-query entry points in ``range_scan``:
+Two kernels:
 
-  * ``multi_scan_tiles``    — fused full scan: grid ``(n_tiles,)``, one
-    (m_pad, tile_n) data tile and one (Q, tile_n) int8 mask block per step.
-  * ``multi_scan_vertical`` — batched partial-match scan: grid
-    ``(n_tiles, n_groups)`` fetching only the 8-dim sublane groups that some
-    query of the batch constrains (the paper's vertical partitioning at the
-    TPU's sublane granularity).
-  * ``multi_scan_visit``    — batched two-phase refinement: a flattened
+  * ``multi_scan_tiles`` — fused scan, complete- or partial-match: grid
+    ``(n_tiles,)``, one (m_pad, tile_n) data tile and one (Q, tile_n) int8
+    mask block per step. The scan is bound by VPU compares, not by bytes, so
+    each chunk of ``INT8_SUBLANES`` queries compares only the dimension rows
+    some query of it bounds (``chunk_flags``, listed in SMEM); the other rows
+    carry match-all bounds for the whole chunk and would decide nothing. The
+    batched vertical (partial-match) scan is this kernel too: fetching only
+    the 8-dim sublane groups a batch needs saved bytes the kernel was not
+    short of, and its extra grid steps cost more than they saved.
+  * ``multi_scan_visit`` — batched two-phase refinement: a flattened
     (query_id, block_id) visit list drives scattered tile scans for *all*
     queries of a batch in one launch (kd-tree / R*-tree / VA-file phase 2).
 
@@ -29,6 +32,8 @@ query-minor, ``(m_pad, Q)``; the wrappers transpose the tiny arrays.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -38,34 +43,143 @@ from repro.kernels.range_scan import (DEFAULT_TILE_N, INT8_SUBLANES, LANES,
                                       SUBLANES)
 
 
-def _match_rows(x, lo, up):
-    """(Q, TN) bool: AND over the rows of an (r, TN) data tile against
-    (Q, r) query-major bounds. Each data row broadcasts over the query
-    sublanes and each query's bound over the lanes."""
-    acc = None
-    for d in range(x.shape[0]):
-        row = x[d:d + 1, :]
-        ok = jnp.logical_and(row >= lo[:, d:d + 1], row <= up[:, d:d + 1])
-        acc = ok if acc is None else jnp.logical_and(acc, ok)
-    return acc
+# A chunk that flags every row compares them in straight-line code, as the
+# chunks of a tile where all are dense do together. Any other chunk
+# compares the first HEAD entries of its row list in straight-line code,
+# then the rest TAIL at a time in a loop: a loop step costs about as much as
+# two row compares on a v5e (PERF.md).
+HEAD, TAIL = SUBLANES, 2
 
 
-def _store_matches(out_ref, x, lower_ref, upper_ref, *, merge=None):
-    """Write (Q, TN) int8 matches of tile ``x`` in int8-tile row chunks;
-    with ``merge`` (a traced bool) AND them into what ``out_ref`` holds."""
-    q_n = out_ref.shape[0]
-    for q0 in range(0, q_n, INT8_SUBLANES):
-        q1 = min(q0 + INT8_SUBLANES, q_n)
-        ok = _match_rows(x, lower_ref[q0:q1, :], upper_ref[q0:q1, :])
-        if merge is not None:
-            prev = out_ref[q0:q1, :] != 0
-            ok = jnp.logical_and(ok, jnp.logical_or(prev, jnp.logical_not(merge)))
-        out_ref[q0:q1, :] = ok.astype(jnp.int8)
+def chunk_flags(bound, xp=jnp):
+    """(n_chunks, m_pad) int32: which rows each chunk of ``INT8_SUBLANES``
+    queries compares.
+
+    ``bound`` is (Q, m_pad) bool, True where a query bounds a row. A row is
+    flagged where any query of its chunk bounds it. A chunk that bounds
+    nothing compares row 0, so the object padding (+inf on every row) is
+    still rejected there. A chunk whose loop would run over more than half
+    the rows past its head compares them all, as does every chunk of a
+    tile no taller than the head: a loop step costs about two row compares,
+    so such a loop costs more than the rows it skips (PERF.md). ``xp`` is
+    ``jnp`` inside a jit and ``np`` on the host, so the kernel and the
+    host's row counters agree.
+    """
+    q_n, m_pad = bound.shape
+    rows = min(INT8_SUBLANES, q_n)
+    n_chunks = -(-q_n // rows)
+    bound = xp.pad(bound, ((0, n_chunks * rows - q_n), (0, 0)))
+    flags = bound.reshape(n_chunks, rows, m_pad).any(axis=1)
+    row0 = xp.arange(m_pad) == 0
+    flags = flags | (row0 & ~flags.any(axis=1, keepdims=True))
+    loop = flags.sum(axis=1, keepdims=True) - HEAD
+    flags = flags | (2 * loop > m_pad - HEAD) | (m_pad <= HEAD)
+    return flags.astype(xp.int32)
 
 
-def _multi_scan_kernel(lower_ref, upper_ref, data_ref, out_ref):
-    """Compare one (m_pad, TN) data tile against every query's bounds."""
-    _store_matches(out_ref, data_ref[...], lower_ref, upper_ref)
+def _bound_rows(lo_t, up_t):
+    """(Q, m_pad) bool from query-major bounds in the data's dtype: False only
+    on a match-all row (the dtype's finite extrema, which every finite value
+    satisfies), so skipping it decides nothing."""
+    info = jnp.finfo(lo_t.dtype)
+    return jnp.logical_or(lo_t != info.min, up_t != info.max)
+
+
+def _chunks(q_n):
+    """(chunk, q0, q1) of the int8-tile query-row chunks of a (q_n, ·) block."""
+    rows = min(INT8_SUBLANES, q_n)
+    return [(c, q0, min(q0 + rows, q_n))
+            for c, q0 in enumerate(range(0, q_n, rows))]
+
+
+def _row_list(flags):
+    """Each chunk's flagged rows: (n_chunks, m_pad) int32 row numbers, flagged
+    ones first and ascending, the tail repeating the last flagged row (AND is
+    idempotent); and (n_chunks,) int32 counts."""
+    m_pad = flags.shape[1]
+    order = jnp.argsort(1 - flags, axis=-1, stable=True)
+    counts = flags.sum(axis=-1)
+    pos = jnp.minimum(jnp.arange(m_pad), counts[:, None] - 1)
+    rows = jnp.take_along_axis(order, pos, axis=-1)
+    return rows.astype(jnp.int32), counts.astype(jnp.int32)
+
+
+def _listed(b, rows):
+    """(Q, m_pad) bounds whose column k holds each query's bound on entry k
+    of its chunk's row list; a chunk that flags every row keeps its order."""
+    q_n = b.shape[0]
+    per_query = jnp.repeat(rows, min(INT8_SUBLANES, q_n), axis=0)[:q_n]
+    return jnp.take_along_axis(b, per_query, axis=1)
+
+
+def _column(ref, q0, q1, k):
+    """(q1 - q0, 1) column ``k`` of a (Q, m_pad) bounds block: a static lane
+    slice, or for a traced ``k`` a select and a lane reduce."""
+    if isinstance(k, int):
+        return ref[q0:q1, k:k + 1]
+    b = ref[q0:q1, :]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
+    return jnp.max(jnp.where(lanes == k, b, -jnp.inf), axis=1, keepdims=True)
+
+
+def _multi_scan_kernel(rows_ref, counts_ref, lower_ref, upper_ref, data_ref,
+                       out_ref):
+    """Compare one (m_pad, TN) data tile against every query's bounds, on the
+    rows each query chunk flags. ``rows_ref`` holds each chunk's row list,
+    and column k of the (Q, m_pad) bounds blocks the bound on its entry k."""
+    m_pad = data_ref.shape[0]
+
+    def all_of(pairs, q0, q1):
+        """(q1 - q0, TN) bool: AND of the compares of each (data row, bounds
+        column k) pair. A row broadcasts over the query sublanes, each
+        query's bound over the lanes; the compares stay in the data's
+        dtype."""
+        ok = None
+        for row, k in pairs:
+            hit = jnp.logical_and(row >= _column(lower_ref, q0, q1, k),
+                                  row <= _column(upper_ref, q0, q1, k))
+            ok = hit if ok is None else jnp.logical_and(ok, hit)
+        return ok
+
+    def every_row(chunks):
+        for _, q0, q1 in chunks:
+            ok = all_of([(data_ref[d:d + 1, :], d) for d in range(m_pad)],
+                        q0, q1)
+            out_ref[q0:q1, :] = ok.astype(jnp.int8)
+
+    def listed_rows(c, q0, q1):
+        listed = c * m_pad
+
+        def rows(k0, n):
+            return [(data_ref[pl.ds(rows_ref[listed + k0 + u], 1), :], k0 + u)
+                    for u in range(n)]
+        # int32 accumulator: Mosaic cannot narrow an int8 vector to bool
+        acc = all_of(rows(0, HEAD), q0, q1).astype(jnp.int32)
+
+        def step(t, acc):
+            return jnp.where(all_of(rows(HEAD + t * TAIL, TAIL), q0, q1),
+                             acc, 0)
+        steps = (counts_ref[c] - HEAD + TAIL - 1) // TAIL
+        acc = jax.lax.fori_loop(0, steps, step, acc)
+        out_ref[q0:q1, :] = acc.astype(jnp.int8)
+
+    chunks = _chunks(out_ref.shape[0])
+    if m_pad <= HEAD:  # every chunk flags every row (``chunk_flags``)
+        every_row(chunks)
+        return
+    dense = [counts_ref[c] == m_pad for c, _, _ in chunks]
+    all_dense = functools.reduce(jnp.logical_and, dense)
+    pl.when(all_dense)(functools.partial(every_row, chunks))
+
+    @pl.when(jnp.logical_not(all_dense))
+    def _():
+        for chunk in chunks:
+            if len(chunks) == 1:
+                listed_rows(*chunk)
+                continue
+            pl.when(dense[chunk[0]])(functools.partial(every_row, [chunk]))
+            pl.when(jnp.logical_not(dense[chunk[0]]))(
+                functools.partial(listed_rows, *chunk))
 
 
 def multi_scan_tiles(
@@ -76,11 +190,16 @@ def multi_scan_tiles(
     tile_n: int = DEFAULT_TILE_N,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused full scan of a query batch.
+    """Fused scan of a query batch, complete- or partial-match.
+
+    Each chunk of ``INT8_SUBLANES`` queries compares only the rows some query
+    of it bounds (``chunk_flags``, listed in SMEM); the rest carry match-all
+    bounds for the whole chunk and would decide nothing.
 
     Args:
       data_cm: (m_pad, n_pad) columnar data; m_pad % 8 == 0, n_pad % tile_n == 0.
-      lower, upper: (m_pad, Q) finite bounds, one column per query.
+      lower, upper: (m_pad, Q) finite bounds, one column per query, match-all
+        on the rows a query leaves unconstrained.
 
     Returns:
       (Q, n_pad) int8 match masks, row q = query q.
@@ -90,92 +209,25 @@ def multi_scan_tiles(
     assert m_pad % SUBLANES == 0, m_pad
     assert n_pad % tile_n == 0 and tile_n % LANES == 0, (n_pad, tile_n)
     assert lower.shape == (m_pad, q_n) and upper.shape == (m_pad, q_n)
-
-    grid = (n_pad // tile_n,)
-    return pl.pallas_call(
-        _multi_scan_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((q_n, m_pad), lambda i: (0, 0)),
-            pl.BlockSpec((q_n, m_pad), lambda i: (0, 0)),
-            pl.BlockSpec((m_pad, tile_n), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((q_n, tile_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((q_n, n_pad), jnp.int8),
-        interpret=interpret,
-    )(lower.T.astype(data_cm.dtype), upper.T.astype(data_cm.dtype), data_cm)
-
-
-def _multi_vertical_kernel(group_ids_ref, lower_ref, upper_ref, data_ref,
-                           out_ref):
-    """One grid step = (tile, dim group); AND-merge in place over groups."""
-    j = pl.program_id(1)
-    _store_matches(out_ref, data_ref[...], lower_ref, upper_ref, merge=j > 0)
-
-
-def _needed_groups(dim_ids: jax.Array, n_groups: int) -> jax.Array:
-    """(n_groups,) int32 ascending ids of the sublane groups any query of the
-    batch constrains, padded by repeating the last one. A repeated block
-    index is not fetched again, and AND is idempotent."""
-    need = jnp.zeros((n_groups,), jnp.bool_).at[
-        dim_ids.reshape(-1) // SUBLANES].set(True)
-    order = jnp.sort(jnp.where(need, jnp.arange(n_groups), n_groups))
-    last = order[jnp.sum(need) - 1]
-    return jnp.where(order < n_groups, order, last).astype(jnp.int32)
-
-
-def multi_scan_vertical(
-    data_cm: jax.Array,
-    dim_ids: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = DEFAULT_TILE_N,
-    interpret: bool = False,
-) -> jax.Array:
-    """Batched partial-match vertical scan.
-
-    Only the (8, tile_n) sublane groups holding a dim that some query
-    constrains are read from HBM; every query is compared on every dim of
-    those groups, which is exact because unconstrained dims carry match-all
-    bounds.
-
-    Args:
-      data_cm: (m_pad, n_pad) columnar data.
-      dim_ids: (Q, D_max) int32 per-query constrained-dimension ids (padding
-        repeats one of the query's own dims; a match-all query uses dim 0).
-      lower, upper: (m_pad, Q) finite bounds, match-all on unconstrained dims.
-
-    Returns:
-      (Q, n_pad) int8 match masks over each query's constrained dims.
-    """
-    m_pad, n_pad = data_cm.shape
-    q_n = dim_ids.shape[0]
-    assert m_pad % SUBLANES == 0, m_pad
-    assert n_pad % tile_n == 0 and tile_n % LANES == 0, (n_pad, tile_n)
-    assert lower.shape == (m_pad, q_n) and upper.shape == (m_pad, q_n)
-    n_groups = m_pad // SUBLANES
-
-    def grouped(b):  # (m_pad, Q) -> (n_groups, Q, 8): one block per group
-        return b.T.astype(data_cm.dtype).reshape(
-            q_n, n_groups, SUBLANES).transpose(1, 0, 2)
+    lo_t, up_t = lower.T.astype(data_cm.dtype), upper.T.astype(data_cm.dtype)
+    rows, counts = _row_list(chunk_flags(_bound_rows(lo_t, up_t)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_pad // tile_n, n_groups),
+        num_scalar_prefetch=2,
+        grid=(n_pad // tile_n,),
         in_specs=[
-            pl.BlockSpec((None, q_n, SUBLANES), lambda i, j, g: (g[j], 0, 0)),
-            pl.BlockSpec((None, q_n, SUBLANES), lambda i, j, g: (g[j], 0, 0)),
-            pl.BlockSpec((SUBLANES, tile_n), lambda i, j, g: (g[j], i)),
+            pl.BlockSpec((q_n, m_pad), lambda i, r, c: (0, 0)),
+            pl.BlockSpec((q_n, m_pad), lambda i, r, c: (0, 0)),
+            pl.BlockSpec((m_pad, tile_n), lambda i, r, c: (0, i)),
         ],
-        out_specs=pl.BlockSpec((q_n, tile_n), lambda i, j, g: (0, i)),
+        out_specs=pl.BlockSpec((q_n, tile_n), lambda i, r, c: (0, i)),
     )
     return pl.pallas_call(
-        _multi_vertical_kernel,
+        _multi_scan_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q_n, n_pad), jnp.int8),
         interpret=interpret,
-    )(_needed_groups(dim_ids, n_groups), grouped(lower), grouped(upper),
+    )(rows.reshape(-1), counts, _listed(lo_t, rows), _listed(up_t, rows),
       data_cm)
 
 

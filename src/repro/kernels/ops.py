@@ -137,6 +137,30 @@ def reset_counters() -> None:
         c.reset()
 
 
+# (chunk, row) pairs of the scan kernels: each chunk of ``INT8_SUBLANES``
+# queries compares a dimension row only where ``multi_scan.chunk_flags`` flags
+# it. Counted on the host where ``ColumnarScan`` builds a batch launch, once
+# per launch, in families of their own so that ``counters()`` and the launch
+# budgets do not see them.
+_ROWS_HELP = "(query chunk, dimension row) pairs the scan kernels {}"
+
+
+def count_scan_rows(kernel: str, dims_mask: np.ndarray, q_pad: int,
+                    m_pad: int) -> None:
+    """Count one scan launch's compared and skipped (chunk, row) pairs,
+    labelled ``kernel`` (``"vertical"`` or ``"full"``), from the batch's (Q, m)
+    ``dims_mask`` padded to the launch's (q_pad, m_pad)."""
+    bound = np.zeros((q_pad, m_pad), bool)
+    bound[: dims_mask.shape[0], : dims_mask.shape[1]] = dims_mask
+    flags = _ms.chunk_flags(bound, xp=np)
+    compared = int(flags.sum())
+    for outcome, n in (("compared", compared),
+                       ("skipped", flags.size - compared)):
+        _obs_metrics.registry().counter(
+            f"mdrq_scan_rows_{outcome}_total",
+            help=_ROWS_HELP.format(outcome), kernel=kernel).inc(n)
+
+
 def device_get(x, *, stage=None, path=None):
     """Counted device->host transfer — the host-sync tax the cost model prices.
 
@@ -430,7 +454,6 @@ range_scan_visit = _counted(
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
 def _range_scan_vertical_jit(
     data_cm: jax.Array,
-    dim_ids: jax.Array,
     lower: jax.Array,
     upper: jax.Array,
     *,
@@ -438,19 +461,16 @@ def _range_scan_vertical_jit(
     interpret: bool | None = None,
 ) -> jax.Array:
     note_trace("range_scan_vertical")
-    if use_xla():
-        rows = data_cm[dim_ids]  # touch only the queried dimensions' columns
-        return _ref.range_scan_ref(rows, lower[dim_ids, 0], upper[dim_ids, 0])
     if interpret is None:
         interpret = default_interpret()
-    return _rs.range_scan_vertical(
-        data_cm, dim_ids, lower, upper, tile_n=tile_n, interpret=interpret
-    )
+    # the batched scan at Q=1, which compares only the bounded dims
+    return _multi_scan_masks(data_cm, lower, upper, tile_n=tile_n,
+                             interpret=interpret)[0]
 
 
 range_scan_vertical = _counted(
     "range_scan_vertical",
-    "Partial-match scan touching only queried dims -> (n_pad,) int8.",
+    "Partial-match scan comparing only the bounded dims -> (n_pad,) int8.",
 )(_range_scan_vertical_jit)
 
 
@@ -482,7 +502,6 @@ multi_range_scan = _counted(
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
 def _multi_range_scan_vertical_jit(
     data_cm: jax.Array,
-    dim_ids: jax.Array,
     lower: jax.Array,
     upper: jax.Array,
     *,
@@ -490,18 +509,16 @@ def _multi_range_scan_vertical_jit(
     interpret: bool | None = None,
 ) -> jax.Array:
     note_trace("multi_range_scan_vertical")
-    if use_xla():
-        return _ref.multi_scan_vertical_ref(data_cm, dim_ids, lower, upper)
     if interpret is None:
         interpret = default_interpret()
-    return _ms.multi_scan_vertical(
-        data_cm, dim_ids, lower, upper, tile_n=tile_n, interpret=interpret
-    )
+    return _multi_scan_masks(data_cm, lower, upper, tile_n=tile_n,
+                             interpret=interpret)
 
 
 multi_range_scan_vertical = _counted(
     "multi_range_scan_vertical",
-    "Batched partial-match scan -> (Q, n_pad) int8 masks.",
+    "Batched partial-match scan: the fused scan, counted and named apart "
+    "-> (Q, n_pad) int8 masks.",
 )(_multi_range_scan_vertical_jit)
 
 
@@ -653,6 +670,25 @@ def _multi_scan_masks(data_cm, lower, upper, *, tile_n, interpret):
                                 interpret=interpret)
 
 
+def _scan_reduce(data_cm, lower, upper, delta_cm, base_tomb, *, spec, tile_n,
+                 interpret):
+    """The fused scan, the base tombstones and the spec's reducer, plus the
+    delta's payload where there is a delta (trace-time helper)."""
+    if interpret is None:
+        interpret = default_interpret()
+    mask = _multi_scan_masks(data_cm, lower, upper, tile_n=tile_n,
+                             interpret=interpret)
+    if base_tomb is not None:
+        from repro.kernels import reducers as _red
+        mask = _red.fold_tombstones(mask, base_tomb)
+    base = spec.device_reduce(mask, data_cm, tile_n=tile_n,
+                              interpret=interpret)
+    if delta_cm is None:
+        return base
+    return base, _delta_payload(delta_cm, lower, upper, spec=spec,
+                                tile_n=tile_n, interpret=interpret)
+
+
 def _delta_payload(delta_cm, lower, upper, *, spec, tile_n, interpret):
     """Scan + reduce the delta block with the batch's bounds (same jit)."""
     dmask = _multi_scan_masks(delta_cm, lower, upper, tile_n=tile_n,
@@ -674,19 +710,8 @@ def _multi_scan_reduce_jit(
     interpret: bool | None = None,
 ):
     note_trace("multi_scan_reduce")
-    if interpret is None:
-        interpret = default_interpret()
-    mask = _multi_scan_masks(data_cm, lower, upper, tile_n=tile_n,
-                             interpret=interpret)
-    if base_tomb is not None:
-        from repro.kernels import reducers as _red
-        mask = _red.fold_tombstones(mask, base_tomb)
-    base = spec.device_reduce(mask, data_cm, tile_n=tile_n,
-                              interpret=interpret)
-    if delta_cm is None:
-        return base
-    return base, _delta_payload(delta_cm, lower, upper, spec=spec,
-                                tile_n=tile_n, interpret=interpret)
+    return _scan_reduce(data_cm, lower, upper, delta_cm, base_tomb,
+                        spec=spec, tile_n=tile_n, interpret=interpret)
 
 
 multi_scan_reduce = _counted(
@@ -700,7 +725,6 @@ multi_scan_reduce = _counted(
 @functools.partial(jax.jit, static_argnames=("spec", "tile_n", "interpret"))
 def _multi_scan_vertical_reduce_jit(
     data_cm: jax.Array,
-    dim_ids: jax.Array,
     lower: jax.Array,
     upper: jax.Array,
     delta_cm: jax.Array | None = None,
@@ -711,29 +735,14 @@ def _multi_scan_vertical_reduce_jit(
     interpret: bool | None = None,
 ):
     note_trace("multi_scan_vertical_reduce")
-    if interpret is None:
-        interpret = default_interpret()
-    if use_xla():
-        mask = _ref.multi_scan_vertical_ref(data_cm, dim_ids, lower, upper)
-    else:
-        mask = _ms.multi_scan_vertical(data_cm, dim_ids, lower, upper,
-                                       tile_n=tile_n, interpret=interpret)
-    if base_tomb is not None:
-        from repro.kernels import reducers as _red
-        mask = _red.fold_tombstones(mask, base_tomb)
-    base = spec.device_reduce(mask, data_cm, tile_n=tile_n,
-                              interpret=interpret)
-    if delta_cm is None:
-        return base
-    # The delta is tiny: a full multi-scan over it is exact (unconstrained
-    # dims carry match-all bounds) and avoids a second vertical variant.
-    return base, _delta_payload(delta_cm, lower, upper, spec=spec,
-                                tile_n=tile_n, interpret=interpret)
+    return _scan_reduce(data_cm, lower, upper, delta_cm, base_tomb,
+                        spec=spec, tile_n=tile_n, interpret=interpret)
 
 
 multi_scan_vertical_reduce = _counted(
     "multi_scan_vertical_reduce",
-    "Batched partial-match scan + ResultSpec reducer in one launch.",
+    "Batched partial-match scan + ResultSpec reducer in one launch: "
+    "multi_scan_reduce, counted and named apart.",
 )(_multi_scan_vertical_reduce_jit)
 
 

@@ -19,9 +19,9 @@ Entry points:
     block ids selects which tiles are visited (kd-tree / R-tree / VA-file
     refinement). Grid size = number of visited blocks, so pruned blocks cost
     *nothing* — the TPU analogue of "skip subtrees".
-  * ``range_scan_vertical``  — partial-match scan over the queried dims only.
 
-The last two are the batched kernels of ``multi_scan`` at Q=1.
+``range_scan_visit`` is the batched visit kernel of ``multi_scan`` at Q=1; the
+single-query partial-match scan is ``multi_scan_tiles`` at Q=1 (``ops``).
 """
 from __future__ import annotations
 
@@ -84,34 +84,6 @@ def range_scan_tiles(
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int8),
         interpret=interpret,
     )(lower.astype(data_cm.dtype), upper.astype(data_cm.dtype), data_cm)
-    return out[0]
-
-
-def range_scan_vertical(
-    data_cm: jax.Array,
-    dim_ids: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = DEFAULT_TILE_N,
-    interpret: bool = False,
-) -> jax.Array:
-    """Partial-match vertical scan: touch only the queried dimensions' columns.
-
-    The batched vertical kernel at Q=1: only the 8-dim sublane groups that
-    hold a queried dimension are read.
-
-    Args:
-      data_cm: (m_pad, n_pad) columnar data.
-      dim_ids: (n_qdims,) int32 ids of the queried dimensions.
-      lower, upper: (m_pad, 1) finite bounds, match-all on the other dims.
-
-    Returns:
-      (n_pad,) int8 match mask over the queried dimensions only.
-    """
-    from repro.kernels import multi_scan as _ms  # deferred: _ms imports us
-    out = _ms.multi_scan_vertical(data_cm, dim_ids.reshape(1, -1), lower,
-                                  upper, tile_n=tile_n, interpret=interpret)
     return out[0]
 
 

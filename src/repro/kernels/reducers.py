@@ -18,7 +18,7 @@ tile is fetched from HBM once per batch):
     Mosaic kernel are not a win over XLA's top_k).
   * ``masked_agg_tiles`` — lane-parallel accumulation: every tile step
     revisits one (Q, tile_n) accumulator block (init at tile 0, combine
-    after — the ``multi_scan_vertical`` in-place-merge idiom), leaving a
+    after — an in-place merge over the grid), leaving a
     (Q, tile_n) lane partial whose final cross-lane reduce rides in the
     wrapping jit.
 
@@ -129,8 +129,8 @@ def masked_agg_tiles(
     assert values.shape == (n_pad,), values.shape
     fill = AGG_FILL[op]
 
-    # One (Q, tile_n) accumulator block, revisited by every tile step (the
-    # ``multi_scan_vertical`` in-place-merge idiom); it flushes once.
+    # One (Q, tile_n) accumulator block, revisited by every tile step and
+    # merged in place; it flushes once.
     grid = (n_pad // tile_n,)
     return pl.pallas_call(
         functools.partial(_masked_agg_kernel, op=op, fill=fill),

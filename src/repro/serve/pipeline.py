@@ -144,7 +144,6 @@ class WarmupReport:
 
     paths: tuple[str, ...]
     bucket_sizes: tuple[int, ...]
-    dim_counts: tuple[int, ...]
     spec_kind: str
     n_runs: int
     n_compiled: int
@@ -152,18 +151,16 @@ class WarmupReport:
     keys: tuple
 
 
-def _warm_batch(n_q: int, n_dims: int, m: int) -> T.QueryBatch:
-    """A (n_q, m) warmup batch constraining the first ``n_dims`` dims.
+def _warm_batch(n_q: int, m: int) -> T.QueryBatch:
+    """A (n_q, m) warmup batch constraining every dim.
 
     Constrained dims carry the widest *finite* f32 bounds (finite so they
     count as constrained; widest so tree/VA warmups traverse their largest
-    visit bucket); the rest are +-inf match-alls. Shapes — the only thing an
-    AOT executable is specialized on — match real traffic exactly.
+    visit bucket). Shapes — the only thing an AOT executable is specialized
+    on — match real traffic exactly.
     """
-    lo = np.full((n_q, m), -np.inf, np.float32)
-    up = np.full((n_q, m), np.inf, np.float32)
-    lo[:, :n_dims] = numerics.finite_min(np.float32)
-    up[:, :n_dims] = numerics.finite_max(np.float32)
+    lo = np.full((n_q, m), numerics.finite_min(np.float32), np.float32)
+    up = np.full((n_q, m), numerics.finite_max(np.float32), np.float32)
     return T.QueryBatch(lo, up)
 
 
@@ -254,9 +251,8 @@ class PipelinedMDRQServer(MDRQServer):
         path (all plannable paths under ``method="auto"``, else the explicit
         path), under the server's spec and the engine's *current* delta
         snapshot, inside ``ops.aot_capture()`` — each jitted op a run hits
-        is lowered + compiled once and cached by (op, shapes, statics). The
-        vertical scan additionally sweeps pow2 constrained-dim counts (its
-        shapes vary with ``next_pow2(max mq)``). Steady-state traffic whose
+        is lowered + compiled once and cached by (op, shapes, statics).
+        Steady-state traffic whose
         shapes were advertised here dispatches straight to compiled
         executables: zero retraces, counter-asserted via ``ops.trace_log``.
         Re-run automatically after ``compact`` (new data shapes).
@@ -277,24 +273,19 @@ class PipelinedMDRQServer(MDRQServer):
         while b <= top:
             sizes.append(b)
             b *= 2
-        dim_counts = tuple(sorted({min(T.next_pow2(k), m)
-                                   for k in range(1, m + 1)}))
         before = set(ops.aot_cache_keys())
         n_runs = 0
         with obs_tracing.span("warmup", paths=len(names)):
             with ops.aot_capture():
                 for name in names:
-                    path = paths[name]
-                    dcs = dim_counts if name == "scan_vertical" else (m,)
-                    for d in dcs:
-                        for bsz in sizes:
-                            engine._path_query_batch(
-                                path, _warm_batch(bsz, d, m), self.spec,
-                                delta=delta_arg)
-                            n_runs += 1
+                    for bsz in sizes:
+                        engine._path_query_batch(
+                            paths[name], _warm_batch(bsz, m), self.spec,
+                            delta=delta_arg)
+                        n_runs += 1
         keys = tuple(k for k in ops.aot_cache_keys() if k not in before)
         self.last_warmup = WarmupReport(
-            paths=names, bucket_sizes=tuple(sizes), dim_counts=dim_counts,
+            paths=names, bucket_sizes=tuple(sizes),
             spec_kind=self.spec.kind, n_runs=n_runs, n_compiled=len(keys),
             seconds=time.perf_counter() - t0, keys=keys)
         return self.last_warmup

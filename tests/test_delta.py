@@ -155,6 +155,31 @@ def test_tombstones_only_delta(uni5):
     assert ops.counters() == {"multi_scan_reduce": 1, "host_sync": 1}
 
 
+@pytest.mark.parametrize("method", ("scan", "scan_vertical"))
+def test_tombstoned_delta_rows_never_match_one_dim_chunks(uni5, method):
+    """Each chunk of 32 queries here bounds one dimension, with a range that
+    holds every value, so the scan kernels compare that one row and skip the
+    rest: the +inf-poisoned tombstoned delta rows (and the object padding)
+    are rejected by that compare alone. A match-all query compares row 0."""
+    eng = MDRQEngine(uni5, structures=("scan",))
+    rng = np.random.default_rng(31)
+    extra = rng.random((300, uni5.m)).astype(np.float32)
+    new_ids = eng.append(extra)
+    dead = np.concatenate([new_ids[::3], rng.choice(uni5.n, 50,
+                                                    replace=False)])
+    eng.delete(dead)
+    oracle = _Oracle(uni5.cols, extra, dead)
+    queries = [RangeQuery.partial(uni5.m, {(k // 32) % uni5.m: (-1.0, 2.0)})
+               for k in range(64)] + [RangeQuery.partial(uni5.m, {})]
+    live = uni5.n + len(extra) - len(dead)
+    for spec in (Count(), Ids()):
+        for q, res in zip(queries, eng.query_batch(queries, method=method,
+                                                   spec=spec)):
+            oracle.check(spec, q, res)
+    assert eng.query_batch(queries, method=method, spec=Count()) == \
+        [live] * len(queries)
+
+
 # -- launch / host-sync budgets under a live delta ----------------------------
 
 @pytest.mark.parametrize("spec", [Count(), TopK(k=4, dim=2), Agg("sum", 1)],

@@ -60,8 +60,7 @@ def test_range_scan_vertical_sweep(m, n_q):
     padded, _, n0 = ops.prepare_columnar(cols)
     data = jnp.asarray(padded)
     lo, up = ops.query_bounds_device(q, padded.shape[0], jnp.float32)
-    out = np.asarray(ops.range_scan_vertical(
-        data, jnp.asarray(dims.astype(np.int32)), lo, up))[:n0]
+    out = np.asarray(ops.range_scan_vertical(data, lo, up))[:n0]
     np.testing.assert_array_equal(out.astype(bool), T.match_mask_np(cols, q))
 
 

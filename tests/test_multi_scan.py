@@ -12,6 +12,8 @@ from repro.core import (Count, Dataset, MDRQEngine, QueryBatch, RangeQuery,
                         match_ids_np, match_mask_np)
 from repro.core.planner import CostModel, Planner, Histograms
 from repro.core.vafile import build_vafile
+from repro import obs
+from repro.kernels import multi_scan as ms
 from repro.kernels import ops, ref
 from repro.kernels.va_filter import pack_codes
 
@@ -32,39 +34,185 @@ def _mixed_queries(m, cols, rng, n_q):
 
 
 # -- (a) kernel variants vs the numpy oracle ---------------------------------
+# Each chunk of 32 query rows compares only the dimension rows some query of
+# it bounds, so the cases vary which rows a chunk bounds: random, disjoint
+# between chunks, only a row of the last sublane group, a chunk of match-all
+# and padding queries, GMRQB's templates 1-7; Q from 1 to 128 and m not a
+# multiple of 8. The object padding (+inf) never matches, so a match-all or
+# padding query row counts n, not n_pad.
 
-@pytest.mark.parametrize("m,n_q", [(3, 1), (5, 4), (19, 6)])
-def test_multi_scan_tiles_vs_oracle(m, n_q):
+def _case_queries(kind, m, cols, rng, n_q):
+    if kind == "mixed":
+        return _mixed_queries(m, cols, rng, n_q)
+    if kind == "disjoint":  # chunk 0 bounds dims 0-4 only, chunk 1 dims 13+
+        out = []
+        for k in range(n_q):
+            pool = np.arange(5) if k < 32 else np.arange(13, m)
+            dims = rng.choice(pool, size=int(rng.integers(1, 4)),
+                              replace=False)
+            out.append(RangeQuery.partial(m, {
+                int(d): tuple(sorted(rng.random(2).tolist())) for d in dims}))
+        return out
+    if kind == "tail":  # 3 dims each among the first 13: past the head of 8
+        return [RangeQuery.partial(m, {
+            int(d): tuple(sorted(rng.random(2).tolist()))
+            for d in rng.choice(13, size=3, replace=False)})
+            for _ in range(n_q)]
+    if kind == "last_group":  # a row of the last sublane group, nothing else
+        return [RangeQuery.partial(m, {m - 1: tuple(sorted(
+            rng.random(2).tolist()))}) for _ in range(n_q)]
+    if kind == "match_all":  # a first chunk of 32, then match-all queries
+        return (_mixed_queries(m, cols, rng, 32)
+                + [RangeQuery.partial(m, {})] * (n_q - 32))
+    if kind == "wide":  # chunk 0 bounds 5 of m dims each, chunk 1 2 of 30
+        return [RangeQuery.partial(m, {
+            int(d): tuple(sorted(rng.random(2).tolist()))
+            for d in rng.choice(m if k < 32 else 30, size=5 if k < 32 else 2,
+                                replace=False)})
+            for k in range(n_q)]
+    if kind == "gmrqb":  # templates 1-7 in turn
+        from repro.data import gmrqb
+        return [gmrqb.template(1 + k % 7, rng) for k in range(n_q)]
+    raise AssertionError(kind)
+
+
+def _case_data(kind, m, n):
+    if kind == "gmrqb":
+        from repro.data import gmrqb
+        return gmrqb.build(n, seed=3).cols
+    return np.random.default_rng(m * 7 + n).random((m, n)).astype(np.float32)
+
+
+def _case_batch(kind, m, n_q, q_pad, n):
+    """(cols, batch, padded data, lo, up) of one oracle case."""
     rng = np.random.default_rng(m * 10 + n_q)
-    cols = rng.random((m, 4096)).astype(np.float32)
-    batch = QueryBatch.from_queries(_mixed_queries(m, cols, rng, n_q))
-    padded, _, n0 = ops.prepare_columnar(cols)
-    data = jnp.asarray(padded)
-    lo, up = batch.bounds_columnar(padded.shape[0])
-    lo, up = jnp.asarray(lo), jnp.asarray(up)
+    cols = _case_data(kind, m, n)
+    batch = QueryBatch.from_queries(_case_queries(kind, m, cols, rng, n_q))
+    padded, _, _ = ops.prepare_columnar(cols)
+    lo, up = batch.bounds_columnar(padded.shape[0], q_pad)
+    return cols, batch, jnp.asarray(padded), jnp.asarray(lo), jnp.asarray(up)
+
+
+def _check_vs_oracle(out, cols, batch):
+    n0 = cols.shape[1]
+    for k in range(len(batch)):
+        np.testing.assert_array_equal(out[k, :n0].astype(bool),
+                                      match_mask_np(cols, batch[k]))
+    assert not out[:, n0:].any()           # object padding never matches
+    assert (out[len(batch):, :n0] != 0).all()  # padding queries match all
+
+
+# (m, Q, kind, q_pad, n) of the row-skipping cases; each test's first cases
+# keep their historical ids
+_ROW_CASES = [
+    pytest.param(19, 64, "disjoint", 64, 2000, id="disjoint-chunks-q64"),
+    pytest.param(19, 8, "last_group", 8, 2000, id="last-group-only-q8"),
+    pytest.param(24, 32, "tail", 32, 2000, id="loop-tail-m24-q32"),
+    pytest.param(11, 32, "mixed", 32, 2000, id="m11-q32"),
+    pytest.param(19, 40, "match_all", 64, 2000, id="match-all-chunk-q64"),
+    pytest.param(19, 128, "gmrqb", 128, 2000, id="gmrqb-t1-7-q128"),
+    pytest.param(100, 64, "wide", 64, 2000, id="half-rule-m100-q64"),
+]
+
+
+@pytest.mark.parametrize("m,n_q,kind,q_pad,n", [
+    pytest.param(3, 1, "mixed", None, 4096, id="3-1"),
+    pytest.param(5, 4, "mixed", None, 4096, id="5-4"),
+    pytest.param(19, 6, "mixed", None, 4096, id="19-6"),
+    *_ROW_CASES])
+def test_multi_scan_tiles_vs_oracle(m, n_q, kind, q_pad, n):
+    cols, batch, data, lo, up = _case_batch(kind, m, n_q, q_pad, n)
     out = np.asarray(ops.multi_range_scan(data, lo, up))
     np.testing.assert_array_equal(out, np.asarray(ref.multi_scan_ref(data, lo, up)))
-    for k in range(n_q):
-        np.testing.assert_array_equal(out[k, :n0].astype(bool),
-                                      match_mask_np(cols, batch[k]))
+    _check_vs_oracle(out, cols, batch)
 
 
-@pytest.mark.parametrize("m,n_q", [(5, 3), (19, 5)])
-def test_multi_scan_vertical_vs_oracle(m, n_q):
-    rng = np.random.default_rng(m + n_q)
-    cols = rng.random((m, 4096)).astype(np.float32)
-    batch = QueryBatch.from_queries(_mixed_queries(m, cols, rng, n_q))
-    padded, _, n0 = ops.prepare_columnar(cols)
-    data = jnp.asarray(padded)
-    dim_ids = jnp.asarray(batch.padded_dim_ids())
-    lo, up = batch.bounds_columnar(padded.shape[0])
-    lo, up = jnp.asarray(lo), jnp.asarray(up)
-    out = np.asarray(ops.multi_range_scan_vertical(data, dim_ids, lo, up))
-    np.testing.assert_array_equal(
-        out, np.asarray(ref.multi_scan_vertical_ref(data, dim_ids, lo, up)))
-    for k in range(n_q):
-        np.testing.assert_array_equal(out[k, :n0].astype(bool),
-                                      match_mask_np(cols, batch[k]))
+@pytest.mark.parametrize("m,n_q,kind,q_pad,n", [
+    pytest.param(5, 3, "mixed", None, 4096, id="5-3"),
+    pytest.param(19, 5, "mixed", None, 4096, id="19-5"),
+    *_ROW_CASES])
+def test_multi_scan_vertical_vs_oracle(m, n_q, kind, q_pad, n):
+    cols, batch, data, lo, up = _case_batch(kind, m, n_q, q_pad, n)
+    out = np.asarray(ops.multi_range_scan_vertical(data, lo, up))
+    np.testing.assert_array_equal(out, np.asarray(ref.multi_scan_ref(data, lo, up)))
+    _check_vs_oracle(out, cols, batch)
+
+
+@pytest.mark.parametrize("kind,m,n_q,q_pad", [
+    ("mixed", 19, 6, 8), ("disjoint", 19, 64, 64), ("last_group", 5, 3, 4),
+    ("tail", 24, 32, 32),
+    ("match_all", 19, 40, 64), ("gmrqb", 19, 128, 128),
+    ("wide", 100, 64, 64)])
+def test_row_flags_host_count_matches_kernel_flags(kind, m, n_q, q_pad):
+    """The host's chunk flags (from ``dims_mask``, for the row counters) are
+    the flags the kernels derive in the jit from the bounds."""
+    cols, batch, data, lo, up = _case_batch(kind, m, n_q, q_pad, 1024)
+    m_pad = data.shape[0]
+    kernel = np.asarray(ms.chunk_flags(ms._bound_rows(lo.T, up.T)))
+    bound = np.zeros((q_pad, m_pad), bool)
+    bound[:n_q, :m] = batch.dims_mask
+    np.testing.assert_array_equal(ms.chunk_flags(bound, xp=np), kernel)
+    assert kernel.shape == (-(-q_pad // 32), m_pad)
+    assert kernel.any(axis=1).all()    # every chunk compares some row
+
+
+def _row_counts():
+    reg = obs.registry()
+    return {outcome: reg.counter_values(f"mdrq_scan_rows_{outcome}_total",
+                                        "kernel")
+            for outcome in ("compared", "skipped")}
+
+
+def test_scan_row_counters_gmrqb_templates():
+    """One GMRQB batch of 128 through the vertical scan: chunk 0 is template
+    1 (dims 0, 1), chunk 1 templates 2-3 (0, 1, 2, 3, 6), chunk 2 templates
+    4-5 (0, 1, 2, 3, 6, 13), chunk 3 templates 6-7 (0, 1, 2, 3, 6, 13, 15,
+    17, 18): 2 + 5 + 6 + 9 = 22 of the 4 x 24 (chunk, row) pairs compared."""
+    from repro.core.scan import build_columnar_scan
+    from repro.data import gmrqb
+    rng = np.random.default_rng(0)
+    ds = gmrqb.build(2000, seed=1)
+    order = [1] * 32 + [2, 3] * 16 + [4, 5] * 16 + [6, 7] * 16
+    batch = QueryBatch.from_queries([gmrqb.template(k, rng) for k in order])
+    scan = build_columnar_scan(ds)
+    counts = scan.query_batch(batch, partial=True, spec=Count())
+    assert counts == [int(match_mask_np(ds.cols, q).sum())
+                      for q in batch.queries]
+    assert _row_counts() == {"compared": {"vertical": 22.0},
+                             "skipped": {"vertical": 96.0 - 22.0}}
+
+
+def test_scan_row_counters_complete_match_compares_every_row():
+    """A complete-match batch over 19 dims (m_pad 24) would skip only the 5
+    padding rows, fewer than a sublane group: each of its two chunks
+    compares all 24 rows in straight-line code instead."""
+    from repro.core.scan import build_columnar_scan
+    rng = np.random.default_rng(1)
+    ds = Dataset(rng.random((19, 2000), dtype=np.float32))
+    batch = QueryBatch.from_queries(_mixed_queries(19, ds.cols, rng, 80)[::2])
+    assert batch.dims_mask.all()
+    scan = build_columnar_scan(ds)
+    counts = scan.query_batch(batch, spec=Count())
+    assert counts == [int(match_mask_np(ds.cols, q).sum())
+                      for q in batch.queries]
+    assert _row_counts() == {"compared": {"full": 2 * 24.0}, "skipped": {}}
+
+
+def test_scan_row_counters_half_rule():
+    """At m=100 (m_pad 104) a chunk whose loop would run over more than half
+    of the 96 rows past its head compares every row: chunk 0 bounds about
+    80 rows (5 random dims a query) and compares all 104; chunk 1 bounds at
+    most 30 and compares just those."""
+    from repro.core.scan import build_columnar_scan
+    cols, batch, _, _, _ = _case_batch("wide", 100, 64, 64, 2000)
+    union = batch.dims_mask.reshape(2, 32, 100).any(axis=1).sum(axis=1)
+    assert union[0] > 8 + 96 // 2 and 2 <= union[1] <= 30
+    scan = build_columnar_scan(Dataset(cols))
+    counts = scan.query_batch(batch, spec=Count())
+    assert counts == [int(match_mask_np(cols, q).sum())
+                      for q in batch.queries]
+    assert _row_counts() == {"compared": {"full": 104.0 + union[1]},
+                             "skipped": {"full": 104.0 - union[1]}}
 
 
 def test_multi_scan_visit_vs_oracle():

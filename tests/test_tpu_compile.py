@@ -96,10 +96,25 @@ def test_multi_scan_reduce_with_delta_compiles(one_chip):
 
 @pytest.mark.parametrize("q_n", BUCKETS)
 def test_multi_scan_vertical_reduce_compiles(one_chip, q_n):
-    data, lo, up = _scan_args(q_n, one_chip)
-    dim_ids = _sds((q_n, 8), I32, one_chip)
-    _compile(ops.multi_scan_vertical_reduce, data, dim_ids, lo, up,
+    _compile(ops.multi_scan_vertical_reduce, *_scan_args(q_n, one_chip),
              spec=Ids())
+
+
+@pytest.mark.parametrize("kernel,m_pad,q_n", [
+    # GMRQB (m_pad 24) and SYNT-UNI at m=5 (m_pad 8), the benchmark's buckets
+    *((k, m_pad, q_n) for k in ("full", "vertical") for m_pad in (24, 8)
+      for q_n in (16, 32, 64, 128)),
+    # SYNT-UNI at m=50 and m=100 (fig. 5), at the largest bucket
+    *((k, m_pad, 128) for k in ("full", "vertical") for m_pad in (56, 104)),
+])
+def test_row_skipping_scan_compiles(one_chip, kernel, m_pad, q_n):
+    """The Count scans at 10M rows, whose query chunks compare only the rows
+    they flag (row lists read from SMEM)."""
+    data = _sds((m_pad, N_PAD), F32, one_chip)
+    lo = up = _sds((m_pad, q_n), F32, one_chip)
+    op = ops.multi_scan_reduce if kernel == "full" else \
+        ops.multi_scan_vertical_reduce
+    _compile(op, data, lo, up, spec=Count())
 
 
 @pytest.mark.parametrize("q_n", BUCKETS)
@@ -115,7 +130,7 @@ def test_multi_visit_reduce_compiles(one_chip, q_n):
 def test_range_scan_vertical_compiles(one_chip):
     """The single-query partial-match scan behind ``engine.query``."""
     data, lo, up = _scan_args(1, one_chip)
-    _compile(ops.range_scan_vertical, data, _sds((5,), I32, one_chip), lo, up)
+    _compile(ops.range_scan_vertical, data, lo, up)
 
 
 def test_range_scan_visit_compiles(one_chip):
