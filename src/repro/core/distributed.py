@@ -35,6 +35,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import types as T
+from repro.obs import tracing as obs_tracing
 from repro.kernels import ops
 from repro.kernels import multi_scan as _ms
 from repro.kernels import range_scan as _rs
@@ -55,18 +56,78 @@ def make_data_mesh(n_devices: int | None = None) -> Mesh:
     return Mesh(np.asarray(devs[:k]), ("data",))
 
 
-def shard_columnar(mesh: Mesh, padded_cols: np.ndarray, tile_n: int = 1024) -> jax.Array:
-    """Place (m_pad, n_pad) columnar data sharded over objects.
+# -- placement: one device, or sharded when one device cannot hold the table --
+# A device scanning its table also holds the (Q, n) int8 mask of the window
+# it scans; WINDOW_Q is the servers' default window (``max_batch``).
+WINDOW_Q = 128
 
-    n_pad must divide by (#devices * tile_n) — callers pad with +inf sentinels
-    via ``ops.prepare_columnar`` using tile_n * axis_size.
+
+def device_bytes_limit() -> int | None:
+    """The first device's memory limit in bytes; None where the backend
+    reports none (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def padded_shape(m: int, n: int, n_devices: int, tile_n: int
+                 ) -> tuple[int, int]:
+    """(m_pad, n_pad) of an (m, n) table padded to sublane groups and to
+    whole tiles on each of ``n_devices`` devices."""
+    return (-(-m // _rs.SUBLANES) * _rs.SUBLANES,
+            -(-n // (n_devices * tile_n)) * n_devices * tile_n)
+
+
+def scan_bytes_per_device(m: int, n: int, n_devices: int,
+                          tile_n: int) -> int:
+    """Bytes each of ``n_devices`` devices holds to scan its share of an
+    (m, n) float32 table: its padded slice and a full window's mask."""
+    m_pad, n_pad = padded_shape(m, n, n_devices, tile_n)
+    return n_pad // n_devices * (m_pad * 4 + WINDOW_Q)
+
+
+def placement_mesh(m: int, n: int, tile_n: int) -> Mesh | None:
+    """None where one device holds an (m, n) table beside a window's mask,
+    else a data mesh over every local device (DESIGN.md §5).
+
+    Raises ValueError where even the local devices together cannot hold it.
+    """
+    limit = device_bytes_limit()
+    if limit is None or scan_bytes_per_device(m, n, 1, tile_n) <= limit:
+        return None
+    k = len(jax.devices())
+    need = scan_bytes_per_device(m, n, k, tile_n)
+    if need > limit:
+        raise ValueError(
+            f"a ({m}, {n}) float32 table needs {need} B a device on {k} "
+            f"device(s) (its padded slice and a {WINDOW_Q}-query int8 mask); "
+            f"a device holds {limit} B")
+    return make_data_mesh(k)
+
+
+def shard_columnar(mesh: Mesh, cols: np.ndarray, tile_n: int = 1024) -> jax.Array:
+    """Place (m, n) columnar data sharded over objects, padded as
+    ``ops.prepare_columnar`` pads it (dim rows 0.0, object columns +inf) to
+    whole tiles on every device.
+
+    Straight from host numpy into the sharding: each device's padded slice
+    is cut from ``cols`` on its own, so the host never holds more than one
+    padded copy, and no device ever holds more than its slice.
     """
     n_dev = mesh.shape["data"]
-    m_pad, n_pad = padded_cols.shape
-    assert n_pad % (n_dev * tile_n) == 0, (n_pad, n_dev, tile_n)
-    # Straight from host numpy into the sharding: each device receives only
-    # its own slice (a jnp.asarray first would land the whole array on one).
-    return jax.device_put(padded_cols, NamedSharding(mesh, P(None, "data")))
+    m, n = cols.shape
+    shape = padded_shape(m, n, n_dev, tile_n)
+
+    def piece(index) -> np.ndarray:
+        a, b, _ = index[1].indices(shape[1])
+        k = max(0, min(b, n) - a)
+        out = np.full((shape[0], b - a), np.inf, np.float32)
+        out[:m, :k] = cols[:, a:a + k]
+        out[m:, :k] = 0.0
+        return out
+
+    with obs_tracing.span("place", n_devices=n_dev,
+                          bytes_per_device=shape[0] * shape[1] // n_dev * 4):
+        return jax.make_array_from_callback(
+            shape, NamedSharding(mesh, P(None, "data")), piece)
 
 
 def _local_scan(data_local, lo, up, *, tile_n: int, interpret: bool):
@@ -79,12 +140,16 @@ def _local_scan(data_local, lo, up, *, tile_n: int, interpret: bool):
 
 
 def _local_multi_scan(data_local, lo, up, *, tile_n: int, interpret: bool):
-    """One device's fused multi-query scan of its shard -> (Q, n_local)."""
+    """One device's fused multi-query scan of its shard -> (Q, n_local).
+
+    The scope names the kernel's device operation ``sharded_scan.<k>`` in a
+    profiler trace (``mdrqbench/layers/shard_scan_roofline.count.py``)."""
     if ops.use_xla():
         from repro.kernels import ref as _ref
         return _ref.multi_scan_ref(data_local, lo, up)
-    return _ms.multi_scan_tiles(data_local, lo, up, tile_n=tile_n,
-                                interpret=interpret)
+    with jax.named_scope("sharded_scan"):
+        return _ms.multi_scan_tiles(data_local, lo, up, tile_n=tile_n,
+                                    interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "tile_n", "interpret"))
@@ -316,11 +381,9 @@ class DistributedScan:
         self.mesh = mesh or make_data_mesh()
         self.tile_n = tile_n
         self.n_devices = self.mesh.shape["data"]
-        padded, self.m, self.n = ops.prepare_columnar(
-            dataset.cols, tile_n=tile_n * self.n_devices
-        )
-        self.m_pad = padded.shape[0]
-        self.data = shard_columnar(self.mesh, padded, tile_n=tile_n)
+        self.m, self.n = dataset.m, dataset.n
+        self.data = shard_columnar(self.mesh, dataset.cols, tile_n=tile_n)
+        self.m_pad = self.data.shape[0]
 
     @property
     def nbytes_index(self) -> int:
@@ -347,11 +410,20 @@ class DistributedScan:
             batch = T.QueryBatch.from_queries(list(batch))
         return batch
 
+    def _launch_bounds(self, batch: T.QueryBatch) -> tuple:
+        """Device bounds of one batch launch (``bucketed_batch_bounds``),
+        whose compared and skipped (chunk, row) pairs are counted here on
+        the host, once per launch, under ``kernel="sharded"``."""
+        from repro.core.scan import bucketed_batch_bounds
+        q_pad, lo, up = bucketed_batch_bounds(batch, self.m_pad,
+                                              self.data.dtype)
+        ops.count_scan_rows("sharded", batch.dims_mask, q_pad, self.m_pad)
+        return lo, up
+
     def mask_batch(self, batch) -> np.ndarray:
         """(Q, n) bool match masks from one cross-device fused launch."""
-        from repro.core.scan import bucketed_batch_bounds
         batch = self._as_batch(batch)
-        _, lo, up = bucketed_batch_bounds(batch, self.m_pad, self.data.dtype)
+        lo, up = self._launch_bounds(batch)
         out = distributed_multi_mask(self.mesh, self.data, lo, up,
                                      tile_n=self.tile_n)
         return ops.device_get(out)[: len(batch), : self.n] > 0
@@ -359,9 +431,8 @@ class DistributedScan:
     def count_batch(self, batch) -> list[int]:
         """Per-query global counts: one collective launch + one psum, so the
         host (and the collective) only ever see (Q,) ints."""
-        from repro.core.scan import bucketed_batch_bounds
         batch = self._as_batch(batch)
-        _, lo, up = bucketed_batch_bounds(batch, self.m_pad, self.data.dtype)
+        lo, up = self._launch_bounds(batch)
         counts = distributed_multi_counts(self.mesh, self.data, lo, up,
                                           tile_n=self.tile_n)
         return [int(c) for c in ops.device_get(counts)[: len(batch)]]
@@ -384,9 +455,8 @@ class DistributedScan:
         (the counted ``device_get`` + host finalizers run via ``finalize``
         on the caller's thread)."""
         spec = T.validate_mode(spec).validate(self.m)
-        from repro.core.scan import bucketed_batch_bounds
         batch = self._as_batch(batch)
-        _, lo, up = bucketed_batch_bounds(batch, self.m_pad, self.data.dtype)
+        lo, up = self._launch_bounds(batch)
         dcm = tomb = None
         if delta is not None and not delta.is_empty:
             dcm = delta.device_cm(self.tile_n)

@@ -45,6 +45,7 @@ from repro.core import types as T
 from repro.core import delta as delta_mod
 from repro.core import scan as scan_mod
 from repro.core import paths as paths_mod
+from repro.core import distributed
 from repro.core.distributed import DistributedScan
 from repro.core.kdtree import build_kdtree
 from repro.core.rstar import build_rstar
@@ -276,8 +277,20 @@ class MDRQEngine:
         self.last_trace: Optional[obs_tracing.BatchTrace] = None
 
     def _build_state(self, dataset: T.Dataset, version: int = 0) -> _EngineState:
+        mesh = self._mesh
+        if mesh is None:
+            # A table one device cannot hold is sharded over every local
+            # device (DESIGN.md §5); only the scan shards.
+            mesh = distributed.placement_mesh(dataset.m, dataset.n,
+                                              self.tile_n)
+            unsharded = set(self._structures) & {"kdtree", "rstar", "vafile"}
+            if mesh is not None and unsharded:
+                raise ValueError(
+                    f"a ({dataset.m}, {dataset.n}) table is sharded over "
+                    f"{mesh.shape['data']} devices; {sorted(unsharded)} "
+                    f"would place it whole on one")
         return _EngineState(dataset, self._structures, self.tile_n,
-                            self._rowscan_enabled, self._mesh, version=version)
+                            self._rowscan_enabled, mesh, version=version)
 
     # -- versioned-state views ---------------------------------------------
     # Pre-versioning callers read these as plain attributes; each delegates
@@ -503,7 +516,8 @@ class MDRQEngine:
             sub = T.QueryBatch(batch.lower[idxs], batch.upper[idxs])
             path = _lookup_path(state.paths, meth)
             with obs_tracing.span("execute", path=meth, bucket=len(idxs),
-                                  stage="launch"):
+                                  stage="launch",
+                                  n_devices=getattr(path, "n_devices", None)):
                 if self._path_supports_launch(path, delta_arg):
                     payload, fin = path.launch_batch(sub, spec=spec,
                                                      delta=delta_arg)
@@ -634,11 +648,12 @@ class MDRQEngine:
             results: list = [None] * len(batch)
             for meth, idxs in buckets.items():
                 sub = T.QueryBatch(batch.lower[idxs], batch.upper[idxs])
-                with obs_tracing.span("execute", path=meth,
-                                      bucket=len(idxs)) as sp:
-                    out = self._path_query_batch(
-                        _lookup_path(state.paths, meth), sub, spec,
-                        delta=delta_arg)
+                path = _lookup_path(state.paths, meth)
+                with obs_tracing.span(
+                        "execute", path=meth, bucket=len(idxs),
+                        n_devices=getattr(path, "n_devices", None)) as sp:
+                    out = self._path_query_batch(path, sub, spec,
+                                                 delta=delta_arg)
                     sp.block_on(out)
                 for k, res in zip(idxs, out):
                     results[k] = res

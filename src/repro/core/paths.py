@@ -264,6 +264,7 @@ class DistributedScanPath(ScanCost):
 
     def __init__(self, dist):
         self._dist = dist
+        self.n_devices = dist.n_devices
 
     @property
     def nbytes_index(self) -> int:

@@ -139,17 +139,18 @@ def reset_counters() -> None:
 
 # (chunk, row) pairs of the scan kernels: each chunk of ``INT8_SUBLANES``
 # queries compares a dimension row only where ``multi_scan.chunk_flags`` flags
-# it. Counted on the host where ``ColumnarScan`` builds a batch launch, once
-# per launch, in families of their own so that ``counters()`` and the launch
-# budgets do not see them.
+# it. Counted on the host where ``ColumnarScan`` or ``DistributedScan`` builds
+# a batch launch, once per launch (a sharded launch once, not per device), in
+# families of their own so that ``counters()`` and the launch budgets do not
+# see them.
 _ROWS_HELP = "(query chunk, dimension row) pairs the scan kernels {}"
 
 
 def count_scan_rows(kernel: str, dims_mask: np.ndarray, q_pad: int,
                     m_pad: int) -> None:
     """Count one scan launch's compared and skipped (chunk, row) pairs,
-    labelled ``kernel`` (``"vertical"`` or ``"full"``), from the batch's (Q, m)
-    ``dims_mask`` padded to the launch's (q_pad, m_pad)."""
+    labelled ``kernel`` (``"vertical"``, ``"full"`` or ``"sharded"``), from
+    the batch's (Q, m) ``dims_mask`` padded to the launch's (q_pad, m_pad)."""
     bound = np.zeros((q_pad, m_pad), bool)
     bound[: dims_mask.shape[0], : dims_mask.shape[1]] = dims_mask
     flags = _ms.chunk_flags(bound, xp=np)

@@ -96,6 +96,34 @@ def test_distributed_single_query_is_counted(dist_pair, uni5):
     assert cnt == ids.size == match_ids_np(uni5.cols, q).size
 
 
+def test_sharded_row_counters_equal_the_full_scans():
+    """A sharded launch counts its compared and skipped (chunk, row) pairs
+    once, under ``kernel="sharded"``, exactly as the single-device full scan
+    counts the same GMRQB batch under ``kernel="full"``."""
+    from repro.data import gmrqb
+    from repro.obs import metrics
+    ds = gmrqb.build(3000, seed=4)
+    rng = np.random.default_rng(8)
+    batch = QueryBatch.from_queries(
+        [gmrqb.template(k, rng, ds) for k in [1, 2, 3, 4] * 10 + [8] * 3])
+    reg = metrics.registry()
+
+    def rows():
+        return {o: reg.counter_values(f"mdrq_scan_rows_{o}_total", "kernel")
+                for o in ("compared", "skipped")}
+
+    reg.reset()
+    want = build_columnar_scan(ds).query_batch(batch, spec=Count())
+    full = rows()
+    reg.reset()
+    got = DistributedScan(ds, mesh=make_data_mesh()).query_batch(
+        batch, spec=Count())
+    sharded = rows()
+    assert got == want
+    assert sharded == {o: {"sharded": v["full"]} for o, v in full.items()}
+    assert full["compared"]["full"] > 0 and full["skipped"]["full"] > 0
+
+
 def test_meshed_engine_routes_scan_buckets(uni5):
     """``MDRQEngine(mesh=...)`` sends scan buckets through the distributed
     path (counter-asserted) and returns identical results to a plain engine;

@@ -162,3 +162,24 @@ def test_distributed_multi_reduce_compiles(topo, spec):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert ("all-reduce" in text) == (spec == Count())
+
+
+@pytest.mark.parametrize("q_n", (16, 32, 64, 128))
+def test_sharded_count_compiles_at_200m_rows(topo, q_n):
+    """The sharded Count of GMRQB at 2e8 rows on the 2x2 (the
+    ``gmrqb-200m-4chip`` cell): 50,000,896 rows a chip, whose (Q, n) int8
+    mask passes 2^31 elements at Q=128. Table and mask fit a chip."""
+    mesh = Mesh(topo.devices[:4], ("data",))
+    n_pad = 200_003_584
+    data = _sds((M_PAD, n_pad), F32, NamedSharding(mesh, P(None, "data")))
+    bounds = _sds((M_PAD, q_n), F32, NamedSharding(mesh, P()))
+    compiled = distributed.distributed_multi_reduce.__wrapped__.lower(
+        mesh, data, bounds, bounds, spec=Count(), tile_n=TILE_N,
+        interpret=False).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= M_PAD * n_pad // 4 * 4
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= distributed.scan_bytes_per_device(19, 200_000_000, 4, TILE_N) \
+        + (1 << 20)
