@@ -152,6 +152,41 @@ class PendingBatch:
         return results
 
 
+def bucket_order(idxs: np.ndarray, dims_mask: np.ndarray) -> np.ndarray:
+    """A bucket's input positions ``idxs`` in the order its launch takes
+    them: stably sorted by how many dimensions each query bounds, then by
+    which (the integer whose bit d is dimension d of ``dims_mask``, (Q, m)
+    bool). Queries of one signature sit together and the widest fill the
+    last chunks, so a scan kernel's chunk of ``INT8_SUBLANES`` queries
+    compares only the rows its own queries bound. A bucket of one signature
+    keeps its order."""
+    mask = dims_mask[idxs]
+    packed = np.packbits(mask, axis=1, bitorder="little")
+    # lexsort's last key is the primary one: the count, then the bytes of
+    # the highest dimensions first
+    return idxs[np.lexsort((*packed.T, mask.sum(axis=1)))]
+
+
+def _buckets(methods: Sequence[str],
+             dims_mask: np.ndarray) -> dict[str, np.ndarray]:
+    """Path -> the input positions of its queries, in ``bucket_order``.
+    Results scatter back by position, so the order costs nothing else;
+    ``mdrq_bucket_reordered_total{path}`` counts the buckets it changed."""
+    buckets: dict[str, list[int]] = {}
+    for k, meth in enumerate(methods):
+        buckets.setdefault(meth, []).append(k)
+    out = {}
+    for meth, idxs in buckets.items():
+        idxs = np.asarray(idxs)
+        out[meth] = bucket_order(idxs, dims_mask)
+        if not np.array_equal(out[meth], idxs):
+            obs_metrics.registry().counter(
+                "mdrq_bucket_reordered_total",
+                help="buckets whose launch order bucket_order changed",
+                path=meth).inc()
+    return out
+
+
 def _lookup_path(paths: dict, method: str) -> paths_mod.AccessPath:
     path = paths.get(method)
     if path is None:
@@ -504,9 +539,7 @@ class MDRQEngine:
                 methods = [method] * len(batch)
         t1 = time.perf_counter()
 
-        buckets: dict[str, list[int]] = {}
-        for k, meth in enumerate(methods):
-            buckets.setdefault(meth, []).append(k)
+        buckets = _buckets(methods, batch.dims_mask)
 
         pending = PendingBatch(
             n_queries=len(batch), spec=spec, methods=list(methods),
@@ -641,9 +674,7 @@ class MDRQEngine:
                     methods = [method] * len(batch)
             plan_dt = time.perf_counter() - t0
 
-            buckets: dict[str, list[int]] = {}
-            for k, meth in enumerate(methods):
-                buckets.setdefault(meth, []).append(k)
+            buckets = _buckets(methods, batch.dims_mask)
 
             results: list = [None] * len(batch)
             for meth, idxs in buckets.items():

@@ -105,6 +105,10 @@ SCRIPT = textwrap.dedent("""
     rows = metrics.registry().counter_values(
         "mdrq_scan_rows_compared_total", "kernel")
     assert set(rows) == {"sharded"} and rows["sharded"] > 0, rows
+    # the windows of mixed templates ran sorted, and still answer in order
+    reordered = metrics.registry().counter_values(
+        "mdrq_bucket_reordered_total", "path")
+    assert set(reordered) == {"scan"} and reordered["scan"] > 0, reordered
     with serve_pipelined(eng, max_batch=8, max_wait_s=0.01, method="auto",
                          spec=Ids(), warmup=False,
                          latency_budget_s=float("inf")) as srv:
