@@ -12,7 +12,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import emit_row, qps
-from repro.core import DistributedScan, MDRQEngine
+from repro.core import MDRQEngine
 from repro.core.distributed import make_data_mesh
 from repro.data import synthetic
 
@@ -43,11 +43,11 @@ def run(quick: bool = True) -> None:
     # multi-device sharded scan over every device this process sees (on a
     # CPU: XLA_FLAGS=--xla_force_host_platform_device_count=8 before launch)
     k = len(jax.devices())
-    d = DistributedScan(ds, mesh=make_data_mesh(k))
+    d = MDRQEngine(ds, structures=("scan",), mesh=make_data_mesh(k))
     for q in queries[:3]:
-        d.query(q)
+        d.query(q, "scan")
     t0 = time.perf_counter()
     for q in queries:
-        d.query(q)
+        d.query(q, "scan")
     dt = (time.perf_counter() - t0) / len(queries)
     emit_row(f"fig4/scan_vectorized_{k}dev", dt * 1e6, f"qps={1/dt:.1f}")

@@ -10,7 +10,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import emit_row
-from repro.core import DistributedScan
+from repro.core import MDRQEngine
 from repro.core.distributed import make_data_mesh
 from repro.data import gmrqb
 
@@ -25,11 +25,11 @@ def run(quick: bool = True) -> None:
             print(f"# fig11/devices{k}/scan skipped: this process sees "
                   f"{n_dev} device(s)", flush=True)
             continue
-        d = DistributedScan(ds, mesh=make_data_mesh(k))
+        d = MDRQEngine(ds, structures=("scan",), mesh=make_data_mesh(k))
         for q in qs[:3]:
-            d.query(q)
+            d.query(q, "scan")
         t0 = time.perf_counter()
         for q in qs:
-            d.query(q)
+            d.query(q, "scan")
         dt = (time.perf_counter() - t0) / len(qs)
         emit_row(f"fig11/devices{k}/scan", dt * 1e6, f"qps={1/dt:.1f}")

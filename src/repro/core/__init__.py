@@ -19,7 +19,7 @@ from repro.core.types import (Agg, Count, Dataset, DeltaHostCtx, Ids, Mask,
 from repro.core.delta import Compactor, DeltaView, MutableDelta
 from repro.core.engine import MDRQEngine, ALL_METHODS, BatchStats
 from repro.core.paths import AccessPath, PerQueryPath, PlanInputs
-from repro.core.scan import build_columnar_scan, build_row_scan
+from repro.core.scan import build_columnar_scan
 from repro.core.kdtree import build_kdtree
 from repro.core.rstar import build_rstar
 from repro.core.vafile import build_vafile
@@ -35,7 +35,7 @@ __all__ = [
     "MutableDelta", "DeltaView", "Compactor",
     "MDRQEngine", "ALL_METHODS", "BatchStats",
     "AccessPath", "PerQueryPath", "PlanInputs",
-    "build_columnar_scan", "build_row_scan", "build_kdtree", "build_rstar",
+    "build_columnar_scan", "build_kdtree", "build_rstar",
     "build_vafile", "BatchPlan", "CalibrationFit", "CalibrationReport",
     "CostModel", "Histograms", "Planner",
     "DistributedScan", "make_data_mesh",

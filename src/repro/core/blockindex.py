@@ -5,10 +5,14 @@ R*-tree — reduce at query time to the same TPU-native two-phase plan
 (DESIGN.md §2):
 
   phase 1 (prune):  vectorized MBR-overlap tests over a small hierarchy of
-                    per-block bounding boxes (device, one jit call);
-  phase 2 (refine): the ``range_scan_visit`` Pallas kernel scans *only* the
-                    surviving leaf blocks (grid size = #survivors, so pruned
-                    blocks cost nothing — the TPU analogue of subtree pruning).
+                    per-block bounding boxes (device, one jit call for the
+                    whole batch);
+  phase 2 (refine): the ``multi_scan_visit`` Pallas kernel scans *only* the
+                    surviving (query, leaf block) pairs (grid size =
+                    #survivors, so pruned blocks cost nothing — the TPU
+                    analogue of subtree pruning).
+
+A single query is a batch of one.
 
 What distinguishes the structures is the *build*: how objects are permuted
 into leaf blocks (median splits vs sort-tile-recursive vs storage order).
@@ -53,42 +57,6 @@ def build_hierarchy(
         if n_up == 1:
             break
     return levels[::-1]  # root first
-
-
-@functools.partial(jax.jit, static_argnames=("fanout",))
-def _prune_hierarchy_jit(
-    levels_lo: tuple[jax.Array, ...],
-    levels_hi: tuple[jax.Array, ...],
-    qlo: jax.Array,
-    qhi: jax.Array,
-    fanout: int,
-) -> jax.Array:
-    """Top-down vectorized MBR pruning.
-
-    Args:
-      levels_lo/hi: root-first tuples of (m, n_nodes) MBR bounds.
-      qlo, qhi: (m, 1) query bounds.
-
-    Returns:
-      (n_leaves,) bool — leaves whose MBR intersects the query box.
-    """
-    ops.note_trace("prune_hierarchy")
-    active = None
-    for lo, hi in zip(levels_lo, levels_hi):
-        overlap = jnp.all(jnp.logical_and(hi >= qlo, lo <= qhi), axis=0)
-        if active is None:
-            active = overlap
-        else:
-            parents = jnp.repeat(active, fanout)[: overlap.shape[0]]
-            active = jnp.logical_and(parents, overlap)
-    return active
-
-
-prune_hierarchy = ops.counted(
-    "prune_hierarchy",
-    "Phase-1 MBR hierarchy prune for one query (the tree MDIS's extra "
-    "launch on top of the fused visit kernel).",
-)(_prune_hierarchy_jit)
 
 
 @functools.partial(jax.jit, static_argnames=("fanout",))
@@ -172,36 +140,6 @@ def _build_visit_index(query_ids: np.ndarray, n_queries: int,
     return index
 
 
-def reduce_visits_batch(
-    data_dev: jax.Array,
-    query_ids: np.ndarray,
-    block_ids: np.ndarray,
-    batch: T.QueryBatch,
-    tile_n: int,
-    n_queries: int,
-    spec: T.ResultSpec,
-    n: int,
-    perm: np.ndarray | None = None,
-    delta=None,
-) -> list:
-    """Phase 2 of every batched two-phase path, under any ResultSpec.
-
-    Pads the flattened (query, block) visit list to a pow2 bucket, runs ONE
-    ``ops.multi_visit_reduce`` launch (the visit kernel + the spec's
-    on-device visit reducer in the same jit), fetches the payload in one
-    host sync, and finalizes per query. Shared by the tree MDIS and the
-    VA-file so a new result shape lands on both at once.
-
-    ``delta`` (a ``core.delta.DeltaView``) rides the same launch: base
-    tombstones gather per visited block and AND into the visit masks, the
-    delta block scans with the batch bounds, and the spec merges the halves.
-    """
-    payload, fin = launch_visits_batch(data_dev, query_ids, block_ids, batch,
-                                       tile_n, n_queries, spec, n, perm=perm,
-                                       delta=delta)
-    return fin(ops.device_get(payload) if payload is not None else None)
-
-
 def launch_visits_batch(
     data_dev: jax.Array,
     query_ids: np.ndarray,
@@ -214,7 +152,8 @@ def launch_visits_batch(
     perm: np.ndarray | None = None,
     delta=None,
 ) -> tuple:
-    """Device half of ``reduce_visits_batch``: one launch, no host sync.
+    """Phase 2 of every two-phase path, under any ResultSpec: one launch,
+    no host sync.
 
     Returns ``(payload, finalize)``; the caller owns the single counted
     ``ops.device_get(payload)`` and hands its host value to ``finalize`` —
@@ -341,7 +280,7 @@ class BlockedIndex:
     m: int
     n: int
 
-    # -- stats of the last query (for benchmarks / planner calibration) --
+    # (query, block) pairs the last batch visited (benchmarks, calibration)
     last_visited_blocks: int = 0
 
     @property
@@ -352,51 +291,6 @@ class BlockedIndex:
     def nbytes_index(self) -> int:
         """Extra memory vs a plain scan (MBR hierarchy; paper §7.2 metric)."""
         return sum(int(np.prod(l.shape)) * 4 * 2 for l in self.levels_lo)
-
-    def query_leaf_mask(self, q: T.RangeQuery) -> np.ndarray:
-        """Phase 1: (n_leaves,) bool survivors of the hierarchy prune."""
-        qlo, qhi = ops.query_bounds_device(q, self.m, jnp.float32)
-        mask = prune_hierarchy(self.levels_lo, self.levels_hi, qlo, qhi,
-                               fanout=self.fanout)
-        return ops.device_get(mask)
-
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        """Full query -> sorted original ids of matching objects."""
-        leaf_mask = self.query_leaf_mask(q)
-        survivors = np.nonzero(leaf_mask)[0].astype(np.int32)
-        self.last_visited_blocks = int(survivors.size)
-        if survivors.size == 0:
-            return np.empty((0,), np.int64)
-        # Pad the visit list to a pow2 bucket to bound jit retraces.
-        n_visit = _next_pow2(survivors.size)
-        ids = np.full((n_visit,), -1, np.int32)
-        ids[: survivors.size] = survivors
-        qlo, qhi = ops.query_bounds_device(q, self.data_dev.shape[0], self.data_dev.dtype)
-        masks = ops.range_scan_visit(self.data_dev, jnp.asarray(ids), qlo, qhi,
-                                     tile_n=self.tile_n)
-        masks = ops.device_get(masks)[: survivors.size]  # (v, tile_n)
-        # Map (block, offset) -> permuted position -> original id.
-        pos = (survivors[:, None] * self.tile_n + np.arange(self.tile_n)[None, :])
-        pos = pos[masks > 0]
-        pos = pos[pos < self.n]  # drop object padding
-        return np.sort(self.perm[pos]).astype(np.int64)
-
-    def count(self, q: T.RangeQuery) -> int:
-        """Count-only query: visit masks are summed on device (no id arrays —
-        counts are permutation-invariant, so ``perm`` never enters)."""
-        leaf_mask = self.query_leaf_mask(q)
-        survivors = np.nonzero(leaf_mask)[0].astype(np.int32)
-        self.last_visited_blocks = int(survivors.size)
-        if survivors.size == 0:
-            return 0
-        n_visit = _next_pow2(survivors.size)
-        ids = np.full((n_visit,), -1, np.int32)
-        ids[: survivors.size] = survivors
-        qlo, qhi = ops.query_bounds_device(q, self.data_dev.shape[0], self.data_dev.dtype)
-        masks = ops.range_scan_visit(self.data_dev, jnp.asarray(ids), qlo, qhi,
-                                     tile_n=self.tile_n)
-        # padding visits (id -1, clamped to block 0) are sliced off on device
-        return int(ops.device_get(jnp.sum(masks[: survivors.size] != 0)))
 
     def launch_batch(self, batch: T.QueryBatch, spec: T.ResultSpec = T.IDS,
                      delta=None) -> tuple:

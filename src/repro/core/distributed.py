@@ -10,15 +10,15 @@ sharding — and the only collective in the system is an optional ``psum`` for
 global match counts. Load balancing is inherited from random object placement,
 exactly as in the paper.
 
-Batched execution (cross-device × multi-query): ``distributed_multi_mask`` /
-``distributed_multi_counts`` wrap the fused multi-query kernels
-(``kernels.multi_scan``) in the same shard_map — data sharded ``P(None,
-"data")``, the (m_pad, Q) query bounds replicated — so one collective launch
-answers a whole batch on every device at once. In count mode the per-device
-(Q,) partial counts reduce through a single ``psum`` and only O(Q) ints ever
-cross the collective *and* the host boundary. ``DistributedScan.query_batch``
-buckets the query axis to pow2 exactly like ``ColumnarScan`` so both engines
-share jit traces per batch-size bucket.
+Batched execution (cross-device × multi-query): ``distributed_multi_reduce``
+wraps the fused multi-query kernel (``kernels.multi_scan``) and the
+ResultSpec's reducer in the shard_map — data sharded ``P(None, "data")``, the
+(m_pad, Q) query bounds replicated — so one collective launch answers a whole
+batch on every device at once; a single query is a batch of one. Under Count
+the per-device (Q,) partial counts reduce through a single ``psum`` and only
+O(Q) ints ever cross the collective *and* the host boundary.
+``DistributedScan.query_batch`` buckets the query axis to pow2 exactly like
+``ColumnarScan`` so both engines share jit traces per batch-size bucket.
 
 Instrumentation: every entry point here is registered through
 ``kernels.ops.counted`` and every device->host read goes through
@@ -30,7 +30,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -38,7 +37,6 @@ from repro.core import types as T
 from repro.obs import tracing as obs_tracing
 from repro.kernels import ops
 from repro.kernels import multi_scan as _ms
-from repro.kernels import range_scan as _rs
 
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
@@ -72,7 +70,7 @@ def padded_shape(m: int, n: int, n_devices: int, tile_n: int
                  ) -> tuple[int, int]:
     """(m_pad, n_pad) of an (m, n) table padded to sublane groups and to
     whole tiles on each of ``n_devices`` devices."""
-    return (-(-m // _rs.SUBLANES) * _rs.SUBLANES,
+    return (-(-m // _ms.SUBLANES) * _ms.SUBLANES,
             -(-n // (n_devices * tile_n)) * n_devices * tile_n)
 
 
@@ -130,15 +128,6 @@ def shard_columnar(mesh: Mesh, cols: np.ndarray, tile_n: int = 1024) -> jax.Arra
             shape, NamedSharding(mesh, P(None, "data")), piece)
 
 
-def _local_scan(data_local, lo, up, *, tile_n: int, interpret: bool):
-    """One device's full scan of its object shard (backend-dispatched)."""
-    if ops.use_xla():
-        from repro.kernels import ref as _ref
-        return _ref.range_scan_ref(data_local, lo, up)
-    return _rs.range_scan_tiles(data_local, lo, up, tile_n=tile_n,
-                                interpret=interpret)
-
-
 def _local_multi_scan(data_local, lo, up, *, tile_n: int, interpret: bool):
     """One device's fused multi-query scan of its shard -> (Q, n_local).
 
@@ -150,149 +139,6 @@ def _local_multi_scan(data_local, lo, up, *, tile_n: int, interpret: bool):
     with jax.named_scope("sharded_scan"):
         return _ms.multi_scan_tiles(data_local, lo, up, tile_n=tile_n,
                                     interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh", "tile_n", "interpret"))
-def _distributed_mask_jit(
-    mesh: Mesh,
-    data_sharded: jax.Array,
-    qlo: jax.Array,
-    qhi: jax.Array,
-    *,
-    tile_n: int = 1024,
-    interpret: bool | None = None,
-) -> jax.Array:
-    ops.note_trace("distributed_mask")
-    if interpret is None:
-        interpret = ops.default_interpret()
-
-    def local_scan(data_local, lo, up):
-        return _local_scan(data_local, lo, up, tile_n=tile_n,
-                           interpret=interpret)
-
-    fn = shard_map_compat(
-        local_scan,
-        mesh=mesh,
-        in_specs=(P(None, "data"), P(), P()),
-        out_specs=P("data"),
-    )
-    return fn(data_sharded, qlo, qhi)
-
-
-distributed_mask = ops.counted(
-    "distributed_mask",
-    "Sharded single-query match mask: each device scans its own object shard "
-    "-> (n_pad,) int8, output sharded over 'data'.",
-)(_distributed_mask_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh", "tile_n", "interpret"))
-def _distributed_count_jit(
-    mesh: Mesh,
-    data_sharded: jax.Array,
-    qlo: jax.Array,
-    qhi: jax.Array,
-    *,
-    tile_n: int = 1024,
-    interpret: bool | None = None,
-) -> jax.Array:
-    ops.note_trace("distributed_count")
-    if interpret is None:
-        interpret = ops.default_interpret()
-
-    def local_count(data_local, lo, up):
-        mask = _local_scan(data_local, lo, up, tile_n=tile_n,
-                           interpret=interpret)
-        return jax.lax.psum(mask.astype(jnp.int32).sum(), "data")
-
-    fn = shard_map_compat(
-        local_count,
-        mesh=mesh,
-        in_specs=(P(None, "data"), P(), P()),
-        out_specs=P(),
-    )
-    return fn(data_sharded, qlo, qhi)
-
-
-distributed_count = ops.counted(
-    "distributed_count",
-    "Global single-query match count — one psum over the data axis (the "
-    "paper's result concatenation reduced to its cheapest sufficient "
-    "collective).",
-)(_distributed_count_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh", "tile_n", "interpret"))
-def _distributed_multi_mask_jit(
-    mesh: Mesh,
-    data_sharded: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = 1024,
-    interpret: bool | None = None,
-) -> jax.Array:
-    ops.note_trace("distributed_multi_mask")
-    if interpret is None:
-        interpret = ops.default_interpret()
-
-    def local_multi(data_local, lo, up):
-        return _local_multi_scan(data_local, lo, up, tile_n=tile_n,
-                                 interpret=interpret)
-
-    fn = shard_map_compat(
-        local_multi,
-        mesh=mesh,
-        in_specs=(P(None, "data"), P(), P()),
-        out_specs=P(None, "data"),
-    )
-    return fn(data_sharded, lower, upper)
-
-
-distributed_multi_mask = ops.counted(
-    "distributed_multi_mask",
-    "Cross-device fused batch scan: every device evaluates the whole (m_pad, "
-    "Q) replicated query batch against its own object shard in one "
-    "collective launch -> (Q, n_pad) int8 masks sharded over objects.",
-)(_distributed_multi_mask_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh", "tile_n", "interpret"))
-def _distributed_multi_counts_jit(
-    mesh: Mesh,
-    data_sharded: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = 1024,
-    interpret: bool | None = None,
-) -> jax.Array:
-    ops.note_trace("distributed_multi_counts")
-    if interpret is None:
-        interpret = ops.default_interpret()
-
-    def local_multi_counts(data_local, lo, up):
-        mask = _local_multi_scan(data_local, lo, up, tile_n=tile_n,
-                                 interpret=interpret)
-        # (Q,) partial counts per device; one psum concatenates the paper's
-        # partial result sets — only O(Q) ints cross the collective.
-        return jax.lax.psum(jnp.sum(mask != 0, axis=-1).astype(jnp.int32),
-                            "data")
-
-    fn = shard_map_compat(
-        local_multi_counts,
-        mesh=mesh,
-        in_specs=(P(None, "data"), P(), P()),
-        out_specs=P(),
-    )
-    return fn(data_sharded, lower, upper)
-
-
-distributed_multi_counts = ops.counted(
-    "distributed_multi_counts",
-    "Cross-device fused batch count: per-device (Q,) partial counts reduced "
-    "via one psum -> (Q,) int32 global match counts, replicated.",
-)(_distributed_multi_counts_jit)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "spec", "tile_n",
@@ -371,10 +217,9 @@ distributed_multi_reduce = ops.counted(
 class DistributedScan:
     """Horizontally partitioned scan over a device mesh (build-once facade).
 
-    Single-query (``mask`` / ``query`` / ``count``) and batched
-    (``mask_batch`` / ``query_batch`` / ``count_batch``) entry points mirror
-    ``ColumnarScan`` — batched calls are one collective launch and one host
-    sync per batch, with the same pow2 query-axis bucketing.
+    ``query_batch`` / ``launch_batch`` mirror ``ColumnarScan``'s: one
+    collective launch and one host sync per batch, with the same pow2
+    query-axis bucketing.
     """
 
     def __init__(self, dataset: T.Dataset, mesh: Mesh | None = None, tile_n: int = 1024):
@@ -388,21 +233,6 @@ class DistributedScan:
     @property
     def nbytes_index(self) -> int:
         return 0  # a scan needs no auxiliary structures (paper §8)
-
-    # -- single query ------------------------------------------------------
-    def mask(self, q: T.RangeQuery) -> np.ndarray:
-        qlo, qhi = ops.query_bounds_device(q, self.m_pad, self.data.dtype)
-        out = distributed_mask(self.mesh, self.data, qlo, qhi, tile_n=self.tile_n)
-        return ops.device_get(out)[: self.n] > 0
-
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        return np.nonzero(self.mask(q))[0].astype(np.int64)
-
-    def count(self, q: T.RangeQuery) -> int:
-        qlo, qhi = ops.query_bounds_device(q, self.m_pad, self.data.dtype)
-        total = distributed_count(self.mesh, self.data, qlo, qhi, tile_n=self.tile_n)
-        # subtract sentinel padding matches (there are none: +inf never matches)
-        return int(ops.device_get(total))
 
     # -- batched execution (one collective launch per batch) ---------------
     def _as_batch(self, batch) -> T.QueryBatch:
@@ -419,23 +249,6 @@ class DistributedScan:
                                               self.data.dtype)
         ops.count_scan_rows("sharded", batch.dims_mask, q_pad, self.m_pad)
         return lo, up
-
-    def mask_batch(self, batch) -> np.ndarray:
-        """(Q, n) bool match masks from one cross-device fused launch."""
-        batch = self._as_batch(batch)
-        lo, up = self._launch_bounds(batch)
-        out = distributed_multi_mask(self.mesh, self.data, lo, up,
-                                     tile_n=self.tile_n)
-        return ops.device_get(out)[: len(batch), : self.n] > 0
-
-    def count_batch(self, batch) -> list[int]:
-        """Per-query global counts: one collective launch + one psum, so the
-        host (and the collective) only ever see (Q,) ints."""
-        batch = self._as_batch(batch)
-        lo, up = self._launch_bounds(batch)
-        counts = distributed_multi_counts(self.mesh, self.data, lo, up,
-                                          tile_n=self.tile_n)
-        return [int(c) for c in ops.device_get(counts)[: len(batch)]]
 
     def query_batch(self, batch, spec=T.IDS, delta=None) -> list:
         """Batched execution under any ResultSpec: one collective launch

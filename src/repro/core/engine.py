@@ -5,17 +5,17 @@ available; kd-tree / R*-tree / VA-file optional), wraps each in its
 ``core.paths.AccessPath`` adapter, and answers range queries either with an
 explicitly named path or through the planner ("auto"). This is the paper's
 experimental matrix (§7.1.3) as a composable component — and the extension
-seam (DESIGN.md §6): all routing (single/batch, ids/count) is one lookup in
-the ``paths`` registry, so a new access path is ``register_path`` away from
-planning and execution, with no engine changes.
+seam (DESIGN.md §6): all routing is one lookup in the ``paths`` registry,
+so a new access path is ``register_path`` away from planning and execution,
+with no engine changes.
 
 Batched execution: ``query_batch`` takes a whole stream of queries at once —
 the inter-query-parallelism counterpart of the paper's intra-query parallel
 scans (§5). The planner's vectorized fixpoint (``Planner.plan_batch``)
 assigns every query an access path under *realized-bucket* cost
 amortization, each bucket executes through one fused multi-query launch
-(``kernels.multi_scan``), and results come back per query, identical to the
-single-query path. ``BatchStats`` splits ``plan_seconds`` from execution so
+(``kernels.multi_scan``), and results come back per query. ``query`` is a
+batch of one through the same bucket loop. ``BatchStats`` splits ``plan_seconds`` from execution so
 the planning cost is visible to ``benchmarks.bench_throughput``;
 ``serve.mdrq_server`` wraps the whole thing into a throughput front end.
 
@@ -52,10 +52,10 @@ from repro.core.rstar import build_rstar
 from repro.core.vafile import build_vafile
 from repro.core.planner import CostModel, Histograms, Planner
 
-# The built-in access paths (every name ``structures``/``rowscan``/``mesh``
-# can put in the registry). The registry itself — ``MDRQEngine.paths`` — is
-# the authoritative routing table; this tuple is the build vocabulary.
-ALL_METHODS = ("scan", "scan_vertical", "rowscan", "kdtree", "rstar", "vafile")
+# The built-in access paths (every name ``structures``/``mesh`` can put in
+# the registry). The registry itself — ``MDRQEngine.paths`` — is the
+# authoritative routing table; this tuple is the build vocabulary.
+ALL_METHODS = ("scan", "scan_vertical", "kdtree", "rstar", "vafile")
 RESULT_MODES = T.RESULT_MODES
 
 
@@ -207,7 +207,7 @@ class _EngineState:
     """
 
     def __init__(self, dataset: T.Dataset, structures: tuple[str, ...],
-                 tile_n: int, rowscan: bool, mesh, version: int = 0):
+                 tile_n: int, mesh, version: int = 0):
         self.dataset = dataset
         self.tile_n = tile_n
         self.version = version
@@ -221,7 +221,6 @@ class _EngineState:
                      if mesh is not None else None)
         self._columnar = (None if mesh is not None
                           else scan_mod.build_columnar_scan(dataset, tile_n=tile_n))
-        self.rowscan = scan_mod.build_row_scan(dataset) if rowscan else None
         self.kdtree = build_kdtree(dataset, tile_n=tile_n) if "kdtree" in structures else None
         self.rstar = build_rstar(dataset, tile_n=tile_n) if "rstar" in structures else None
         self.vafile = build_vafile(dataset, tile_n=tile_n) if "vafile" in structures else None
@@ -248,11 +247,6 @@ class _EngineState:
         else:
             self.add_path(paths_mod.ColumnarScanPath(self._columnar))
             self.add_path(paths_mod.VerticalScanPath(lambda: self.columnar))
-        if self.rowscan is not None:
-            # no fused batch kernel for the row layout — per-query fallback;
-            # host columns enable the reduced specs' from_ids finalization
-            self.add_path(paths_mod.PerQueryPath("rowscan", self.rowscan,
-                                                 cols=dataset.cols))
         for index in (self.kdtree, self.rstar):
             if index is not None:
                 self.add_path(paths_mod.BlockedIndexPath(index))
@@ -277,7 +271,7 @@ class _EngineState:
 
     def add_path(self, path: paths_mod.AccessPath) -> None:
         for attr in ("name", "plannable", "owns_storage", "nbytes_index",
-                     "query", "count", "query_batch", "cost", "cost_batch"):
+                     "query_batch", "cost", "cost_batch"):
             if not hasattr(path, attr):
                 raise TypeError(f"access path lacks {attr!r} "
                                 f"(see core.paths.AccessPath)")
@@ -294,14 +288,12 @@ class MDRQEngine:
         dataset: T.Dataset,
         structures: tuple[str, ...] = ("scan", "kdtree", "rstar", "vafile"),
         tile_n: int = 1024,
-        rowscan: bool = False,
         mesh=None,
     ):
         # Build parameters persist so ``compact`` can rebuild the same
         # structure set over the merged dataset.
         self._structures = tuple(structures)
         self.tile_n = tile_n
-        self._rowscan_enabled = bool(rowscan)
         self._mesh = mesh
         # Serializes the write side (append/delete/compact-commit); the read
         # side is lock-free — queries capture ``self._state`` once.
@@ -324,8 +316,8 @@ class MDRQEngine:
                     f"a ({dataset.m}, {dataset.n}) table is sharded over "
                     f"{mesh.shape['data']} devices; {sorted(unsharded)} "
                     f"would place it whole on one")
-        return _EngineState(dataset, self._structures, self.tile_n,
-                            self._rowscan_enabled, mesh, version=version)
+        return _EngineState(dataset, self._structures, self.tile_n, mesh,
+                            version=version)
 
     # -- versioned-state views ---------------------------------------------
     # Pre-versioning callers read these as plain attributes; each delegates
@@ -338,10 +330,6 @@ class MDRQEngine:
     @property
     def dist(self):
         return self._state.dist
-
-    @property
-    def rowscan(self):
-        return self._state.rowscan
 
     @property
     def kdtree(self):
@@ -576,6 +564,10 @@ class MDRQEngine:
         """Execute q under a ResultSpec -> sorted ids (default ``Ids()``),
         an int count, a bool mask, top-k ids, or an aggregate; records
         QueryStats. ``mode="ids"|"count"`` is the deprecated string alias.
+
+        A batch of one: ``planner.explain`` picks the path ("auto"), then q
+        runs through the bucket loop of ``query_batch``, with the same
+        launches and host syncs as ``query_batch([q], method=<that path>)``.
         """
         state = self._state
         if q.m != state.dataset.m:
@@ -588,26 +580,33 @@ class MDRQEngine:
             method, est = plan.method, plan.est_selectivity
         else:
             est = state.planner.hist.selectivity(q)
-        path = _lookup_path(state.paths, method)
+        batch = T.QueryBatch.from_queries([q])
         t0 = time.perf_counter()
-        if not dview.is_empty:
-            # Singles see only the frozen base — with a live delta every
-            # spec (ids and count included) rides the delta-aware batch
-            # rung at Q=1.
-            res = self._path_query_batch(
-                path, T.QueryBatch.from_queries([q]), spec, delta=dview)[0]
-        elif spec.kind == "ids":    # dedicated single-query fast paths for
-            res = path.query(q)     # the two historical shapes; every other
-        elif spec.kind == "count":  # spec rides the batch rung at Q=1
-            res = path.count(q)
-        else:
-            res = self._path_query_batch(
-                path, T.QueryBatch.from_queries([q]), spec)[0]
+        res = self._execute(state, batch, _buckets([method], batch.dims_mask),
+                            spec, None if dview.is_empty else dview)[0]
         dt = time.perf_counter() - t0
         self.last_stats = QueryStats(method=method, seconds=dt,
                                      n_results=spec.result_size(res),
                                      est_selectivity=est)
         return res
+
+    def _execute(self, state: _EngineState, batch: T.QueryBatch,
+                 buckets: dict[str, np.ndarray], spec: T.ResultSpec,
+                 delta) -> list:
+        """Run each bucket through its path, synchronously -> per-query
+        results, positionally aligned with ``batch``."""
+        results: list = [None] * len(batch)
+        for meth, idxs in buckets.items():
+            sub = T.QueryBatch(batch.lower[idxs], batch.upper[idxs])
+            path = _lookup_path(state.paths, meth)
+            with obs_tracing.span(
+                    "execute", path=meth, bucket=len(idxs),
+                    n_devices=getattr(path, "n_devices", None)) as sp:
+                out = self._path_query_batch(path, sub, spec, delta=delta)
+                sp.block_on(out)
+            for k, res in zip(idxs, out):
+                results[k] = res
+        return results
 
     def query_batch(
         self,
@@ -625,7 +624,8 @@ class MDRQEngine:
         ``method="auto"``, or the explicit method for all) and each bucket
         runs through a single fused multi-query launch carrying the spec's
         on-device reducer. Results are positionally aligned with the input
-        and identical to per-query ``query`` calls; aggregate ``BatchStats``
+        and do not depend on the other queries of the batch; aggregate
+        ``BatchStats``
         land in ``last_batch_stats`` with the planning share in
         ``plan_seconds``.
 
@@ -675,19 +675,7 @@ class MDRQEngine:
             plan_dt = time.perf_counter() - t0
 
             buckets = _buckets(methods, batch.dims_mask)
-
-            results: list = [None] * len(batch)
-            for meth, idxs in buckets.items():
-                sub = T.QueryBatch(batch.lower[idxs], batch.upper[idxs])
-                path = _lookup_path(state.paths, meth)
-                with obs_tracing.span(
-                        "execute", path=meth, bucket=len(idxs),
-                        n_devices=getattr(path, "n_devices", None)) as sp:
-                    out = self._path_query_batch(path, sub, spec,
-                                                 delta=delta_arg)
-                    sp.block_on(out)
-                for k, res in zip(idxs, out):
-                    results[k] = res
+            results = self._execute(state, batch, buckets, spec, delta_arg)
             dt = time.perf_counter() - t0
         finally:
             if tracer is not None:

@@ -4,8 +4,8 @@ The paper's experimental matrix (§7.1.3) — scans, tree MDIS, VA-file — is a
 set of interchangeable access paths behind one query interface. This module
 makes that matrix explicit (DESIGN.md §6): ``AccessPath`` is the protocol
 every path speaks, the ``*Path`` adapters put the concrete structures
-(``ColumnarScan``, ``RowScan``, ``DistributedScan``, ``BlockedIndex``,
-``VAFile``) behind it, and ``MDRQEngine`` becomes a name -> path registry —
+(``ColumnarScan``, ``DistributedScan``, ``BlockedIndex``, ``VAFile``) behind
+it, and ``MDRQEngine`` becomes a name -> path registry —
 adding a path (grid file, learned layout, ...) means registering one object,
 not editing three dispatch chains.
 
@@ -21,9 +21,9 @@ Conventions:
   * ``cost``/``cost_batch`` return ``inf`` where the path is not applicable
     (e.g. the vertical scan on a complete-match query) — the planner skips
     non-finite entries.
-  * ``plannable=False`` paths execute only when named explicitly
-    (``rowscan``; the vertical scan on a meshed engine, where an "auto"
-    choice would lazily re-place the dataset on one device).
+  * ``plannable=False`` paths execute only when named explicitly (a
+    ``PerQueryPath`` by default; the vertical scan on a meshed engine, where
+    an "auto" choice would lazily re-place the dataset on one device).
   * ``owns_storage=False`` marks views over another path's arrays so
     ``memory_report`` never double-counts (the vertical scan shares the
     columnar scan's data).
@@ -129,13 +129,14 @@ class PlanInputs:
 class AccessPath(Protocol):
     """What the engine registry and the planner require of a path.
 
-    Execution surface: ``query``/``count`` singles and
-    ``query_batch(batch, spec)`` (one fused launch per bucket; ``spec`` is a
-    ``types.ResultSpec`` — ids, count, mask, top-k, aggregate — whose
-    on-device reducer the path's launch carries). Planning surface: ``cost``
-    (scalar) and ``cost_batch`` (vectorized over a ``PlanInputs``), both
-    taking the spec so reduced result shapes price their smaller host
-    payload. ``PerQueryPath`` adapts anything that only has singles.
+    Execution surface: ``query_batch(batch, spec)`` (one fused launch per
+    bucket; ``spec`` is a ``types.ResultSpec`` — ids, count, mask, top-k,
+    aggregate — whose on-device reducer the path's launch carries); a single
+    query is a batch of one. Planning surface: ``cost`` (scalar) and
+    ``cost_batch`` (vectorized over a ``PlanInputs``), both taking the spec
+    so reduced result shapes price their smaller host payload.
+    ``PerQueryPath`` adapts anything that only has per-query
+    ``query``/``count`` functions.
     """
 
     name: str
@@ -144,10 +145,6 @@ class AccessPath(Protocol):
 
     @property
     def nbytes_index(self) -> int: ...
-
-    def query(self, q: T.RangeQuery) -> np.ndarray: ...
-
-    def count(self, q: T.RangeQuery) -> int: ...
 
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS) -> Results: ...
@@ -239,12 +236,6 @@ class ColumnarScanPath(ScanCost):
     def nbytes_index(self) -> int:
         return self._scan.nbytes_index
 
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        return self._scan.query(q)
-
-    def count(self, q: T.RangeQuery) -> int:
-        return self._scan.count(q)
-
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
         return self._scan.query_batch(batch, spec=spec, delta=delta)
@@ -269,12 +260,6 @@ class DistributedScanPath(ScanCost):
     @property
     def nbytes_index(self) -> int:
         return self._dist.nbytes_index
-
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        return self._dist.query(q)
-
-    def count(self, q: T.RangeQuery) -> int:
-        return self._dist.count(q)
 
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
@@ -305,12 +290,6 @@ class VerticalScanPath(VerticalScanCost):
     def nbytes_index(self) -> int:
         return 0
 
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        return self._scan_ref().query_partial(q)
-
-    def count(self, q: T.RangeQuery) -> int:
-        return self._scan_ref().count_partial(q)
-
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
         return self._scan_ref().query_batch(batch, partial=True, spec=spec,
@@ -336,12 +315,6 @@ class BlockedIndexPath(TreeCost):
     def nbytes_index(self) -> int:
         return self._index.nbytes_index
 
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        return self._index.query(q)
-
-    def count(self, q: T.RangeQuery) -> int:
-        return self._index.count(q)
-
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
         return self._index.query_batch(batch, spec=spec, delta=delta)
@@ -366,12 +339,6 @@ class VAFilePath(VAFileCost):
     def nbytes_index(self) -> int:
         return self._vafile.nbytes_index
 
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        return self._vafile.query(q)
-
-    def count(self, q: T.RangeQuery) -> int:
-        return self._vafile.count(q)
-
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
         return self._vafile.query_batch(batch, spec=spec, delta=delta)
@@ -382,12 +349,13 @@ class VAFilePath(VAFileCost):
 
 
 class PerQueryPath:
-    """Generic adapter: any object with single-query ``query``/``count``
-    becomes a full ``AccessPath`` whose batch execution is a per-query loop.
+    """Generic adapter: any object with per-query ``query``/``count``
+    functions becomes a full ``AccessPath`` whose batch execution is a
+    per-query loop over them.
 
-    This is the fallback rung of the layer — structures without a fused batch
-    kernel (``RowScan``, prototypes, test doubles) still ride the registry,
-    paying Q launches instead of one. Reduced result shapes ride the spec's
+    This is the extension rung of the layer — registered structures without
+    a fused batch kernel (prototypes, test doubles) still ride the registry,
+    paying Q calls instead of one launch. Reduced result shapes ride the spec's
     *host* fallback: ids materialize per query and ``ResultSpec.from_ids``
     finalizes against the host columns (pass ``cols`` to enable — specs that
     read attribute values need it). Not plannable by default: a path whose
@@ -409,27 +377,21 @@ class PerQueryPath:
     def nbytes_index(self) -> int:
         return int(getattr(self._impl, "nbytes_index", 0))
 
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        return self._impl.query(q)
-
-    def count(self, q: T.RangeQuery) -> int:
-        return self._impl.count(q)
-
     def query_batch(self, batch: T.QueryBatch,
                     spec: T.ResultSpec = T.IDS, delta=None) -> Results:
         spec = T.validate_mode(spec)
         if delta is not None and not delta.is_empty:
             return self._query_batch_delta(batch, spec, delta)
         if spec.kind == "ids":
-            return [self.query(batch[k]) for k in range(len(batch))]
+            return [self._impl.query(batch[k]) for k in range(len(batch))]
         if spec.kind == "count":
             # the impl's own count (device-reduced where it has one)
-            return [self.count(batch[k]) for k in range(len(batch))]
+            return [self._impl.count(batch[k]) for k in range(len(batch))]
         if self._cols is None:
             raise ValueError(
                 f"path {self.name!r} has no host columns for result spec "
                 f"{spec.kind!r}; construct PerQueryPath(..., cols=...)")
-        return [spec.from_ids(self.query(batch[k]), self._cols)
+        return [spec.from_ids(self._impl.query(batch[k]), self._cols)
                 for k in range(len(batch))]
 
     def _query_batch_delta(self, batch: T.QueryBatch, spec: T.ResultSpec,
@@ -443,7 +405,7 @@ class PerQueryPath:
         out = []
         for k in range(len(batch)):
             q = batch[k]
-            ids = np.asarray(self.query(q), np.int64)
+            ids = np.asarray(self._impl.query(q), np.int64)
             if delta.has_base_tombs:
                 ids = ids[~delta.base_tomb[ids]]
             ids = np.concatenate([ids, delta.match_delta_ids(q)])

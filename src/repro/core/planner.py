@@ -325,7 +325,7 @@ class CostModel:
                     spec=None) -> float:
         words = -(-self.m // VA_DIMS_PER_WORD)  # packing density of the kernel
         # Both phases are fused per batch (``multi_va_filter`` +
-        # ``multi_range_scan_visit``): the packed words stream from HBM once
+        # ``multi_visit_reduce``): the packed words stream from HBM once
         # per *batch* — down to the VPU unpack-compare floor — and both sync
         # halves (the phase-1 survivor-bit readback, now one (Q, n_blocks)
         # array, and the visit-mask readback) divide by the batch, as do the
@@ -366,8 +366,6 @@ class CostModel:
                 / (b * max(self.n_devices, 1)) + dbytes
         if method == "scan_vertical":
             return self.n * mq * self.bytes_per_val / b + dbytes
-        if method == "rowscan":
-            return float(self.n * self.m * self.bytes_per_val) + dbytes
         if method in ("kdtree", "rstar"):
             n_leaves = -(-self.n // self.tile_n)
             prune = 2 * n_leaves * self.m * self.bytes_per_val / b

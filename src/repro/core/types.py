@@ -785,24 +785,13 @@ def pad_axis(x: np.ndarray, axis: int, multiple: int, value) -> np.ndarray:
     return np.pad(x, widths, constant_values=value)
 
 
-def padded_query_bounds(
-    q: RangeQuery, m_padded: int
-) -> tuple[np.ndarray, np.ndarray]:
-    """Query bounds padded to ``m_padded`` dims with [-inf, +inf] (match-all)."""
-    lo = np.full((m_padded,), NEG_INF, np.float32)
-    up = np.full((m_padded,), POS_INF, np.float32)
-    lo[: q.m] = q.lower
-    up[: q.m] = q.upper
-    return lo, up
-
-
 def finite_query_bounds(lo: np.ndarray, up: np.ndarray, dtype=np.float32):
     """Replace +-inf with the *target device dtype's* finite extrema.
 
     ``dtype`` must be the dtype the comparison actually runs in: substituting
     float32 extrema under a bfloat16 cast rounds ``finfo(f32).max`` back to
     ``+inf``, so the +inf object-padding sentinels *match* and every
-    padded-axis reduction (``mask_counts``, visit segment counts, psum counts)
+    padded-axis reduction (scan-mask counts, visit segment counts, psum counts)
     overcounts. ``jnp.finfo`` understands bfloat16 (ml_dtypes); extrema are
     additionally clamped into float32's finite range because these carrier
     arrays are float32 — for a wider dtype (f64 under jax x64) the f32

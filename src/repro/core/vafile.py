@@ -7,11 +7,11 @@ therefore the most TPU-friendly of the paper's MDIS:
     ``b_j = 2``), boundaries either equal-width over the observed domain (the
     paper's choice) or equal-frequency (exposed as an option, which the paper
     lists as an obvious improvement direction, §8);
-  * phase 1: the ``va_filter`` Pallas kernel compares packed approximations
-    (16 dims / int32 word) against the approximated query — ints instead of
-    floats, 16x less HBM traffic than the exact scan;
+  * phase 1: the ``multi_va_filter`` Pallas kernel compares packed
+    approximations (16 dims / int32 word) against the approximated queries —
+    ints instead of floats, 16x less HBM traffic than the exact scan;
   * phase 2: leaf blocks containing at least one candidate are refined with
-    the exact ``range_scan_visit`` kernel. Blocks with zero candidates are
+    the exact ``multi_scan_visit`` kernel. Blocks with zero candidates are
     never touched — the paper's "buckets whose approximation intersects".
 
 Unlike the tree MDIS, data stays in storage order (no permutation): the
@@ -22,9 +22,8 @@ Batched execution runs *both* phases fused: phase 1 is one
 fetched from HBM once per batch) whose candidate masks reduce to per-
 (query, block) survivor bits on device — a single small (Q, n_blocks) bool
 readback replaces Q per-query mask transfers — and phase 2 flattens the
-surviving pairs into one ``multi_range_scan_visit`` launch, exactly like the
-tree MDIS. The per-query phases-1 regime this replaced was the one term the
-cost model could not amortize (see ``planner.cost_vafile``).
+surviving pairs into one ``multi_visit_reduce`` launch, exactly like the
+tree MDIS. A single query is a batch of one.
 """
 from __future__ import annotations
 
@@ -58,7 +57,7 @@ class VAFile:
     m: int
     n: int
 
-    last_candidate_frac: float = 0.0
+    # (query, block) pairs the last batch visited
     last_visited_blocks: int = 0
 
     @property
@@ -70,25 +69,14 @@ class VAFile:
     def _m_sublane(self) -> int:
         return -(-self.m // 8) * 8
 
-    def query_cells(self, q: T.RangeQuery) -> tuple[np.ndarray, np.ndarray]:
-        """Approximate the query: per-dim [cell_lo, cell_hi] intersected cells."""
-        cell_lo = np.zeros((self.m,), np.int32)
-        cell_hi = np.full((self.m,), CELLS - 1, np.int32)
-        for d in range(self.m):
-            b = self.boundaries[d]
-            # cell of x = #boundaries <= x  (boundaries are inner edges)
-            cell_lo[d] = np.searchsorted(b, q.lower[d], side="right") if np.isfinite(q.lower[d]) else 0
-            cell_hi[d] = np.searchsorted(b, q.upper[d], side="right") if np.isfinite(q.upper[d]) else CELLS - 1
-        return cell_lo, cell_hi
-
     def query_cells_batch(self, batch: T.QueryBatch, q_pad: int | None = None
                           ) -> tuple[np.ndarray, np.ndarray]:
         """Query-minor (m_s, q_pad or Q) cell bounds for the batched filter.
 
         Sublane-padded rows — and padding query columns beyond Q — carry
         [0, CELLS-1] match-all bounds (padding queries' rows are dropped by
-        the caller). Per-query values are identical to ``query_cells``:
-        ``searchsorted`` maps -inf to cell 0 and +inf to the last cell.
+        the caller). ``searchsorted`` maps -inf to cell 0 and +inf to the
+        last cell.
         """
         q_n = len(batch)
         width = q_pad or q_n
@@ -100,57 +88,6 @@ class VAFile:
             cell_hi[d, :q_n] = np.searchsorted(b, batch.upper[:, d], side="right")
         return cell_lo, cell_hi
 
-    def query(self, q: T.RangeQuery) -> np.ndarray:
-        """Two-phase query -> sorted matching object ids."""
-        survivors = self._candidate_blocks(q)
-        self.last_visited_blocks = int(survivors.size)
-        if survivors.size == 0:
-            return np.empty((0,), np.int64)
-        masks = self._refine(survivors, q)
-        pos = survivors[:, None] * self.tile_n + np.arange(self.tile_n)[None, :]
-        pos = pos[masks > 0]  # already on host: _refine syncs via device_get
-        return np.sort(pos[pos < self.n]).astype(np.int64)
-
-    def count(self, q: T.RangeQuery) -> int:
-        """Count-only query: refinement masks are summed on device (object
-        padding is +inf and never survives the exact compare)."""
-        survivors = self._candidate_blocks(q)
-        self.last_visited_blocks = int(survivors.size)
-        if survivors.size == 0:
-            return 0
-        masks = self._refine(survivors, q, to_host=False)
-        return int(ops.device_get(jnp.sum(masks != 0)))
-
-    def _refine(self, survivors: np.ndarray, q: T.RangeQuery,
-                to_host: bool = True):
-        """Phase 2: exact visit scan of the surviving blocks -> (v, tile_n)."""
-        n_visit = _next_pow2(survivors.size)
-        ids = np.full((n_visit,), -1, np.int32)
-        ids[: survivors.size] = survivors
-        qlo_f, qhi_f = ops.query_bounds_device(q, self.data_dev.shape[0], self.data_dev.dtype)
-        masks = ops.range_scan_visit(self.data_dev, jnp.asarray(ids), qlo_f,
-                                     qhi_f, tile_n=self.tile_n)
-        masks = masks[: survivors.size]  # padding visits (id -1) drop
-        return ops.device_get(masks) if to_host else masks
-
-    def _candidate_blocks(self, q: T.RangeQuery) -> np.ndarray:
-        """Phase 1 for one query: block ids containing >= 1 VA candidate."""
-        cell_lo, cell_hi = self.query_cells(q)
-        m_s = self._m_sublane
-        qlo = np.zeros((m_s, 1), np.int32)
-        qhi = np.full((m_s, 1), CELLS - 1, np.int32)
-        qlo[: self.m, 0] = cell_lo
-        qhi[: self.m, 0] = cell_hi
-        cand = ops.device_get(ops.va_filter(
-            self.packed_dev, jnp.asarray(qlo), jnp.asarray(qhi), m=self.m,
-            tile_n=self.tile_n,
-        )) > 0
-        self.last_candidate_frac = float(cand[: self.n].mean())
-        n_blocks = self.data_dev.shape[1] // self.tile_n
-        block_any = cand[: n_blocks * self.tile_n].reshape(
-            n_blocks, self.tile_n).any(axis=1)
-        return np.nonzero(block_any)[0].astype(np.int32)
-
     def _candidate_blocks_batch(self, batch: T.QueryBatch
                                 ) -> tuple[np.ndarray, np.ndarray]:
         """Batched phase 1: one fused filter launch, one small host sync.
@@ -159,7 +96,7 @@ class VAFile:
         launch and reduces the candidate masks to per-
         (query, block) survivor bits on device, so the only device->host
         transfer of the phase is one (Q, n_blocks) bool array — the batch
-        counterpart of the Q mask readbacks the per-query path paid.
+        counterpart of Q per-query mask readbacks.
         """
         q_n = len(batch)
         q_pad = _next_pow2(q_n)  # pow2 query bucket bounds jit retraces

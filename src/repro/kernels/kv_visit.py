@@ -4,7 +4,7 @@
 `take_along_axis` over the block axis lowers to cross-device all-gathers and
 the gather's HLO cost counts the full cache operand. This kernel is the
 TPU-native fix — the same scalar-prefetch visit-list idiom as
-``range_scan_visit`` (the MDRQ engine's two-phase refine), applied to
+``multi_scan_visit`` (the MDRQ engine's two-phase refine), applied to
 attention:
 
   * the host (or a tiny jnp prune pass over the zone maps) produces a per

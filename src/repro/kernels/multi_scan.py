@@ -7,6 +7,13 @@ launch pays the full dispatch + host-sync tax per query. These kernels
 evaluate a (Q, m) batch of query boxes against the (m, n) columnar dataset in
 one launch, so the fixed overheads amortize over Q and each VMEM data tile is
 fetched from HBM *once* and compared against all Q queries while resident.
+They are the engine's only scan kernels: a single query is a batch of one.
+
+The layout is the TPU adaptation of the paper's Listing 2 (DESIGN.md §2):
+data is dimension-major ``(m_pad, n_pad)``, so the lane axis runs over
+objects and one VPU op compares 128 objects of one attribute against one
+bound; the AND across dimensions happens in vector registers. m pads to a
+multiple of ``SUBLANES``, tiles to a multiple of ``LANES``.
 
 Two kernels:
 
@@ -39,8 +46,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.range_scan import (DEFAULT_TILE_N, INT8_SUBLANES, LANES,
-                                      SUBLANES)
+LANES = 128
+SUBLANES = 8
+# int8's native tile is (32, 128): batched kernels write their (Q, tile_n)
+# int8 masks in chunks of this many query rows.
+INT8_SUBLANES = 32
+DEFAULT_TILE_N = 1024
 
 
 # A chunk that flags every row compares them in straight-line code, as the

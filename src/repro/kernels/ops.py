@@ -5,12 +5,13 @@ the tile size with +inf sentinel objects that never match), dtype casting of
 the bounds, and interpret-mode selection (interpret=True on CPU so the kernel
 body executes as the oracle-checked reference path; compiled Mosaic on TPU).
 
-Batched execution: the ``multi_range_scan*`` wrappers drive the fused
-multi-query kernels (``kernels.multi_scan``) — (m_pad, Q) query-minor bounds,
-one launch for a whole query batch — and ``multi_va_filter`` does the same
-for the VA-file's packed approximation phase. On the XLA backend they route
-to the per-dimension-accumulating refs in ``ref.py``, which are also the
-honest CPU throughput proxy for ``benchmarks/bench_throughput.py``.
+Batched execution: the ``multi_*`` ops drive the fused multi-query kernels
+(``kernels.multi_scan``) — (m_pad, Q) query-minor bounds, one launch for a
+whole query batch — and ``multi_va_filter`` does the same for the VA-file's
+packed approximation phase. A single query is a batch of one: there are no
+single-query ops. On the XLA backend they route to the
+per-dimension-accumulating refs in ``ref.py``, which are also the honest CPU
+throughput proxy for ``benchmarks/bench_throughput.py``.
 
 Instrumentation: every public op is built by ``_counted`` — a plain-Python
 wrapper that bumps a named launch counter before delegating to the jitted
@@ -38,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import multi_scan as _ms
-from repro.kernels import range_scan as _rs
 from repro.kernels import ref as _ref
 from repro.kernels import va_filter as _va
 
@@ -352,7 +352,7 @@ _counted = counted  # historical spelling used by the in-module registrations
 
 
 def prepare_columnar(
-    cols: np.ndarray, tile_n: int = _rs.DEFAULT_TILE_N, dtype=jnp.float32
+    cols: np.ndarray, tile_n: int = _ms.DEFAULT_TILE_N, dtype=jnp.float32
 ) -> tuple[np.ndarray, int, int]:
     """Pad (m, n) columnar data for the kernel.
 
@@ -363,24 +363,9 @@ def prepare_columnar(
     """
     from repro.core import types as T  # deferred: breaks ops<->core cycle
     m, n = cols.shape
-    x = T.pad_axis(cols, 0, _rs.SUBLANES, 0.0)
+    x = T.pad_axis(cols, 0, _ms.SUBLANES, 0.0)
     x = T.pad_axis(x, 1, tile_n, np.inf)
     return np.asarray(x, dtype=np.float32 if dtype == jnp.float32 else x.dtype), m, n
-
-
-def query_bounds_device(q: T.RangeQuery, m_pad: int, dtype) -> tuple[jax.Array, jax.Array]:
-    """(m_pad, 1) finite device bounds for a query (pad rows = match-all).
-
-    ``dtype`` threads into the match-all substitution so the extrema stay
-    finite *in the comparison dtype* (float32 extrema round to +inf under a
-    bfloat16 cast and would match the +inf padding sentinels).
-    """
-    from repro.core import types as T  # deferred: breaks ops<->core cycle
-    lo, up = T.padded_query_bounds(q, m_pad)
-    lo, up = T.finite_query_bounds(lo, up, dtype=dtype)
-    lo_d = jnp.asarray(lo, dtype=dtype).reshape(-1, 1)
-    up_d = jnp.asarray(up, dtype=dtype).reshape(-1, 1)
-    return lo_d, up_d
 
 
 def batch_bounds_device(batch, m_pad: int, dtype,
@@ -396,214 +381,6 @@ def batch_bounds_device(batch, m_pad: int, dtype,
         batch = T.QueryBatch.from_queries(list(batch))
     lo, up = batch.bounds_columnar(m_pad, q_pad, dtype=dtype)
     return jnp.asarray(lo, dtype=dtype), jnp.asarray(up, dtype=dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def _range_scan_jit(
-    data_cm: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = _rs.DEFAULT_TILE_N,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("range_scan")
-    if use_xla():
-        return _ref.range_scan_ref(data_cm, lower, upper)
-    if interpret is None:
-        interpret = default_interpret()
-    return _rs.range_scan_tiles(
-        data_cm, lower, upper, tile_n=tile_n, interpret=interpret
-    )
-
-
-range_scan = _counted(
-    "range_scan",
-    "Full vectorized range scan over padded columnar data -> (n_pad,) int8.",
-)(_range_scan_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def _range_scan_visit_jit(
-    data_cm: jax.Array,
-    block_ids: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = _rs.DEFAULT_TILE_N,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("range_scan_visit")
-    if use_xla():
-        m_pad, n_pad = data_cm.shape
-        blocks = data_cm.reshape(m_pad, n_pad // tile_n, tile_n).transpose(1, 0, 2)
-        return _ref.range_scan_blocks_ref(blocks, block_ids,
-                                          lower[:, 0], upper[:, 0])
-    if interpret is None:
-        interpret = default_interpret()
-    return _rs.range_scan_visit(
-        data_cm, block_ids, lower, upper, tile_n=tile_n, interpret=interpret
-    )
-
-
-range_scan_visit = _counted(
-    "range_scan_visit",
-    "Scan only the listed tile ids -> (n_visit, tile_n) int8 masks.",
-)(_range_scan_visit_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def _range_scan_vertical_jit(
-    data_cm: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = _rs.DEFAULT_TILE_N,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("range_scan_vertical")
-    if interpret is None:
-        interpret = default_interpret()
-    # the batched scan at Q=1, which compares only the bounded dims
-    return _multi_scan_masks(data_cm, lower, upper, tile_n=tile_n,
-                             interpret=interpret)[0]
-
-
-range_scan_vertical = _counted(
-    "range_scan_vertical",
-    "Partial-match scan comparing only the bounded dims -> (n_pad,) int8.",
-)(_range_scan_vertical_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def _multi_range_scan_jit(
-    data_cm: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = _rs.DEFAULT_TILE_N,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("multi_range_scan")
-    if use_xla():
-        return _ref.multi_scan_ref(data_cm, lower, upper)
-    if interpret is None:
-        interpret = default_interpret()
-    return _ms.multi_scan_tiles(
-        data_cm, lower, upper, tile_n=tile_n, interpret=interpret
-    )
-
-
-multi_range_scan = _counted(
-    "multi_range_scan",
-    "Fused full scan of a query batch -> (Q, n_pad) int8 masks.",
-)(_multi_range_scan_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def _multi_range_scan_vertical_jit(
-    data_cm: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = _rs.DEFAULT_TILE_N,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("multi_range_scan_vertical")
-    if interpret is None:
-        interpret = default_interpret()
-    return _multi_scan_masks(data_cm, lower, upper, tile_n=tile_n,
-                             interpret=interpret)
-
-
-multi_range_scan_vertical = _counted(
-    "multi_range_scan_vertical",
-    "Batched partial-match scan: the fused scan, counted and named apart "
-    "-> (Q, n_pad) int8 masks.",
-)(_multi_range_scan_vertical_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def _multi_range_scan_visit_jit(
-    data_cm: jax.Array,
-    query_ids: jax.Array,
-    block_ids: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_n: int = _rs.DEFAULT_TILE_N,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("multi_range_scan_visit")
-    if use_xla():
-        m_pad, n_pad = data_cm.shape
-        blocks = data_cm.reshape(m_pad, n_pad // tile_n, tile_n).transpose(1, 0, 2)
-        return _ref.multi_scan_blocks_ref(blocks, query_ids, block_ids, lower, upper)
-    if interpret is None:
-        interpret = default_interpret()
-    return _ms.multi_scan_visit(
-        data_cm, query_ids, block_ids, lower, upper, tile_n=tile_n,
-        interpret=interpret,
-    )
-
-
-multi_range_scan_visit = _counted(
-    "multi_range_scan_visit",
-    "Batched two-phase refinement over a (query, block) visit list "
-    "-> (V, tile_n) int8 per-visit masks.",
-)(_multi_range_scan_visit_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
-def _range_scan_rows_jit(
-    data_rm: jax.Array,
-    lower: jax.Array,
-    upper: jax.Array,
-    *,
-    tile_rows: int = 512,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("range_scan_rows")
-    if use_xla():
-        ok = jnp.logical_and(data_rm >= lower, data_rm <= upper)
-        return jnp.all(ok, axis=1).astype(jnp.int8)
-    if interpret is None:
-        interpret = default_interpret()
-    return _rs.range_scan_rows(
-        data_rm, lower, upper, tile_rows=tile_rows, interpret=interpret
-    )
-
-
-range_scan_rows = _counted(
-    "range_scan_rows",
-    "Row-major (horizontal layout) scan -> (n_pad,) int8.",
-)(_range_scan_rows_jit)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "tile_n", "interpret"))
-def _va_filter_jit(
-    packed: jax.Array,
-    cell_lo: jax.Array,
-    cell_hi: jax.Array,
-    m: int,
-    *,
-    tile_n: int = _va.DEFAULT_TILE_N,
-    interpret: bool | None = None,
-) -> jax.Array:
-    note_trace("va_filter")
-    if use_xla():
-        return _ref.va_filter_packed_ref(packed, cell_lo[:, 0], cell_hi[:, 0], m)
-    if interpret is None:
-        interpret = default_interpret()
-    return _va.va_filter_packed(
-        packed, cell_lo, cell_hi, m, tile_n=tile_n, interpret=interpret
-    )
-
-
-va_filter = _counted(
-    "va_filter",
-    "Packed VA-file approximation filter -> (n_pad,) int8 candidate mask.",
-)(_va_filter_jit)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "tile_n", "block_n", "interpret"))
@@ -707,7 +484,7 @@ def _multi_scan_reduce_jit(
     base_tomb: jax.Array | None = None,
     *,
     spec,
-    tile_n: int = _rs.DEFAULT_TILE_N,
+    tile_n: int = _ms.DEFAULT_TILE_N,
     interpret: bool | None = None,
 ):
     note_trace("multi_scan_reduce")
@@ -732,7 +509,7 @@ def _multi_scan_vertical_reduce_jit(
     base_tomb: jax.Array | None = None,
     *,
     spec,
-    tile_n: int = _rs.DEFAULT_TILE_N,
+    tile_n: int = _ms.DEFAULT_TILE_N,
     interpret: bool | None = None,
 ):
     note_trace("multi_scan_vertical_reduce")
@@ -761,7 +538,7 @@ def _multi_visit_reduce_jit(
     base_tomb: jax.Array | None = None,
     *,
     spec,
-    tile_n: int = _rs.DEFAULT_TILE_N,
+    tile_n: int = _ms.DEFAULT_TILE_N,
     n_queries: int = 1,
     interpret: bool | None = None,
 ):
@@ -797,23 +574,6 @@ multi_visit_reduce = _counted(
     "ResultSpec's on-device visit reducer in one launch (shared by the tree "
     "MDIS and the VA-file phase 2).",
 )(_multi_visit_reduce_jit)
-
-
-@jax.jit
-def _mask_counts_jit(mask: jax.Array) -> jax.Array:
-    note_trace("mask_counts")
-    return jnp.sum(mask != 0, axis=-1).astype(jnp.int32)
-
-
-mask_counts = _counted(
-    "mask_counts",
-    "On-device match counts over the object axis (count-only result mode). "
-    "Works for both (n_pad,) single-query and (Q, n_pad) batched masks; "
-    "padding objects are +inf sentinels that never match, so summing the "
-    "padded axis is exact. The sum is the distributed_count pattern "
-    "localized to one device: the result crossing to host is O(Q) ints, "
-    "never an id array.",
-)(_mask_counts_jit)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.range_scan import DEFAULT_TILE_N, LANES, SUBLANES  # noqa: F401
+from repro.kernels.multi_scan import DEFAULT_TILE_N, LANES
 
 # Reduction identities, keyed by agg op.
 AGG_FILL = {"sum": 0.0, "min": float("inf"), "max": float("-inf")}

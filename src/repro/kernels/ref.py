@@ -13,44 +13,6 @@ from repro import numerics
 from repro.kernels.va_filter import BITS_PER_DIM, CODE_MASK, DIMS_PER_WORD
 
 
-def range_scan_ref(data_cm: jax.Array, lower: jax.Array, upper: jax.Array) -> jax.Array:
-    """Oracle for the columnar range-scan kernel.
-
-    Args:
-      data_cm: (m, n) columnar data, any float dtype.
-      lower, upper: (m,) or (m, 1) query bounds (same dtype as data after cast).
-
-    Returns:
-      (n,) int8 mask — 1 where ``all_j lower_j <= x_ji <= upper_j``.
-    """
-    lo = lower.reshape(-1, 1).astype(data_cm.dtype)
-    up = upper.reshape(-1, 1).astype(data_cm.dtype)
-    ok = jnp.logical_and(data_cm >= lo, data_cm <= up)
-    return jnp.all(ok, axis=0).astype(jnp.int8)
-
-
-def range_scan_blocks_ref(
-    data_blocks: jax.Array, block_ids: jax.Array, lower: jax.Array, upper: jax.Array
-) -> jax.Array:
-    """Oracle for the block-visit range scan (two-phase tree/VA refinement).
-
-    Args:
-      data_blocks: (n_blocks, m, tn) columnar leaf blocks.
-      block_ids: (n_visit,) int32 ids of blocks to scan (may repeat; negative
-        ids are treated as padding and clamped to 0 — callers drop those rows).
-      lower, upper: (m,) bounds.
-
-    Returns:
-      (n_visit, tn) int8 per-visit masks.
-    """
-    ids = jnp.maximum(block_ids, 0)
-    blocks = data_blocks[ids]  # (v, m, tn)
-    lo = lower.reshape(1, -1, 1).astype(data_blocks.dtype)
-    up = upper.reshape(1, -1, 1).astype(data_blocks.dtype)
-    ok = jnp.logical_and(blocks >= lo, blocks <= up)
-    return jnp.all(ok, axis=1).astype(jnp.int8)
-
-
 def multi_scan_ref(data_cm: jax.Array, lower: jax.Array, upper: jax.Array) -> jax.Array:
     """Oracle for the fused multi-query full scan.
 
@@ -178,32 +140,6 @@ def va_filter_ref(codes: jax.Array, cell_lo: jax.Array, cell_hi: jax.Array) -> j
     hi = cell_hi.reshape(-1, 1).astype(codes.dtype)
     ok = jnp.logical_and(codes >= lo, codes <= hi)
     return jnp.all(ok, axis=0).astype(jnp.int8)
-
-
-def va_filter_packed_ref(
-    packed: jax.Array, cell_lo: jax.Array, cell_hi: jax.Array, m: int
-) -> jax.Array:
-    """Oracle for the packed VA filter: unpack 16 2-bit fields per int32 word.
-
-    Args:
-      packed: (w, n) int32, word w holds dims [16w, 16w+16) in 2-bit fields.
-      cell_lo, cell_hi: (m,) int32 query cell bounds.
-      m: true number of dimensions (w = ceil(m / 16)).
-    """
-    w, n = packed.shape
-    acc = jnp.ones((n,), dtype=jnp.bool_)
-    for wi in range(w):
-        word = packed[wi]
-        for k in range(DIMS_PER_WORD):
-            d = wi * DIMS_PER_WORD + k
-            if d >= m:
-                break
-            field = jnp.bitwise_and(jnp.right_shift(word, BITS_PER_DIM * k),
-                                    CODE_MASK)
-            acc = jnp.logical_and(
-                acc, jnp.logical_and(field >= cell_lo[d], field <= cell_hi[d])
-            )
-    return acc.astype(jnp.int8)
 
 
 def multi_va_filter_packed_ref(

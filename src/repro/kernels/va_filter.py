@@ -11,14 +11,11 @@ Packing: word ``w`` of object ``i`` holds dims ``[16w, 16w+16)`` — dim
 ``16w + k`` occupies bits ``[2k, 2k+2)``. The kernel unpacks with static
 shift/mask ops (VPU int32 lanes) and AND-reduces across dims in registers.
 
-Two entry points:
-
-  * ``va_filter_packed``       — single query: grid ``(n_tiles,)``.
-  * ``multi_va_filter_packed`` — a whole query batch in one launch: grid
-    ``(n_tiles,)`` with a (Q, tile_n) mask block per step, so each (w, tile_n)
-    packed-word tile streams from HBM once per *batch* — the same fusion
-    ``multi_scan`` applies to the exact scans, here applied to the
-    approximation phase.
+``multi_va_filter_packed`` filters a whole query batch in one launch (a
+single query is a batch of one): grid ``(n_tiles,)`` with a (Q, tile_n) mask
+block per step, so each (w, tile_n) packed-word tile streams from HBM once
+per *batch* — the same fusion ``multi_scan`` applies to the exact scans, here
+applied to the approximation phase.
 """
 from __future__ import annotations
 
@@ -29,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.range_scan import INT8_SUBLANES, LANES
+from repro.kernels.multi_scan import INT8_SUBLANES, LANES
 DEFAULT_TILE_N = 2048
 # The paper's static cell resolution (b_j = 2, §2.2.3). Everything downstream
 # — word packing density, the planner's candidate-fraction slack and
@@ -49,64 +46,6 @@ def pack_codes(codes: np.ndarray) -> np.ndarray:
         wi, k = divmod(d, DIMS_PER_WORD)
         out[wi] |= codes[d].astype(np.int32) << (BITS_PER_DIM * k)
     return out
-
-
-def _va_kernel(qlo_ref, qhi_ref, packed_ref, out_ref, *, m: int):
-    words = packed_ref[...]  # (w, tn) int32
-    w = words.shape[0]
-    acc = None
-    for wi in range(w):
-        word = words[wi]
-        for k in range(DIMS_PER_WORD):
-            d = wi * DIMS_PER_WORD + k
-            if d >= m:
-                break
-            field = jnp.bitwise_and(jnp.right_shift(word, BITS_PER_DIM * k),
-                                    CODE_MASK)
-            ok = jnp.logical_and(field >= qlo_ref[d, 0], field <= qhi_ref[d, 0])
-            acc = ok if acc is None else jnp.logical_and(acc, ok)
-    out_ref[...] = acc[None, :].astype(jnp.int8)
-
-
-def va_filter_packed(
-    packed: jax.Array,
-    cell_lo: jax.Array,
-    cell_hi: jax.Array,
-    m: int,
-    *,
-    tile_n: int = DEFAULT_TILE_N,
-    interpret: bool = False,
-) -> jax.Array:
-    """Candidate mask from packed approximations.
-
-    Args:
-      packed: (w, n_pad) int32 packed codes, n_pad % tile_n == 0.
-      cell_lo, cell_hi: (m_s, 1) int32 query cell bounds, m_s >= m (padded rows
-        carry [0, 3] match-all bounds and are skipped by the static loop bound).
-      m: true dimensionality.
-
-    Returns:
-      (n_pad,) int8 candidate mask.
-    """
-    w, n_pad = packed.shape
-    assert n_pad % tile_n == 0 and tile_n % LANES == 0
-    m_s = cell_lo.shape[0]
-    assert m_s >= m and cell_lo.shape == cell_hi.shape == (m_s, 1)
-
-    grid = (n_pad // tile_n,)
-    out = pl.pallas_call(
-        functools.partial(_va_kernel, m=m),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((m_s, 1), lambda i: (0, 0)),
-            pl.BlockSpec((m_s, 1), lambda i: (0, 0)),
-            pl.BlockSpec((w, tile_n), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int8),
-        interpret=interpret,
-    )(cell_lo.astype(jnp.int32), cell_hi.astype(jnp.int32), packed)
-    return out[0]
 
 
 def _multi_va_kernel(qlo_ref, qhi_ref, packed_ref, out_ref, *, m: int):

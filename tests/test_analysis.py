@@ -349,7 +349,7 @@ def test_multi_rule_suppression_comma_separated(tmp_path):
         from repro.kernels import ops
 
         def peek(x):
-            return np.asarray(ops.range_scan(x)), -3.0e38  # mdrqlint: disable=host-sync,sentinel
+            return np.asarray(ops.multi_scan_reduce(x)), -3.0e38  # mdrqlint: disable=host-sync,sentinel
         """
     path = tmp_path / "repro" / "core" / "multi.py"
     path.parent.mkdir(parents=True)

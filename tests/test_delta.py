@@ -94,9 +94,12 @@ class _Oracle:
 
 
 @pytest.fixture(scope="module")
-def eng_delta(uni5):
-    """All-paths engine over uni5 with a ~1% delta + mixed tombstones."""
-    eng = MDRQEngine(uni5, rowscan=True)
+def eng_delta(uni5, per_query_path):
+    """All-paths engine over uni5 with a ~1% delta + mixed tombstones; the
+    per-query path sees the frozen base only and merges the delta on the
+    host."""
+    eng = MDRQEngine(uni5)
+    eng.register_path(per_query_path("per_query", uni5.cols, cols=uni5.cols))
     rng = np.random.default_rng(77)
     extra = rng.random((200, uni5.m)).astype(np.float32)   # 1% of n=20k
     new_ids = eng.append(extra)
@@ -106,7 +109,8 @@ def eng_delta(uni5):
     return eng, _Oracle(uni5.cols, extra, dead)
 
 
-ALL_PATHS = ("scan", "scan_vertical", "kdtree", "rstar", "vafile", "rowscan")
+ALL_PATHS = ("scan", "scan_vertical", "kdtree", "rstar", "vafile",
+             "per_query")
 
 
 @pytest.mark.parametrize("method", ALL_PATHS)

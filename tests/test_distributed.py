@@ -10,39 +10,42 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.core import Dataset, DistributedScan, RangeQuery, match_ids_np
+from repro.core import Count, MDRQEngine, RangeQuery, match_ids_np
+from repro.core.distributed import make_data_mesh
 
 
 def test_distributed_scan_single_device(uni5):
-    dsc = DistributedScan(uni5)
+    """``engine.query`` on a meshed engine over this process's devices."""
+    eng = MDRQEngine(uni5, structures=("scan",), mesh=make_data_mesh())
     rng = np.random.default_rng(0)
     for _ in range(3):
         i, j = rng.integers(uni5.n), rng.integers(uni5.n)
         q = RangeQuery(np.minimum(uni5.cols[:, i], uni5.cols[:, j]),
                        np.maximum(uni5.cols[:, i], uni5.cols[:, j]))
         oracle = match_ids_np(uni5.cols, q)
-        np.testing.assert_array_equal(dsc.query(q), oracle)
-        assert dsc.count(q) == oracle.size
+        np.testing.assert_array_equal(eng.query(q, "scan"), oracle)
+        assert eng.query(q, "scan", spec=Count()) == oracle.size
 
 
 MULTI_DEVICE_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
-    from repro.core import Dataset, DistributedScan, RangeQuery, match_ids_np
+    from repro.core import (Count, Dataset, MDRQEngine, RangeQuery,
+                            match_ids_np)
     from repro.core.distributed import make_data_mesh
 
     assert len(jax.devices()) == 8
     rng = np.random.default_rng(7)
     ds = Dataset(rng.random((5, 40000), dtype=np.float32))
-    dsc = DistributedScan(ds, mesh=make_data_mesh(8))
+    eng = MDRQEngine(ds, structures=("scan",), mesh=make_data_mesh(8))
     for t in range(5):
         i, j = rng.integers(ds.n), rng.integers(ds.n)
         q = RangeQuery(np.minimum(ds.cols[:, i], ds.cols[:, j]),
                        np.maximum(ds.cols[:, i], ds.cols[:, j]))
         oracle = match_ids_np(ds.cols, q)
-        assert np.array_equal(dsc.query(q), oracle), t
-        assert dsc.count(q) == oracle.size
+        assert np.array_equal(eng.query(q, "scan"), oracle), t
+        assert eng.query(q, "scan", spec=Count()) == oracle.size
     print("MULTI_DEVICE_OK")
 """)
 

@@ -1,7 +1,7 @@
 """Cross-device batched scan: the distributed equivalence suite.
 
-``DistributedScan.query_batch`` / ``count_batch`` must return exactly what
-single-device ``ColumnarScan`` returns — ids and count modes — while issuing
+``DistributedScan.query_batch`` must return exactly what single-device
+``ColumnarScan`` returns — under Ids, Count and the reduced specs — while issuing
 one fused collective launch and one host sync per batch (counter-asserted;
 wall-clock on CPU cannot see launch budgets).
 
@@ -80,19 +80,19 @@ def test_distributed_batch_accepts_query_list(dist_pair, uni5):
         dsc.query_batch(queries, spec="top_k")
 
 
-def test_distributed_single_query_is_counted(dist_pair, uni5):
-    """The pre-existing single-query entry points are in the launch/host-sync
-    accounting too (the seed's raw ``np.asarray`` escaped it)."""
-    dsc, _ = dist_pair
+def test_distributed_single_query_is_counted(uni5):
+    """A single ``engine.query`` on a meshed engine is a batch of one: one
+    collective launch and one host sync, in the launch/host-sync accounting
+    (the seed's raw ``np.asarray`` escaped it)."""
+    eng = MDRQEngine(uni5, structures=("scan",), tile_n=512,
+                     mesh=make_data_mesh())
     q = RangeQuery.partial(uni5.m, {0: (0.1, 0.4)})
     ops.reset_counters()
-    ids = dsc.query(q)
-    assert ops.counter("distributed_mask") == 1
-    assert ops.counter("host_sync") == 1
+    ids = eng.query(q, "scan")
+    assert ops.counters() == {"distributed_multi_reduce": 1, "host_sync": 1}
     ops.reset_counters()
-    cnt = dsc.count(q)
-    assert ops.counter("distributed_count") == 1
-    assert ops.counter("host_sync") == 1
+    cnt = eng.query(q, "scan", spec=Count())
+    assert ops.counters() == {"distributed_multi_reduce": 1, "host_sync": 1}
     assert cnt == ids.size == match_ids_np(uni5.cols, q).size
 
 

@@ -7,8 +7,9 @@ import pytest
 pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (Dataset, MDRQEngine, RangeQuery, build_columnar_scan,
-                        build_kdtree, build_rstar, build_vafile, match_ids_np)
+from repro.core import (Dataset, MDRQEngine, QueryBatch, RangeQuery,
+                        build_columnar_scan, build_kdtree, build_rstar,
+                        build_vafile, match_ids_np)
 
 
 @st.composite
@@ -49,12 +50,14 @@ def test_all_paths_equal_oracle(dq):
     ds, q = dq
     oracle = match_ids_np(ds.cols, q)
     tile = 256  # small tiles so indexes have multiple blocks even at small n
+    one = QueryBatch.from_queries([q])
     scan = build_columnar_scan(ds, tile_n=tile)
-    np.testing.assert_array_equal(scan.query(q), oracle)
-    np.testing.assert_array_equal(scan.query_partial(q), oracle)
-    np.testing.assert_array_equal(build_kdtree(ds, tile_n=tile).query(q), oracle)
-    np.testing.assert_array_equal(build_rstar(ds, tile_n=tile).query(q), oracle)
-    np.testing.assert_array_equal(build_vafile(ds, tile_n=tile).query(q), oracle)
+    np.testing.assert_array_equal(scan.query_batch(one)[0], oracle)
+    np.testing.assert_array_equal(scan.query_batch(one, partial=True)[0],
+                                  oracle)
+    for build in (build_kdtree, build_rstar, build_vafile):
+        np.testing.assert_array_equal(
+            build(ds, tile_n=tile).query_batch(one)[0], oracle)
 
 
 @settings(max_examples=15, deadline=None)

@@ -1,5 +1,6 @@
 """Batched (multi-query) execution: fused kernels vs the numpy oracle, and
-``query_batch`` vs the single-query path for every method.
+``query_batch`` vs the oracle and vs ``engine.query`` (a batch of one) for
+every method.
 
 Kernels run in interpret mode on CPU (the oracle-checked reference path), so
 sizes stay small; the XLA refs are checked for exact equality with the
@@ -8,8 +9,8 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import (Count, Dataset, MDRQEngine, QueryBatch, RangeQuery,
-                        match_ids_np, match_mask_np)
+from repro.core import (Count, Dataset, MDRQEngine, Mask, QueryBatch,
+                        RangeQuery, match_ids_np, match_mask_np)
 from repro.core.planner import CostModel, Planner, Histograms
 from repro.core.vafile import build_vafile
 from repro import obs
@@ -122,7 +123,7 @@ _ROW_CASES = [
     *_ROW_CASES])
 def test_multi_scan_tiles_vs_oracle(m, n_q, kind, q_pad, n):
     cols, batch, data, lo, up = _case_batch(kind, m, n_q, q_pad, n)
-    out = np.asarray(ops.multi_range_scan(data, lo, up))
+    out = np.asarray(ops.multi_scan_reduce(data, lo, up, spec=Mask()))
     np.testing.assert_array_equal(out, np.asarray(ref.multi_scan_ref(data, lo, up)))
     _check_vs_oracle(out, cols, batch)
 
@@ -133,7 +134,7 @@ def test_multi_scan_tiles_vs_oracle(m, n_q, kind, q_pad, n):
     *_ROW_CASES])
 def test_multi_scan_vertical_vs_oracle(m, n_q, kind, q_pad, n):
     cols, batch, data, lo, up = _case_batch(kind, m, n_q, q_pad, n)
-    out = np.asarray(ops.multi_range_scan_vertical(data, lo, up))
+    out = np.asarray(ops.multi_scan_vertical_reduce(data, lo, up, spec=Mask()))
     np.testing.assert_array_equal(out, np.asarray(ref.multi_scan_ref(data, lo, up)))
     _check_vs_oracle(out, cols, batch)
 
@@ -198,6 +199,20 @@ def test_scan_row_counters_complete_match_compares_every_row():
     assert _row_counts() == {"compared": {"full": 2 * 24.0}, "skipped": {}}
 
 
+def test_single_query_moves_the_scan_row_counters():
+    """A single ``engine.query`` on "scan" is a batch of one through the
+    counted scan launch: one chunk of 24 rows, of which a 2-dim GMRQB
+    template 1 query compares its 2 and skips the other 22."""
+    from repro.data import gmrqb
+    ds = gmrqb.build(2000, seed=1)
+    q = gmrqb.template(1, np.random.default_rng(0))
+    eng = MDRQEngine(ds, structures=("scan",))
+    assert eng.query(q, "scan", spec=Count()) == int(
+        match_mask_np(ds.cols, q).sum())
+    assert _row_counts() == {"compared": {"full": 2.0},
+                             "skipped": {"full": 22.0}}
+
+
 def test_scan_row_counters_half_rule():
     """At m=100 (m_pad 104) a chunk whose loop would run over more than half
     of the 96 rows past its head compares every row: chunk 0 bounds about
@@ -231,8 +246,10 @@ def test_multi_scan_visit_vs_oracle():
     bids = np.concatenate([bids[order], [-1, -1]]).astype(np.int32)
     lo, up = batch.bounds_columnar(padded.shape[0])
     lo, up = jnp.asarray(lo), jnp.asarray(up)
-    out = np.asarray(ops.multi_range_scan_visit(
-        data, jnp.asarray(qids), jnp.asarray(bids), lo, up, tile_n=tile_n))
+    out = np.asarray(ops.multi_visit_reduce(
+        data, jnp.asarray(qids), jnp.asarray(bids),
+        jnp.asarray((bids >= 0).astype(np.int32)), jnp.zeros((1, 1), jnp.int32),
+        lo, up, spec=Mask(), tile_n=tile_n, n_queries=4))
     blocks = data.reshape(data.shape[0], n_blocks, tile_n).transpose(1, 0, 2)
     np.testing.assert_array_equal(out, np.asarray(ref.multi_scan_blocks_ref(
         blocks, jnp.asarray(qids), jnp.asarray(bids), lo, up)))
@@ -272,8 +289,9 @@ def test_multi_scan_visit_chunked_vs_oracle(monkeypatch):
 
 @pytest.mark.parametrize("m,n_q", [(5, 3), (19, 6), (33, 4)])
 def test_multi_va_filter_vs_single_and_oracle(m, n_q):
-    """Batched phase 1: one-launch masks == per-query va_filter == ref,
-    including point (cell_lo == cell_hi) and match-all queries."""
+    """Batched phase 1: one-launch masks == ref == the numpy oracle over the
+    unpacked codes, including point (cell_lo == cell_hi) and match-all
+    queries."""
     rng = np.random.default_rng(m * 7 + n_q)
     n, tile_n = 4096, 1024
     codes = rng.integers(0, 4, size=(m, n)).astype(np.uint8)
@@ -290,10 +308,9 @@ def test_multi_va_filter_vs_single_and_oracle(m, n_q):
     np.testing.assert_array_equal(out, np.asarray(ref.multi_va_filter_packed_ref(
         packed, jnp.asarray(qlo), jnp.asarray(qhi), m)))
     for k in range(n_q):
-        single = np.asarray(ops.va_filter(
-            packed, jnp.asarray(qlo[:, k: k + 1]), jnp.asarray(qhi[:, k: k + 1]),
-            m, tile_n=tile_n))
-        np.testing.assert_array_equal(out[k], single)
+        oracle = ((codes >= qlo[:m, k, None]) & (codes <= qhi[:m, k, None])
+                  ).all(axis=0)
+        np.testing.assert_array_equal(out[k].astype(bool), oracle)
     # on-device block reduction == host-side reduction of the full masks
     blocks = np.asarray(ops.multi_va_filter(packed, jnp.asarray(qlo),
                                             jnp.asarray(qhi), m,
@@ -315,20 +332,18 @@ def _queries_with_points(cols, rng, n_q):
 def test_vafile_batch_one_launch_one_sync(uni5):
     """Tentpole budget: the batched VA path issues exactly one phase-1 launch
     and one phase-1 host sync per batch (plus one fused visit-reduce launch +
-    payload readback), never the per-query va_filter — results bit-identical
-    to the single-query path."""
+    payload readback) and nothing else — results equal the oracle."""
     vf = build_vafile(uni5, tile_n=512)
     rng = np.random.default_rng(17)
     queries = _queries_with_points(uni5.cols, rng, 6)
-    singles = [vf.query(q) for q in queries]
+    singles = [match_ids_np(uni5.cols, q) for q in queries]
     batch = QueryBatch.from_queries(queries)
 
     ops.reset_counters()
     batched = vf.query_batch(batch)
-    assert ops.counter("multi_va_filter") == 1   # one phase-1 launch
-    assert ops.counter("va_filter") == 0         # never per-query
-    assert ops.counter("multi_visit_reduce") == 1
-    assert ops.counter("host_sync") == 2         # survivor bits + visit masks
+    assert ops.counters() == {"multi_va_filter": 1,     # one phase-1 launch
+                              "multi_visit_reduce": 1,
+                              "host_sync": 2}  # survivor bits + visit masks
     for s, b in zip(singles, batched):
         np.testing.assert_array_equal(s, b)
 
@@ -342,7 +357,8 @@ def test_vafile_batch_one_launch_one_sync(uni5):
 
 def test_vafile_batch_gmrqb_templates():
     """GMRQB-style batches (templates with point predicates) through the
-    batched VA path: ids and counts match the single-query path / oracle."""
+    batched VA path: ids and counts match the oracle, in the batch and one
+    query at a time."""
     from repro.data import gmrqb
 
     ds = gmrqb.build(8192, seed=3)
@@ -354,37 +370,44 @@ def test_vafile_batch_gmrqb_templates():
     counts = vf.query_batch(batch, spec=Count())
     for k, q in enumerate(queries):
         oracle = match_ids_np(ds.cols, q)
+        one = QueryBatch.from_queries([q])
         np.testing.assert_array_equal(batched[k], oracle)
-        np.testing.assert_array_equal(vf.query(q), oracle)
+        np.testing.assert_array_equal(vf.query_batch(one)[0], oracle)
         assert counts[k] == oracle.size
-        assert vf.count(q) == oracle.size
+        assert vf.query_batch(one, spec=Count()) == [oracle.size]
 
 
-# -- (b) query_batch == per-query query for all methods ----------------------
+# -- (b) a query's answer does not depend on its batch, for all methods ------
 
 @pytest.mark.parametrize("method", ["scan", "scan_vertical", "kdtree",
                                     "rstar", "vafile", "auto"])
 def test_query_batch_equals_single(method, uni5):
+    """The oracle's answer, whether a query rides a batch, the same batch
+    reversed, or ``engine.query`` alone (a batch of one)."""
     eng = MDRQEngine(uni5, tile_n=512)
     rng = np.random.default_rng(11)
     queries = _mixed_queries(uni5.m, uni5.cols, rng, 6)
     batched = eng.query_batch(queries, method=method)
     assert eng.last_batch_stats.n_queries == 6
     assert sum(eng.last_batch_stats.method_counts.values()) == 6
+    reversed_ = eng.query_batch(queries[::-1], method=method)[::-1]
     for k, q in enumerate(queries):
-        np.testing.assert_array_equal(batched[k], eng.query(q, method))
-        if method != "auto":
-            np.testing.assert_array_equal(batched[k], match_ids_np(uni5.cols, q))
+        oracle = match_ids_np(uni5.cols, q)
+        np.testing.assert_array_equal(batched[k], oracle)
+        np.testing.assert_array_equal(reversed_[k], oracle)
+        np.testing.assert_array_equal(eng.query(q, method), oracle)
 
 
 # -- count-only result mode --------------------------------------------------
 
 @pytest.fixture(scope="module")
-def eng_all(uni5):
-    return MDRQEngine(uni5, tile_n=512, rowscan=True)
+def eng_all(uni5, per_query_path):
+    eng = MDRQEngine(uni5, tile_n=512)
+    eng.register_path(per_query_path("per_query", uni5.cols, cols=uni5.cols))
+    return eng
 
 
-@pytest.mark.parametrize("method", ["scan", "scan_vertical", "rowscan",
+@pytest.mark.parametrize("method", ["scan", "scan_vertical", "per_query",
                                     "kdtree", "rstar", "vafile", "auto"])
 def test_count_mode_equals_ids_sizes(method, eng_all, uni5):
     rng = np.random.default_rng(29)

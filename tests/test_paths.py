@@ -80,14 +80,16 @@ def test_toy_path_planned_and_executed_without_engine_changes(uni5):
 
     rng = np.random.default_rng(5)
     queries = _mixed_queries(uni5.cols, rng, 6)
-    # single-query auto: the planner must pick the near-free toy path
+    # single-query auto: the planner must pick the near-free toy path, and
+    # the query rides it as a batch of one
     res = eng.query(queries[0], method="auto")
     assert eng.last_stats.method == "toy_numpy"
+    assert toy.batch_calls == 1
     np.testing.assert_array_equal(res, match_ids_np(uni5.cols, queries[0]))
     # batched auto: one bucket, one toy batch call, oracle-equal results
     batched = eng.query_batch(queries, method="auto")
     assert eng.last_batch_stats.method_counts == {"toy_numpy": 6}
-    assert toy.batch_calls == 1
+    assert toy.batch_calls == 2
     for q, ids in zip(queries, batched):
         np.testing.assert_array_equal(ids, match_ids_np(uni5.cols, q))
     # explicit dispatch + count mode through the same registry entry
@@ -116,23 +118,51 @@ def test_engine_has_no_dispatch_chains():
     src = inspect.getsource(engine_mod)
     for needle in ("_dispatch_batch", "_dispatch_count",
                    'method == "scan"', 'method == "kdtree"',
-                   'method == "vafile"', 'method == "rowscan"'):
+                   'method == "vafile"', 'method == "scan_vertical"'):
         assert needle not in src, needle
 
 
-def test_rowscan_rides_the_per_query_fallback(uni5):
-    """RowScan has no fused batch kernel: the generic ``PerQueryPath``
-    adapter carries it — batch results equal the oracle, and it never enters
-    "auto" planning (plannable=False)."""
-    eng = MDRQEngine(uni5, structures=("scan",), tile_n=512, rowscan=True)
-    assert isinstance(eng.paths["rowscan"], PerQueryPath)
-    assert "rowscan" not in eng.planner.available
+def test_rowscan_rides_the_per_query_fallback(uni5, per_query_path):
+    """A registered structure with only per-query functions rides the
+    generic ``PerQueryPath`` adapter — batch results equal the oracle, and
+    it never enters "auto" planning (plannable=False)."""
+    eng = MDRQEngine(uni5, structures=("scan",), tile_n=512)
+    eng.register_path(per_query_path("per_query", uni5.cols))
+    assert isinstance(eng.paths["per_query"], PerQueryPath)
+    assert "per_query" not in eng.planner.available
     rng = np.random.default_rng(7)
     queries = _mixed_queries(uni5.cols, rng, 4)
-    for q, ids in zip(queries, eng.query_batch(queries, method="rowscan")):
+    for q, ids in zip(queries, eng.query_batch(queries, method="per_query")):
         np.testing.assert_array_equal(ids, match_ids_np(uni5.cols, q))
-    counts = eng.query_batch(queries, method="rowscan", mode="count")
+    counts = eng.query_batch(queries, method="per_query", mode="count")
     assert counts == [match_ids_np(uni5.cols, q).size for q in queries]
+
+
+@pytest.mark.parametrize("method", ["scan", "scan_vertical", "kdtree",
+                                    "rstar", "vafile", "scan-meshed"])
+def test_single_query_launches_what_a_batch_of_one_does(uni5, method):
+    """``engine.query`` is a batch of one: it issues exactly the launches and
+    host syncs of ``query_batch([q])`` on the same path, and answers the
+    same, under Ids and Count."""
+    from repro.core import Count
+    from repro.core.distributed import make_data_mesh
+    if method == "scan-meshed":
+        eng = MDRQEngine(uni5, structures=("scan",), tile_n=512,
+                         mesh=make_data_mesh())
+        method = "scan"
+    else:
+        eng = MDRQEngine(uni5, tile_n=512)
+    rng = np.random.default_rng(19)
+    for q in _mixed_queries(uni5.cols, rng, 2):
+        for spec in (None, Count()):
+            ops.reset_counters()
+            batched = eng.query_batch([q], method=method, spec=spec)[0]
+            want = ops.counters()
+            ops.reset_counters()
+            single = eng.query(q, method=method, spec=spec)
+            assert ops.counters() == want
+            assert want["host_sync"] >= 1 and len(want) >= 2
+            np.testing.assert_array_equal(single, batched)
 
 
 def test_unknown_method_and_unbuilt_structure_raise(uni5):

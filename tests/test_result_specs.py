@@ -81,8 +81,11 @@ def _check_spec(spec, res, ids, cols):
 
 
 @pytest.fixture(scope="module")
-def eng_all(uni5):
-    return MDRQEngine(uni5, tile_n=512, rowscan=True)
+def eng_all(uni5, per_query_path):
+    # the per-query path's reduced specs ride the host from_ids rung
+    eng = MDRQEngine(uni5, tile_n=512)
+    eng.register_path(per_query_path("per_query", uni5.cols, cols=uni5.cols))
+    return eng
 
 
 @pytest.mark.parametrize("spec", SPECS, ids=lambda s: repr(s))
@@ -264,7 +267,7 @@ def test_unknown_modes_keep_canonical_error(eng_all, uni5):
         Agg("median", 0)
 
 
-def test_spec_registry_is_the_extension_point():
+def test_spec_registry_is_the_extension_point(per_query_path):
     """New result shapes register like access paths: a subclass lands in the
     kind registry and rides the PerQueryPath host-fallback rung with only
     ``from_ids`` defined — no engine, path, or kernel edits."""
@@ -295,9 +298,10 @@ def test_spec_registry_is_the_extension_point():
         assert RESULT_SPEC_KINDS["test_median"] is Median
         rng = np.random.default_rng(3)
         ds = Dataset(rng.random((4, 2048), dtype=np.float32))
-        eng = MDRQEngine(ds, structures=("scan",), tile_n=512, rowscan=True)
+        eng = MDRQEngine(ds, structures=("scan",), tile_n=512)
+        eng.register_path(per_query_path("per_query", ds.cols, cols=ds.cols))
         q = RangeQuery.partial(4, {1: (0.2, 0.7)})
-        got = eng.query(q, method="rowscan", spec=Median(dim=2))
+        got = eng.query(q, method="per_query", spec=Median(dim=2))
         ids = match_ids_np(ds.cols, q)
         assert np.isclose(got, np.median(ds.cols[2, ids]))
     finally:

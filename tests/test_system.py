@@ -31,12 +31,21 @@ def test_paper_headline_selectivity_ordering(uni5):
 
 
 def test_vafile_prunes_exact_compares(uni19):
+    import jax.numpy as jnp
+    from repro.core import QueryBatch
+    from repro.kernels import ops
     eng = MDRQEngine(uni19, tile_n=512)
     rng = np.random.default_rng(1)
     q = synthetic.selectivity_targeted_query(uni19, 1e-4, rng)
     ids = eng.query(q, "vafile")
     np.testing.assert_array_equal(ids, match_ids_np(uni19.cols, q))
-    assert eng.vafile.last_candidate_frac < 0.05  # 19-dim prefilter bites
+    # the phase-1 candidates of that query, before the per-block reduction
+    va = eng.vafile
+    lo, hi = va.query_cells_batch(QueryBatch.from_queries([q]))
+    cand = np.asarray(ops.multi_va_filter(va.packed_dev, jnp.asarray(lo),
+                                          jnp.asarray(hi), m=va.m,
+                                          tile_n=va.tile_n))[0, :va.n]
+    assert cand.mean() < 0.05  # 19-dim prefilter bites
 
 
 def test_cross_dataset_workloads():

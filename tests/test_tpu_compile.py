@@ -75,11 +75,6 @@ def _scan_args(q_n, sh):
 
 
 @pytest.mark.parametrize("q_n", BUCKETS)
-def test_multi_range_scan_compiles(one_chip, q_n):
-    _compile(ops.multi_range_scan, *_scan_args(q_n, one_chip))
-
-
-@pytest.mark.parametrize("q_n", BUCKETS)
 @pytest.mark.parametrize("spec", [Ids(), Mask(), Count(), TopK(k=10, dim=2),
                                   Agg("sum", dim=3), Agg("min", dim=3)],
                          ids=str)
@@ -128,16 +123,18 @@ def test_multi_visit_reduce_compiles(one_chip, q_n):
 
 
 def test_range_scan_vertical_compiles(one_chip):
-    """The single-query partial-match scan behind ``engine.query``."""
+    """The partial-match scan a single ``engine.query`` runs: Q=1."""
     data, lo, up = _scan_args(1, one_chip)
-    _compile(ops.range_scan_vertical, data, lo, up)
+    _compile(ops.multi_scan_vertical_reduce, data, lo, up, spec=Ids())
 
 
 def test_range_scan_visit_compiles(one_chip):
-    """The single-query two-phase scan behind ``engine.query``, at the
+    """The two-phase visit launch a single ``engine.query`` runs, at the
     largest pow2 visit bucket one query reaches over 10M rows."""
     data, lo, up = _scan_args(1, one_chip)
-    _compile(ops.range_scan_visit, data, _sds((16384,), I32, one_chip), lo, up)
+    visits = [_sds((16384,), I32, one_chip) for _ in range(3)]
+    _compile(ops.multi_visit_reduce, data, *visits,
+             _sds((1, 1), I32, one_chip), lo, up, spec=Ids(), n_queries=1)
 
 
 @pytest.mark.parametrize("q_n", BUCKETS)
