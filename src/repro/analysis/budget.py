@@ -4,7 +4,9 @@ The repo's core performance claim is a *budget*: every warm serving path
 costs a fixed number of counted kernel launches and host syncs per batch
 window (e.g. scan = one fused ``multi_scan_reduce`` + one ``device_get``;
 the two-phase tree paths = prune + visit launches with the mid-stage
-survivor sync + the payload sync). Runtime tests assert these numbers
+survivor sync + the payload sync, or with the payload sync alone under a
+spec whose visit list stays on the device, certified apart as
+``visits_on_device``). Runtime tests assert these numbers
 against the ``mdrq_launches_total`` counters — *after* the code runs. This
 module derives the same numbers **statically**, by abstract interpretation
 over the project call graph, and writes them to a checked-in ``BUDGET.json``
@@ -590,6 +592,14 @@ def _delta_view() -> VInstance:
     return VInstance("__delta__", {"is_empty": FALSE})
 
 
+def _route_spec() -> VInstance:
+    # A ResultSpec whose visits stay on the device: ``visits_on_device`` is
+    # the one attribute the tree paths branch on to pick their route. The
+    # plain ``spec`` of the bound is OPAQUE, so both routes are walked there
+    # and the costlier one is certified.
+    return VInstance("__spec__", {"visits_on_device": TRUE})
+
+
 def _walk_method(graph: CallGraph, cls_qual: str, method: str,
                  receivers: dict, kwargs: dict) -> dict:
     fi = graph.lookup_method(cls_qual, method)
@@ -609,17 +619,24 @@ def certify(graph: CallGraph) -> dict:
     for name, (cls_qual, recv_spec) in sorted(PATH_ENTRIES.items()):
         entry: dict = {}
         for ctx_name, delta in (("frozen", NONE), ("delta", _delta_view())):
-            recv = _receivers(recv_spec)
-            total = _walk_method(graph, cls_qual, "query_batch", recv,
-                                 {"spec": OPAQUE, "delta": delta})
-            stage = _walk_method(graph, cls_qual, "launch_batch", recv,
-                                 {"spec": OPAQUE, "delta": delta})
-            entry[ctx_name] = {
-                "total": total,
-                "device_stage": stage,
-                "finalize_host_syncs":
-                    total["host_syncs"] - stage["host_syncs"],
-            }
+            def budget(spec):
+                recv = _receivers(recv_spec)
+                total = _walk_method(graph, cls_qual, "query_batch", recv,
+                                     {"spec": spec, "delta": delta})
+                stage = _walk_method(graph, cls_qual, "launch_batch", recv,
+                                     {"spec": spec, "delta": delta})
+                return {
+                    "total": total,
+                    "device_stage": stage,
+                    "finalize_host_syncs":
+                        total["host_syncs"] - stage["host_syncs"],
+                }
+            # The bound over every spec; then, where a path routes the
+            # specs whose visits stay on the device apart, their budget.
+            entry[ctx_name] = budget(OPAQUE)
+            on_device = budget(_route_spec())
+            if on_device != entry[ctx_name]:
+                entry[ctx_name]["visits_on_device"] = on_device
         paths[name] = entry
 
     engine = {

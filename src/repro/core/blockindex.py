@@ -10,7 +10,9 @@ R*-tree — reduce at query time to the same TPU-native two-phase plan
   phase 2 (refine): the ``multi_scan_visit`` Pallas kernel scans *only* the
                     surviving (query, leaf block) pairs (grid size =
                     #survivors, so pruned blocks cost nothing — the TPU
-                    analogue of subtree pruning).
+                    analogue of subtree pruning). Under Count and Agg the
+                    survivors are compacted into the visit list on the
+                    device; the other specs build it on the host.
 
 A single query is a batch of one.
 
@@ -95,8 +97,9 @@ def _prune_hierarchy_batch_jit(
 prune_hierarchy_batch = ops.counted(
     "prune_hierarchy_batch",
     "Batched phase-1 MBR hierarchy prune: every query of a batch in one "
-    "vectorized launch (the tree paths' real budget is this launch + its "
-    "survivor-mask sync on top of the fused visit launch).",
+    "vectorized launch -> the (Q, n_leaves) survivor mask, which the visit "
+    "launch takes on the device or the host syncs (see "
+    "``BlockedIndex.launch_batch``).",
 )(_prune_hierarchy_batch_jit)
 
 
@@ -140,6 +143,29 @@ def _build_visit_index(query_ids: np.ndarray, n_queries: int,
     return index
 
 
+def _delta_launch(delta, data_dev: jax.Array, tile_n: int,
+                  perm: np.ndarray | None) -> tuple:
+    """What a visit launch needs of a delta -> ``(dcm, tomb, merge)``.
+
+    ``dcm`` is the delta rows' device block (None when there are none),
+    ``tomb`` the base tombstones in storage order (None when there are
+    none), and ``merge(spec, base_results, delta_host, q_n)`` folds the
+    delta's payload into the base results, with the delta's host context
+    captured here, at launch. All three are None for an empty delta.
+    """
+    dview = delta if delta is not None and not delta.is_empty else None
+    if dview is None:
+        return None, None, None
+    key = None if perm is None else ("perm", id(perm), int(data_dev.shape[1]))
+    tomb = dview.base_tomb_dev(data_dev.shape[1], perm=perm, key=key)
+    d_n, host_ctx = dview.d, dview.host_ctx()
+
+    def merge(spec, base, delta_host, q_n):
+        return spec.merge_delta(base, spec.finalize(delta_host, q_n, d_n),
+                                host_ctx)
+    return dview.device_cm(tile_n), tomb, merge
+
+
 def launch_visits_batch(
     data_dev: jax.Array,
     query_ids: np.ndarray,
@@ -152,8 +178,8 @@ def launch_visits_batch(
     perm: np.ndarray | None = None,
     delta=None,
 ) -> tuple:
-    """Phase 2 of every two-phase path, under any ResultSpec: one launch,
-    no host sync.
+    """Phase 2 of the host route, under any ResultSpec: one launch over a
+    host-built visit list, no host sync.
 
     Returns ``(payload, finalize)``; the caller owns the single counted
     ``ops.device_get(payload)`` and hands its host value to ``finalize`` —
@@ -162,8 +188,7 @@ def launch_visits_batch(
     host value ignored) when nothing pruned through on a frozen dataset —
     that corner has no device work at all.
     """
-    dview = delta if delta is not None and not delta.is_empty else None
-    dcm = dview.device_cm(tile_n) if dview is not None else None
+    dcm, tomb, merge = _delta_launch(delta, data_dev, tile_n, perm)
     if query_ids.size == 0:
         # Nothing pruned through — but a non-empty delta still has to scan.
         # This corner pays one delta-only launch (vs zero on a frozen
@@ -175,17 +200,7 @@ def launch_visits_batch(
                                              q_pad=_next_pow2(len(batch)))
         payload = ops.multi_scan_reduce(dcm, lo_d, up_d, spec=spec,
                                         tile_n=tile_n)
-        d_n, host_ctx = dview.d, dview.host_ctx()
-
-        def finalize_empty(host_payload):
-            dres = spec.finalize(host_payload, n_queries, d_n)
-            return spec.merge_delta(base, dres, host_ctx)
-        return payload, finalize_empty
-    tomb = None
-    if dview is not None:
-        key = None if perm is None else ("perm", id(perm),
-                                         int(data_dev.shape[1]))
-        tomb = dview.base_tomb_dev(data_dev.shape[1], perm=perm, key=key)
+        return payload, lambda host: merge(spec, base, host, n_queries)
     qids_p, bids_p = _pad_visit_list(query_ids, block_ids)
     q_bucket = _next_pow2(max(n_queries, 1))  # pow2 bounds jit retraces
     # The per-query visit-index table only feeds TopK's gather; every other
@@ -200,8 +215,7 @@ def launch_visits_batch(
                                          data_dev.dtype,
                                          q_pad=_next_pow2(len(batch)))
     payload = ops.multi_visit_reduce(
-        data_dev, jnp.asarray(qids_p), jnp.asarray(bids_p),
-        jnp.asarray((bids_p >= 0).astype(np.int32)),
+        data_dev, (jnp.asarray(qids_p), jnp.asarray(bids_p)),
         jnp.asarray(visit_index), lo_d, up_d, dcm, tomb,
         spec=spec, tile_n=tile_n, n_queries=q_bucket,
     )
@@ -212,13 +226,11 @@ def launch_visits_batch(
         def finalize(host_payload):
             return spec.finalize_visits(host_payload, vctx)
         return payload, finalize
-    d_n, host_ctx = dview.d, dview.host_ctx()
 
     def finalize_delta(host_payload):
         base_host, delta_host = host_payload
-        base = spec.finalize_visits(base_host, vctx)
-        dres = spec.finalize(delta_host, n_queries, d_n)
-        return spec.merge_delta(base, dres, host_ctx)
+        return merge(spec, spec.finalize_visits(base_host, vctx), delta_host,
+                     n_queries)
     return payload, finalize_delta
 
 
@@ -296,35 +308,73 @@ class BlockedIndex:
                      delta=None) -> tuple:
         """Device half of the batched two-phase query -> (payload, finalize).
 
-        The prune phase is inherently a mid-stage sync (the surviving
-        (query, block) pairs decide the visit launch's shapes), so it runs
-        here — in the device stage — along with the fused visit *launch*;
-        what the returned ``finalize`` defers to the caller's thread is the
-        payload sync + the spec's host finalizers, the host-heavy tail.
-        ``payload`` is None (host value ignored) when nothing pruned through
-        on a frozen dataset.
+        Two routes, chosen by the spec alone (``visits_on_device``):
+
+        * device route (Count, Agg): the prune's (Q, n_leaves) survivor mask
+          goes straight from the prune launch to the visit launch, which
+          compacts it into the visit list on the device and visits as many
+          pairs as survived (``ops.multi_visit_reduce``). Nothing syncs
+          here; the payload carries the pair count, and the returned
+          ``finalize`` is the batch's one sync.
+        * host route (Ids, Mask, TopK, whose finalizers read the visit list
+          on the host): the survivor mask syncs here, mid-stage, and the
+          host builds the pow2-padded visit list the visit launch takes;
+          ``finalize`` then syncs the payload.
+
+        Padding queries of the pow2 query bucket carry NaN bounds, which
+        overlap no block. On the host route ``payload`` is None (host value
+        ignored) when nothing pruned through on a frozen dataset.
         """
         spec = T.validate_mode(spec).validate(self.m)
         q_n = len(batch)
         q_pad = _next_pow2(q_n)  # pow2 query bucket bounds jit retraces
         qlo, qhi = batch.bounds_columnar(self.m, q_pad)
-        # (Q, n_leaves); padding queries are match-all -> dropped
-        leaf_mask = ops.device_get(prune_hierarchy_batch(
+        qlo[:, q_n:] = qhi[:, q_n:] = np.nan
+        leaf_mask = prune_hierarchy_batch(
             self.levels_lo, self.levels_hi,
             jnp.asarray(qlo), jnp.asarray(qhi), fanout=self.fanout,
-        ), stage="launch", path=self.name)[:q_n]
-        qids, bids = np.nonzero(leaf_mask)
+        )  # (q_pad, n_leaves)
+        if spec.visits_on_device:
+            return self._launch_survivors(leaf_mask, batch, spec, delta)
+        qids, bids = np.nonzero(ops.device_get(leaf_mask, stage="launch",
+                                               path=self.name))
         self.last_visited_blocks = int(qids.size)
+        ops.count_visit_pairs(self.name, "host", qids.size)
         return launch_visits_batch(
             self.data_dev, qids.astype(np.int32), bids.astype(np.int32),
             batch, self.tile_n, q_n, spec, self.n, perm=self.perm,
             delta=delta,
         )
 
+    def _launch_survivors(self, leaf_mask: jax.Array, batch: T.QueryBatch,
+                          spec: T.ResultSpec, delta) -> tuple:
+        """The device route of ``launch_batch``: one visit launch over the
+        prune mask's survivors, no sync."""
+        q_n = len(batch)
+        dcm, tomb, merge = _delta_launch(delta, self.data_dev, self.tile_n,
+                                         self.perm)
+        lo_d, up_d = ops.batch_bounds_device(batch, self.data_dev.shape[0],
+                                             self.data_dev.dtype,
+                                             q_pad=leaf_mask.shape[0])
+        payload = ops.multi_visit_reduce(
+            self.data_dev, leaf_mask, None, lo_d, up_d, dcm, tomb,
+            spec=spec, tile_n=self.tile_n, n_queries=leaf_mask.shape[0],
+        )
+
+        def finalize(host_payload):
+            (visits, n_pairs), delta_host = (
+                host_payload if dcm is not None else (host_payload, None))
+            self.last_visited_blocks = int(n_pairs)
+            ops.count_visit_pairs(self.name, "device", int(n_pairs))
+            res = spec.finalize(visits, q_n, self.n)
+            return res if dcm is None else merge(spec, res, delta_host, q_n)
+        return payload, finalize
+
     def query_batch(self, batch: T.QueryBatch, spec: T.ResultSpec = T.IDS,
                     delta=None) -> list:
-        """Batched two-phase query: one counted prune launch (+ its
-        survivor-mask sync) + one fused visit launch (+ its payload sync).
+        """Batched two-phase query: one counted prune launch + one fused
+        visit launch + the payload sync (and, on the host route, the
+        survivor-mask sync between the launches; see ``launch_batch``).
 
         Phase 1 prunes all Q queries' hierarchies in a single vectorized
         call; phase 2 flattens the surviving (query, block) pairs into one
@@ -332,8 +382,9 @@ class BlockedIndex:
         on-device reducer, so per-query dispatch and host-sync taxes are
         paid once per batch and reduced shapes (count, top-k, aggregate)
         ship only their payload. Both phases are visible to the launch /
-        host-sync counters (mdrqlint's host-sync rule keeps it that way). Positions map through ``perm`` in the
-        spec's finalizer (counts and aggregates are permutation-invariant).
+        host-sync counters (mdrqlint's host-sync rule keeps it that way).
+        Positions map through ``perm`` in the spec's finalizer (counts and
+        aggregates are permutation-invariant).
         """
         payload, fin = self.launch_batch(batch, spec=spec, delta=delta)
         return fin(ops.device_get(payload) if payload is not None else None)

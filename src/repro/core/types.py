@@ -89,6 +89,13 @@ class ResultSpec:
     # table (TopK's gather); everyone else gets a (1, 1) placeholder so the
     # two-phase paths skip the build + transfer.
     needs_visit_index: ClassVar[bool] = False
+    # True when the visit reducer drops every visit row whose ``valid`` is 0
+    # and the payload finalizes through ``finalize`` alone, with no host
+    # visit list: the tree paths then build the visit list on the device,
+    # from the prune mask, and sync once per batch (``combine_visits`` merges
+    # the payloads of the chunks they visit in). Ids/Mask/TopK read the host
+    # list and keep the host route.
+    visits_on_device: ClassVar[bool] = False
 
     @property
     def value_dim(self) -> Optional[int]:
@@ -115,6 +122,11 @@ class ResultSpec:
     def distributed_reduce(self, mask_local, data_local, axis: str):
         """Per-shard masks -> payload, inside shard_map (collectives OK)."""
         return mask_local
+
+    def combine_visits(self, a, b):
+        """The payload of two disjoint chunks of visits from theirs (device
+        route only; see ``visits_on_device``)."""
+        raise NotImplementedError
 
     # -- host finalizers ----------------------------------------------------
     def finalize(self, payload, q_n: int, n: int) -> list:
@@ -262,6 +274,7 @@ class Count(ResultSpec):
     """Per-query match counts reduced on device (COUNT(*) fast path)."""
 
     kind: ClassVar[str] = "count"
+    visits_on_device: ClassVar[bool] = True
 
     def device_reduce(self, masks, data_cm, *, tile_n, interpret):
         return jnp.sum(masks != 0, axis=-1).astype(jnp.int32)
@@ -270,6 +283,9 @@ class Count(ResultSpec):
                       *, tile_n, n_queries, interpret):
         from repro.kernels import reducers
         return reducers.visit_mask_counts(masks, qids, valid, n_queries)
+
+    def combine_visits(self, a, b):
+        return a + b
 
     def distributed_reduce(self, mask_local, data_local, axis):
         import jax
@@ -426,6 +442,7 @@ class Agg(ResultSpec):
     matching set. Empty matches finalize to 0.0 (sum) or NaN (min/max)."""
 
     kind: ClassVar[str] = "agg"
+    visits_on_device: ClassVar[bool] = True
     op: str = "sum"
     dim: int = 0
 
@@ -456,6 +473,10 @@ class Agg(ResultSpec):
                                  n_queries)
         counts = reducers.visit_mask_counts(masks, qids, valid, n_queries)
         return agg, counts
+
+    def combine_visits(self, a, b):
+        merge = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+        return merge[self.op](a[0], b[0]), a[1] + b[1]
 
     def distributed_reduce(self, mask_local, data_local, axis):
         import jax

@@ -142,6 +142,7 @@ class VAFile:
         q_n = len(batch)
         qids, bids = self._candidate_blocks_batch(batch)
         self.last_visited_blocks = int(qids.size)
+        ops.count_visit_pairs("vafile", "host", qids.size)
         return launch_visits_batch(
             self.data_dev, qids, bids, batch, self.tile_n, q_n, spec,
             self.n, perm=None, delta=delta,

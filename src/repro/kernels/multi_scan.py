@@ -272,6 +272,7 @@ def multi_scan_visit(
     *,
     tile_n: int = DEFAULT_TILE_N,
     interpret: bool = False,
+    n_visit: jax.Array | None = None,
 ) -> jax.Array:
     """Batched two-phase refinement: visit each (query, block) pair once.
 
@@ -279,6 +280,12 @@ def multi_scan_visit(
     resident for ``rows`` consecutive visits (rows = ``INT8_SUBLANES``, or V
     when the list is shorter), so the int8 output keeps its native tile.
     Lists longer than ``VISIT_CHUNK`` run as a loop of kernels over chunks.
+
+    With ``n_visit``, a traced int32 count, the list is one chunk (V at most
+    ``VISIT_CHUNK``, a multiple of ``INT8_SUBLANES``) whose first ``n_visit``
+    entries are real (a count past V means all of them): the grid stops at
+    the count rounded up to whole output blocks, and the rows past that are
+    left unwritten, for the caller to drop.
 
     Args:
       data_cm: (m_pad, n_pad) columnar data, n_pad % tile_n == 0.
@@ -291,24 +298,47 @@ def multi_scan_visit(
       (V, tile_n) int8 per-visit masks.
     """
     m_pad, n_pad = data_cm.shape
-    n_visit = block_ids.shape[0]
+    v_n = block_ids.shape[0]
     q_n = lower.shape[1]
-    assert query_ids.shape == (n_visit,)
+    assert query_ids.shape == (v_n,)
     assert m_pad % SUBLANES == 0 and n_pad % tile_n == 0
-    rows = min(INT8_SUBLANES, n_visit)
-    chunk = min(VISIT_CHUNK, -(-n_visit // rows) * rows)
-    v_pad = -(-n_visit // chunk) * chunk
-    qids = jnp.pad(query_ids.astype(jnp.int32), (0, v_pad - n_visit))
-    bids = jnp.pad(block_ids.astype(jnp.int32), (0, v_pad - n_visit),
-                   constant_values=-1)
 
     def columns(b):  # (m_pad, Q) -> (Q, m_pad, 1): one bounds block per query
         return b.T.astype(data_cm.dtype).reshape(q_n, m_pad, 1)
 
     lo, up = columns(lower), columns(upper)
+    if n_visit is not None:
+        assert v_n <= VISIT_CHUNK and v_n % INT8_SUBLANES == 0, v_n
+        rows = INT8_SUBLANES
+        steps = (jnp.clip(n_visit, 1, v_n) + rows - 1) // rows * rows
+        return _visit_call(data_cm, lo, up, v_n, rows, steps, tile_n,
+                           interpret)(query_ids, block_ids, lo, up, data_cm)
+    rows = min(INT8_SUBLANES, v_n)
+    chunk = min(VISIT_CHUNK, -(-v_n // rows) * rows)
+    v_pad = -(-v_n // chunk) * chunk
+    qids = jnp.pad(query_ids.astype(jnp.int32), (0, v_pad - v_n))
+    bids = jnp.pad(block_ids.astype(jnp.int32), (0, v_pad - v_n),
+                   constant_values=-1)
+    call = _visit_call(data_cm, lo, up, chunk, rows, chunk, tile_n,
+                       interpret)
+    if v_pad == chunk:
+        out = call(qids, bids, lo, up, data_cm)
+    else:  # one launch still: the chunks loop inside this jit
+        out = jax.lax.map(
+            lambda qb: call(qb[0], qb[1], lo, up, data_cm),
+            (qids.reshape(-1, chunk), bids.reshape(-1, chunk)),
+        ).reshape(v_pad, tile_n)
+    return out if v_pad == v_n else out[:v_n]
+
+
+def _visit_call(data_cm, lo, up, chunk, rows, steps, tile_n, interpret):
+    """The visit kernel over a ``chunk``-long list, of which the grid runs
+    the first ``steps`` entries (an int, or a traced count: Mosaic's dynamic
+    grid bound)."""
+    m_pad = data_cm.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(chunk,),
+        grid=(steps,),
         in_specs=[
             pl.BlockSpec((None, m_pad, 1), lambda i, q, b: (q[i], 0, 0)),
             pl.BlockSpec((None, m_pad, 1), lambda i, q, b: (q[i], 0, 0)),
@@ -317,17 +347,53 @@ def multi_scan_visit(
         ],
         out_specs=pl.BlockSpec((rows, tile_n), lambda i, q, b: (i // rows, 0)),
     )
-    call = pl.pallas_call(
+    return pl.pallas_call(
         _multi_visit_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((chunk, tile_n), jnp.int8),
         interpret=interpret,
     )
-    if v_pad == chunk:
-        out = call(qids, bids, lo, up, data_cm)
-    else:  # one launch still: the chunks loop inside this jit
-        out = jax.lax.map(
-            lambda qb: call(qb[0], qb[1], lo, up, data_cm),
-            (qids.reshape(-1, chunk), bids.reshape(-1, chunk)),
-        ).reshape(v_pad, tile_n)
-    return out if v_pad == n_visit else out[:n_visit]
+
+
+# -- the visit list on the device ----------------------------------------------
+# A (Q, n_leaves) prune mask becomes the visit list without leaving the
+# device: survivor number j, in the query-major, leaf-ascending order of
+# ``np.nonzero``, is found by three searches over prefix counts, each a
+# compare over at most ``LANES`` entries (plus one over the few groups of
+# ``LANES * LANES`` mask bits). No scatter and no sort: each slot of a chunk
+# computes its own pair.
+
+
+def survivor_prefix(leaf_mask: jax.Array) -> tuple:
+    """Prefix counts of a (Q, n_leaves) bool prune mask, for ``nth_survivor``.
+
+    Returns ``(bits, ends, group_ends)``: the mask's bits as (R, LANES)
+    int32 rows of the flattened mask (zero-padded to whole groups of LANES
+    rows), each row's inclusive running survivor count as (R // LANES,
+    LANES), and each group's as (R // LANES,). ``group_ends[-1]`` is the
+    number of survivors.
+    """
+    flat = leaf_mask.reshape(-1)
+    group = LANES * LANES
+    flat = jnp.pad(flat, (0, -flat.size % group))
+    bits = flat.reshape(-1, LANES).astype(jnp.int32)
+    ends = jnp.cumsum(bits.sum(axis=1)).astype(jnp.int32).reshape(-1, LANES)
+    return bits, ends, ends[:, -1]
+
+
+def nth_survivor(prefix: tuple, slots: jax.Array) -> jax.Array:
+    """(C,) int32 flat mask index of survivor number ``slots`` (0-based, in
+    flat order). Slots at or past the survivor count give an index the
+    caller must drop."""
+    bits, ends, group_ends = prefix
+    j = slots[:, None]
+    before = lambda e: jnp.max(jnp.where(e <= j, e, 0), axis=1)
+    g = jnp.minimum(jnp.sum(group_ends[None, :] <= j, axis=1),
+                    group_ends.size - 1)
+    g_ends = ends[g]                                         # (C, LANES)
+    r = g * LANES + jnp.minimum(jnp.sum(g_ends <= j, axis=1), LANES - 1)
+    # survivors in the rows before row r, then the rank of j inside it
+    k = slots - jnp.maximum(before(g_ends), before(group_ends[None, :]))
+    seen = jnp.cumsum(bits[r], axis=1)                       # (C, LANES)
+    lane = jnp.minimum(jnp.sum(seen <= k[:, None], axis=1), LANES - 1)
+    return r * LANES + lane

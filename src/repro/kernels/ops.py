@@ -162,6 +162,17 @@ def count_scan_rows(kernel: str, dims_mask: np.ndarray, q_pad: int,
             help=_ROWS_HELP.format(outcome), kernel=kernel).inc(n)
 
 
+def count_visit_pairs(path: str, route: str, n: int) -> None:
+    """Count the (query, block) pairs a two-phase batch visited, labelled by
+    ``path`` and ``route``: ``"host"`` where the visit list was built on the
+    host, ``"device"`` where the prune mask was compacted on the device and
+    the count came back in the payload."""
+    _obs_metrics.registry().counter(
+        "mdrq_visit_pairs_total",
+        help="(query, block) pairs the two-phase paths visited",
+        path=path, route=route).inc(n)
+
+
 def device_get(x, *, stage=None, path=None):
     """Counted device->host transfer — the host-sync tax the cost model prices.
 
@@ -524,14 +535,75 @@ multi_scan_vertical_reduce = _counted(
 )(_multi_scan_vertical_reduce_jit)
 
 
+def _visit_masks(data_cm, query_ids, block_ids, lower, upper, *, tile_n,
+                 interpret, n_visit=None):
+    """Backend-dispatched visit kernel over a (query, block) list
+    (trace-time helper)."""
+    if use_xla():
+        m_pad, n_pad = data_cm.shape
+        blocks = data_cm.reshape(m_pad, n_pad // tile_n, tile_n).transpose(1, 0, 2)
+        return _ref.multi_scan_blocks_ref(blocks, query_ids, block_ids, lower,
+                                          upper)
+    return _ms.multi_scan_visit(data_cm, query_ids, block_ids, lower, upper,
+                                tile_n=tile_n, interpret=interpret,
+                                n_visit=n_visit)
+
+
+def _reduce_visit_list(data_cm, query_ids, block_ids, valid, visit_index,
+                       lower, upper, base_tomb, *, spec, tile_n, n_queries,
+                       interpret, n_visit=None):
+    """A visit list's masks, folded with the base tombstones, through the
+    spec's visit reducer, which drops the rows whose ``valid`` is 0
+    (trace-time helper)."""
+    masks = _visit_masks(data_cm, query_ids, block_ids, lower, upper,
+                         tile_n=tile_n, interpret=interpret, n_visit=n_visit)
+    if base_tomb is not None:
+        from repro.kernels import reducers as _red
+        masks = _red.fold_tombstones(
+            masks, _red.gather_tomb_blocks(base_tomb, block_ids, tile_n))
+    return spec.reduce_visits(masks, data_cm, query_ids, block_ids, valid,
+                              visit_index, tile_n=tile_n,
+                              n_queries=n_queries, interpret=interpret)
+
+
+def _reduce_survivors(data_cm, leaf_mask, lower, upper, base_tomb, *, spec,
+                      tile_n, n_queries, interpret):
+    """The device route: the (Q, n_leaves) prune mask's survivors visited
+    in chunks of ``VISIT_CHUNK`` pairs, as many as the device count needs,
+    each chunk's masks reduced into the spec's payload -> (payload, int32
+    number of visited pairs). Slots past the count are dropped: the kernel
+    leaves them unwritten (trace-time helper)."""
+    n_leaves = leaf_mask.shape[1]
+    chunk = min(_ms.VISIT_CHUNK,
+                -(-leaf_mask.size // _ms.INT8_SUBLANES) * _ms.INT8_SUBLANES)
+    prefix = _ms.survivor_prefix(leaf_mask)
+    n_pairs = prefix[2][-1]
+
+    def reduce_chunk(c):
+        slots = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        valid = slots < n_pairs
+        flat = _ms.nth_survivor(prefix, slots)
+        return _reduce_visit_list(
+            data_cm, jnp.where(valid, flat // n_leaves, 0),
+            jnp.where(valid, flat % n_leaves, 0), valid.astype(jnp.int32),
+            None, lower, upper, base_tomb, spec=spec, tile_n=tile_n,
+            n_queries=n_queries, interpret=interpret,
+            n_visit=n_pairs - c * chunk)
+
+    n_chunks = (n_pairs + chunk - 1) // chunk
+    payload = jax.lax.fori_loop(
+        1, n_chunks,
+        lambda c, acc: spec.combine_visits(acc, reduce_chunk(c)),
+        reduce_chunk(0))
+    return payload, n_pairs
+
+
 @functools.partial(jax.jit,
                    static_argnames=("spec", "tile_n", "n_queries", "interpret"))
 def _multi_visit_reduce_jit(
     data_cm: jax.Array,
-    query_ids: jax.Array,
-    block_ids: jax.Array,
-    valid: jax.Array,
-    visit_index: jax.Array,
+    visits,
+    visit_index: jax.Array | None,
     lower: jax.Array,
     upper: jax.Array,
     delta_cm: jax.Array | None = None,
@@ -542,24 +614,23 @@ def _multi_visit_reduce_jit(
     n_queries: int = 1,
     interpret: bool | None = None,
 ):
+    """``visits`` is the host route's padded ``(query_ids, block_ids)`` list
+    or the device route's (Q, n_leaves) bool prune mask; the device route's
+    base payload is ``(payload, n_pairs)``."""
     note_trace("multi_visit_reduce")
     if interpret is None:
         interpret = default_interpret()
-    if use_xla():
-        m_pad, n_pad = data_cm.shape
-        blocks = data_cm.reshape(m_pad, n_pad // tile_n, tile_n).transpose(1, 0, 2)
-        masks = _ref.multi_scan_blocks_ref(blocks, query_ids, block_ids,
-                                           lower, upper)
+    if isinstance(visits, tuple):
+        query_ids, block_ids = visits  # block -1 pads the pow2 bucket
+        base = _reduce_visit_list(data_cm, query_ids, block_ids,
+                                  (block_ids >= 0).astype(jnp.int32),
+                                  visit_index, lower, upper, base_tomb,
+                                  spec=spec, tile_n=tile_n,
+                                  n_queries=n_queries, interpret=interpret)
     else:
-        masks = _ms.multi_scan_visit(data_cm, query_ids, block_ids, lower,
-                                     upper, tile_n=tile_n, interpret=interpret)
-    if base_tomb is not None:
-        from repro.kernels import reducers as _red
-        masks = _red.fold_tombstones(
-            masks, _red.gather_tomb_blocks(base_tomb, block_ids, tile_n))
-    base = spec.reduce_visits(masks, data_cm, query_ids, block_ids, valid,
-                              visit_index, tile_n=tile_n,
-                              n_queries=n_queries, interpret=interpret)
+        base = _reduce_survivors(data_cm, visits, lower, upper, base_tomb,
+                                 spec=spec, tile_n=tile_n,
+                                 n_queries=n_queries, interpret=interpret)
     if delta_cm is None:
         return base
     # The (m_pad, q_pad) bounds already cover the whole batch, so the delta
@@ -570,9 +641,10 @@ def _multi_visit_reduce_jit(
 
 multi_visit_reduce = _counted(
     "multi_visit_reduce",
-    "Batched two-phase refinement over a (query, block) visit list + the "
-    "ResultSpec's on-device visit reducer in one launch (shared by the tree "
-    "MDIS and the VA-file phase 2).",
+    "Batched two-phase refinement + the ResultSpec's on-device visit "
+    "reducer in one launch (shared by the tree MDIS and the VA-file phase "
+    "2): over a host-built (query, block) visit list, or over the survivors "
+    "of a device prune mask, compacted on the device.",
 )(_multi_visit_reduce_jit)
 
 

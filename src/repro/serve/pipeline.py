@@ -21,7 +21,8 @@ pre-compiles the executables the hot path will need — every pow2 query
 bucket up to ``max_batch``, for every warm path, under the server's spec,
 through ``ops.aot_capture()`` — so steady-state serving *provably* never
 retraces (``ops.trace_log()`` stays empty; data-shape-dependent visit
-buckets on tree/VA paths are the documented residual and fall back to jit).
+buckets of the host-built visit lists — tree paths under Ids/Mask/TopK, the
+VA-file — are the documented residual and fall back to jit).
 
 **Admission control**: ``submit`` sheds with a typed ``Overloaded`` ticket
 once ``(backlog depth + 1) x EWMA batch seconds`` exceeds

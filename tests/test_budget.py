@@ -60,16 +60,26 @@ def test_certificate_covers_every_registered_path(cert):
 
 def test_certificate_internal_consistency(cert):
     """finalize = total - device_stage, launches all happen in the device
-    stage, and the engine/serve layers add zero cost of their own."""
+    stage, and the engine/serve layers add zero cost of their own. The
+    tree paths certify a second budget for specs whose visit list stays on
+    the device: the same launches, one sync, and that one in finalize."""
     for name, ctx in cert["paths"].items():
         for key in ("frozen", "delta"):
-            e = ctx[key]
-            assert e["finalize_host_syncs"] == (
-                e["total"]["host_syncs"]
-                - e["device_stage"]["host_syncs"]), (name, key)
-            assert e["total"]["launches"] == e["device_stage"]["launches"], \
-                (name, key)
-            assert e["finalize_host_syncs"] >= 1, (name, key)
+            for e in (ctx[key], ctx[key].get("visits_on_device", ctx[key])):
+                assert e["finalize_host_syncs"] == (
+                    e["total"]["host_syncs"]
+                    - e["device_stage"]["host_syncs"]), (name, key)
+                assert e["total"]["launches"] == \
+                    e["device_stage"]["launches"], (name, key)
+                assert e["finalize_host_syncs"] >= 1, (name, key)
+    for name in ("kdtree", "rstar"):
+        for key in ("frozen", "delta"):
+            bound = cert["paths"][name][key]
+            dev = bound["visits_on_device"]
+            assert bound["total"]["host_syncs"] == 2, (name, key)
+            assert dev["total"]["host_syncs"] == 1, (name, key)
+            assert dev["device_stage"]["host_syncs"] == 0, (name, key)
+            assert dev["total"]["launches"] == bound["total"]["launches"]
     zero = {"host_syncs": 0, "launches": {}}
     assert cert["engine"]["MDRQEngine.launch_batch"] == zero
     assert cert["engine"]["MDRQEngine.query_batch"] == zero
@@ -133,10 +143,13 @@ def test_query_batch_counters_equal_certificate(cert, eng_frozen, eng_delta,
     rng = np.random.default_rng(7)
     queries = _mixed_queries(uni5.cols, rng)
     for name, ctx in cert["paths"].items():
+        e = ctx[context]
+        if spec.visits_on_device:
+            e = e.get("visits_on_device", e)
         eng.query_batch(queries, method=name, spec=spec)  # warm / trace
         ops.reset_counters()
         eng.query_batch(queries, method=name, spec=spec)
-        assert ops.counters() == _expected(ctx[context]["total"]), \
+        assert ops.counters() == _expected(e["total"]), \
             (name, context, spec.kind)
 
 
@@ -161,6 +174,25 @@ def test_split_protocol_stage_split_equals_certificate(cert, eng_frozen,
             (name, context, "after finalize")
         assert ops.counter("host_sync") - e["device_stage"]["host_syncs"] \
             == e["finalize_host_syncs"], (name, context)
+
+
+@pytest.mark.parametrize("context", ["frozen", "delta"])
+def test_split_protocol_device_route_equals_certificate(cert, eng_frozen,
+                                                        eng_delta, uni5,
+                                                        context):
+    """Under Count the tree paths' ``launch_batch`` syncs nothing and
+    ``finalize`` syncs once: the certificate's ``visits_on_device``
+    budget."""
+    eng = eng_frozen if context == "frozen" else eng_delta
+    queries = _mixed_queries(uni5.cols, np.random.default_rng(29))
+    for name in ("kdtree", "rstar"):
+        e = cert["paths"][name][context]["visits_on_device"]
+        eng.query_batch(queries, method=name, spec=Count())  # warm / trace
+        ops.reset_counters()
+        pending = eng.launch_batch(queries, method=name, spec=Count())
+        assert ops.counters() == _expected(e["device_stage"]), (name, context)
+        pending.finalize()
+        assert ops.counters() == _expected(e["total"]), (name, context)
 
 
 def test_certificate_drift_is_detected(graph, tmp_path):

@@ -55,8 +55,8 @@ def test_range_scan_visit_sweep(m, dtype):
                           [-1, -1]]).astype(np.int32)
     qids = jnp.zeros(ids.shape, jnp.int32)
     out = np.asarray(ops.multi_visit_reduce(
-        data, qids, jnp.asarray(ids), jnp.asarray((ids >= 0).astype(np.int32)),
-        jnp.zeros((1, 1), jnp.int32), lo, up, spec=Mask(), n_queries=1))
+        data, (qids, jnp.asarray(ids)), jnp.zeros((1, 1), jnp.int32), lo, up,
+        spec=Mask(), n_queries=1))
     blocks = data.reshape(data.shape[0], n_blocks, 1024).transpose(1, 0, 2)
     oracle = np.asarray(ref.multi_scan_blocks_ref(blocks, qids,
                                                   jnp.asarray(ids), lo, up))

@@ -247,9 +247,9 @@ def test_multi_scan_visit_vs_oracle():
     lo, up = batch.bounds_columnar(padded.shape[0])
     lo, up = jnp.asarray(lo), jnp.asarray(up)
     out = np.asarray(ops.multi_visit_reduce(
-        data, jnp.asarray(qids), jnp.asarray(bids),
-        jnp.asarray((bids >= 0).astype(np.int32)), jnp.zeros((1, 1), jnp.int32),
-        lo, up, spec=Mask(), tile_n=tile_n, n_queries=4))
+        data, (jnp.asarray(qids), jnp.asarray(bids)),
+        jnp.zeros((1, 1), jnp.int32), lo, up, spec=Mask(), tile_n=tile_n,
+        n_queries=4))
     blocks = data.reshape(data.shape[0], n_blocks, tile_n).transpose(1, 0, 2)
     np.testing.assert_array_equal(out, np.asarray(ref.multi_scan_blocks_ref(
         blocks, jnp.asarray(qids), jnp.asarray(bids), lo, up)))

@@ -5,7 +5,8 @@
   * switched on, a ``jax.profiler`` trace of a few pipelined windows holds
     the server's spans as ``mdrq.*`` events with their attributes: the two
     stages of each window on two host lines, linked by ``window``, and the
-    kd-tree's mid-launch sync carrying its leaf mask's bytes;
+    kd-tree's mid-launch sync carrying its leaf mask's bytes where the host
+    builds the visit list (Ids), and none where the device does (Count);
   * a ``Tracer`` tree is the same with the sink on as with it off.
 """
 import threading
@@ -15,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import Count, Dataset, MDRQEngine
+from repro.core import Count, Dataset, Ids, MDRQEngine
 from repro.data import synthetic
 from repro.kernels import ops
 from repro.obs import tracing
@@ -90,13 +91,15 @@ def _mdrq_events(trace_dir):
     return out
 
 
-def test_profiler_trace_of_pipelined_windows(ds, tmp_path, sink_off):
+def _serve_traced(ds, tmp_path, spec):
+    """Three pipelined kd-tree windows of four queries under ``spec``,
+    traced with the profiler sink on -> (engine, the ``mdrq.*`` events)."""
     import jax
 
     eng = MDRQEngine(ds, structures=("scan", "kdtree"), tile_n=512)
     qs = synthetic.workload(ds, 12, seed=3)
     with serve_pipelined(eng, max_batch=4, max_wait_s=float("inf"),
-                         method="kdtree", spec=Count(), warmup=False,
+                         method="kdtree", spec=spec, warmup=False,
                          latency_budget_s=1e9) as srv:
         for q in qs:                      # one warm pass: compiles
             srv.submit(q)
@@ -109,9 +112,15 @@ def test_profiler_trace_of_pipelined_windows(ds, tmp_path, sink_off):
         finally:
             jax.profiler.stop_trace()
             tracing.to_profiler(False)
-        assert all(t.result(timeout=5.0) >= 0 for t in tickets)
+        for t in tickets:
+            t.result(timeout=5.0)
+    return eng, _mdrq_events(tmp_path)
 
-    evs = _mdrq_events(tmp_path)
+
+def test_profiler_trace_of_pipelined_windows(ds, tmp_path, sink_off):
+    """Under Ids the kd-tree builds its visit list on the host, so the
+    admission thread syncs the prune's mask mid-launch."""
+    eng, evs = _serve_traced(ds, tmp_path, Ids())
     names = {n for n, _, _ in evs}
     assert {"mdrq.flush", "mdrq.plan", "mdrq.execute", "mdrq.sync",
             "mdrq.backlog_put", "mdrq.finalize"} <= names
@@ -138,6 +147,22 @@ def test_profiler_trace_of_pipelined_windows(ds, tmp_path, sink_off):
     assert all(st["path"] == "kdtree" and st["bytes"] > 0 for _, st in final)
     assert {th for th, _ in final} == set(fin.values())
     assert set(flush.values()).isdisjoint(th for th, _ in final)
+
+
+def test_profiler_trace_of_device_route_windows(ds, tmp_path, sink_off):
+    """Under Count the kd-tree's visit list stays on the device: no sync
+    mid-launch, and each window's one sync, on the finalizer's line, brings
+    the counts and the visited pair count back."""
+    _, evs = _serve_traced(ds, tmp_path, Count())
+    fin = {st["window"]: th for n, th, st in evs if n == "mdrq.finalize"}
+    syncs = [(th, st) for n, th, st in evs if n == "mdrq.sync"]
+    assert [st for _, st in syncs if st.get("stage") == "launch"] == []
+    final = [(th, st) for th, st in syncs if st.get("stage") == "finalize"]
+    assert len(fin) == 3 and len(final) == 3
+    # (q_pad,) int32 counts and one int32 pair count
+    assert all(st["path"] == "kdtree" and st["bytes"] == 4 * 4 + 4
+               for _, st in final)
+    assert {th for th, _ in final} == set(fin.values())
 
 
 def _shape(spans):

@@ -116,10 +116,23 @@ def test_row_skipping_scan_compiles(one_chip, kernel, m_pad, q_n):
 def test_multi_visit_reduce_compiles(one_chip, q_n):
     data, lo, up = _scan_args(q_n, one_chip)
     n_visit = q_n * 2048                # pow2 visit bucket
-    visits = [_sds((n_visit,), I32, one_chip) for _ in range(3)]
+    visits = tuple(_sds((n_visit,), I32, one_chip) for _ in range(2))
     visit_index = _sds((1, 1), I32, one_chip)
-    _compile(ops.multi_visit_reduce, data, *visits, visit_index, lo, up,
+    _compile(ops.multi_visit_reduce, data, visits, visit_index, lo, up,
              spec=Ids(), n_queries=q_n)
+
+
+@pytest.mark.parametrize("q_n", BUCKETS)
+@pytest.mark.parametrize("spec", [Count(), Agg("max", dim=3)], ids=str)
+def test_multi_visit_reduce_on_device_compiles(one_chip, q_n, spec):
+    """The device route: a (Q, n_leaves) prune mask compacted on the device,
+    visited in chunks under a dynamic grid bound, with a delta block and
+    base tombstones riding the same launch."""
+    data, lo, up = _scan_args(q_n, one_chip)
+    leaf_mask = _sds((q_n, N_PAD // TILE_N), jnp.bool_, one_chip)
+    _compile(ops.multi_visit_reduce, data, leaf_mask, None, lo, up,
+             _sds((M_PAD, 4 * TILE_N), F32, one_chip),
+             _sds((N_PAD,), I8, one_chip), spec=spec, n_queries=q_n)
 
 
 def test_range_scan_vertical_compiles(one_chip):
@@ -132,8 +145,8 @@ def test_range_scan_visit_compiles(one_chip):
     """The two-phase visit launch a single ``engine.query`` runs, at the
     largest pow2 visit bucket one query reaches over 10M rows."""
     data, lo, up = _scan_args(1, one_chip)
-    visits = [_sds((16384,), I32, one_chip) for _ in range(3)]
-    _compile(ops.multi_visit_reduce, data, *visits,
+    visits = tuple(_sds((16384,), I32, one_chip) for _ in range(2))
+    _compile(ops.multi_visit_reduce, data, visits,
              _sds((1, 1), I32, one_chip), lo, up, spec=Ids(), n_queries=1)
 
 
